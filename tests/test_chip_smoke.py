@@ -1,0 +1,64 @@
+"""chip_smoke.py on the CPU harness: its phase functions run train -> score ->
+serve at a few thousand rows, ``main()`` refuses a backend that is not a
+TPU, and a recovery anywhere on the path fails the smoke's fault check
+(on the chip a fallback is a bug, not resilience)."""
+import numpy as np
+import pytest
+
+import chip_smoke as cs
+from transmogrifai_tpu.observability import metrics as obs_metrics
+from transmogrifai_tpu.robustness import faults
+
+ROWS, HOLDOUT = 3000, 1000
+
+
+def test_phases_train_score_serve():
+    obs_metrics.enable_metrics(True)   # the conftest fixture resets it
+    data = cs.make_data(ROWS + HOLDOUT, seed=3)
+    table = cs.table_of(data, 0, ROWS)
+    holdout = cs.table_of(data, ROWS, ROWS + HOLDOUT)
+
+    single = cs.phase_train(table)
+    # TG_FAST_GRIDS (conftest) shrinks the stock grids: main() would
+    # refuse this count, which is the point of its 135-fit check
+    assert 0 < single["fits"] < cs.DEFAULT_GRID_FITS
+    assert single["results"][
+        (single["family"],
+         cs.json.dumps(single["hyper"], sort_keys=True))] == single["metric"]
+
+    score = cs.phase_score(single["model"], single["pred"], table, holdout,
+                           parity_rows=500, auroc_floor=0.9)
+    assert 0.9 < score["auroc"] <= 1.0
+
+    serve = cs.phase_serve(single["model"], data, ROWS, ROWS + 40)
+    assert serve["requests"] == 40 and serve["aotHits"] > 0
+    cs.check_no_faults("end of run")
+
+
+def test_main_refuses_a_backend_that_is_not_a_tpu(capsys):
+    assert cs.main([]) == 2
+    captured = capsys.readouterr()
+    assert captured.out == ""            # no result line
+    assert "no accelerator" in captured.err
+
+
+@pytest.mark.chaos
+def test_a_quarantined_family_fails_the_fault_check():
+    """train() survives a family whose program raises and elects a winner
+    from the rest — exactly what must not pass for a chip run."""
+    data = cs.make_data(ROWS, seed=4)   # same row bucket: programs reused
+    with faults.injected({"validator.family_fit": {
+            "mode": "raise", "key": "OpGBTClassifier", "count": 99}}):
+        with pytest.raises(cs.SmokeFailure, match="faults.*not clean"):
+            cs.phase_train(cs.table_of(data, 0, ROWS))
+
+
+def test_auroc_matches_the_pairwise_definition():
+    rng = np.random.RandomState(0)
+    y = (rng.rand(200) > 0.4).astype(np.float32)
+    s = np.round(rng.rand(200) + 0.3 * y, 1)      # rounded: many ties
+    pos, neg = s[y > 0.5], s[y < 0.5]
+    pairs = ((pos[:, None] > neg[None, :]).sum()
+             + 0.5 * (pos[:, None] == neg[None, :]).sum())
+    assert cs.auroc(s, y) == pytest.approx(pairs / (len(pos) * len(neg)))
+

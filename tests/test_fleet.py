@@ -553,3 +553,36 @@ def test_subprocess_fleet_kill_failover(model, saved):
         recs = [f.result(timeout=60) for f in futs]
         assert recs == baseline
         assert fd.fleet_snapshot()["kills"] == 1
+
+
+def test_subprocess_spawn_refused_while_parent_holds_the_chip(
+        monkeypatch, tmp_path):
+    """One chip serves one process: a parent that has initialised JAX on
+    an accelerator gets a typed, immediate refusal — no worker is
+    spawned, no 180 s spawn timeout (docs/serving.md)."""
+    from transmogrifai_tpu.serving import fleet
+
+    monkeypatch.setattr(fleet, "_parent_holds_accelerator", lambda: "tpu")
+    spawned = []
+    monkeypatch.setattr(fleet.subprocess, "Popen",
+                        lambda *a, **k: spawned.append(a))
+    with pytest.raises(fleet.ChipHeldError, match="already holds"):
+        fleet.SubprocessReplica("r0", {"m": str(tmp_path)})
+    assert not spawned
+    # on the test harness itself nothing is held: the backend is the CPU
+    monkeypatch.undo()
+    assert fleet._parent_holds_accelerator() is None
+
+
+def test_subprocess_worker_death_at_startup_fails_at_once(
+        monkeypatch, tmp_path):
+    """A worker that exits during start-up fails the spawn as soon as its
+    pipe closes — not at ``spawn_timeout_s``."""
+    from transmogrifai_tpu.serving import fleet
+
+    monkeypatch.setattr(fleet.sys, "executable", "/bin/false")
+    t0 = time.monotonic()
+    with pytest.raises(ReplicaLostError, match="exited during start-up"):
+        fleet.SubprocessReplica("r0", {"m": str(tmp_path)},
+                                spawn_timeout_s=170.0)
+    assert time.monotonic() - t0 < 60.0

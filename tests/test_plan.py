@@ -428,6 +428,37 @@ def test_mid_segment_fault_falls_back_to_eager(titanic):
     assert log.to_json()["planFallbacks"]
 
 
+def test_plan_build_failure_is_recorded_and_runs_eager(titanic, monkeypatch):
+    """A plan whose BUILD raises degrades to eager dispatch like a run that
+    raises — and is just as visible: a ``plan_fallback`` report from site
+    ``plan.compile`` (it used to be a log line only, so a chip run whose
+    plans never built kept a clean ``summary()["faults"]``)."""
+    from transmogrifai_tpu.robustness.policy import FaultLog
+    model, df, _ = titanic
+    tbl = dataframe_to_table(df, model.raw_features)
+    plan_mod.enable_planning(False)
+    try:
+        expected = model.score(table=tbl)
+    finally:
+        plan_mod.enable_planning(None)
+
+    def boom(*a, **k):
+        raise RuntimeError("Mosaic refused the segment")
+
+    monkeypatch.setattr(plan_mod, "_build_plan", boom)
+    log = FaultLog()
+    with log.activate():
+        out = model.score(table=tbl)
+    _assert_tables_bit_equal(expected, out)
+    fallbacks = log.to_json()["planFallbacks"]
+    assert fallbacks and all(r["site"] == "plan.compile" for r in fallbacks)
+    assert "Mosaic refused" in fallbacks[0]["detail"]["error"]
+    # the infeasible sequence is cached as such: reported once, not per run
+    with log.activate():
+        model.score(table=tbl)
+    assert len(log.to_json()["planFallbacks"]) == len(fallbacks)
+
+
 # ---------------------------------------------------------------------------
 # Vectorized value-lambda host fallback (stages/base satellite)
 # ---------------------------------------------------------------------------

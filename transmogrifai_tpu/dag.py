@@ -87,7 +87,7 @@ def fit_and_transform_dag(table: FeatureTable, layers: List[StageLayer],
 
     ``retry_policy`` (a ``robustness.RetryPolicy``, wired by
     ``OpWorkflow.with_fault_policy``) re-runs a stage fit that fails with a
-    TRANSIENT error — device-transfer hiccups on tunneled backends — the
+    TRANSIENT error — a failed device transfer, a reset connection — the
     analog of the reference's ``spark.task.maxFailures``. Fatal errors
     (shape/trace bugs) are never retried: the fit is deterministic, so
     re-running the same program on the same inputs cannot change them.

@@ -38,7 +38,7 @@ winner may legitimately differ.
 """
 from __future__ import annotations
 
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from typing import Optional, Sequence
 
 import numpy as np
@@ -58,12 +58,22 @@ __all__ = [
 @contextmanager
 def engine_mesh(mesh):
     """Activate ``mesh`` as the engine's sharding target for the duration of
-    the block. Must wrap the *trace* (the first call of a jitted fit /
-    fused program, and any re-trace such as AOT export) — the kernels read
-    the context at trace time, like their env knobs."""
+    the block (``None``: no-op). Must wrap the *trace* (the first call of a
+    jitted fit / fused program, and any re-trace such as AOT export) — the
+    kernels read the context at trace time, like their env knobs.
+
+    ``jax.set_mesh`` puts the mesh into jax's trace context, which is part
+    of every jit cache key: a fit first traced single-device (Mosaic
+    kernels, no row-block constraints) is re-traced here instead of being
+    replayed from the trace cache into a sharded program."""
+    if mesh is None:
+        yield
+        return
+    import jax
     token = _ENGINE_MESH.set(mesh)
     try:
-        yield
+        with jax.set_mesh(mesh):
+            yield
     finally:
         _ENGINE_MESH.reset(token)
 
@@ -111,8 +121,7 @@ def build_node_hist(codes, node, stats: Sequence, n_bins: int, *,
             raise ValueError("host histogram backend is stride-1 only")
         return build_node_hist_host(codes, node, stats, n_bins, n_nodes)
     import jax.numpy as jnp
-    ctx = engine_mesh(mesh) if mesh is not None else nullcontext()
-    with ctx:
+    with engine_mesh(mesh):
         flat = node_hist_matmul(codes, node, list(stats), n_nodes, n_bins,
                                 stride=stride)
     k = len(stats)

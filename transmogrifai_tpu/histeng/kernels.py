@@ -80,7 +80,10 @@ def _use_pallas() -> bool:
         return False
     if env in ("1", "true"):
         return True
-    return jax.default_backend() in ("tpu",)
+    # a Mosaic custom call has no partitioning rule: inside a GSPMD program
+    # XLA would gather every row onto every chip, so under an engine mesh
+    # the XLA contraction (which shards) runs instead
+    return jax.default_backend() == "tpu" and current_engine_mesh() is None
 
 
 def _interpret() -> bool:
@@ -103,15 +106,9 @@ def _hist_shards() -> int:
 def _tile_lanes(x, repeats: int):
     """``[x, x, …]`` concatenated ``repeats`` times along lanes (axis 1).
 
-    Mosaic's RepeatOp — what ``pltpu.repeat`` lowers to ON TPU — tiles the
-    whole vector, and every kernel lane layout here is built on that. But
-    jax 0.4.36+ registers a generic lowering for the same primitive that is
-    ELEMENT-WISE ``jnp.repeat`` — so in interpret mode (CPU CI) the lanes
-    came back permuted and every kernel test silently compared bin-major
-    against feature-major garbage. Keep the hardware op on TPU; emulate the
-    tile semantics with an explicit concatenate everywhere else."""
-    if _interpret():
-        return jnp.concatenate([x] * repeats, axis=1)
+    ``pltpu.repeat`` tiles the whole vector — Mosaic's RepeatOp on the chip,
+    ``jnp.tile`` in interpret mode — and every kernel lane layout here is
+    built on that (NOT element-wise ``jnp.repeat`` semantics)."""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.repeat(x, repeats, axis=1)
 

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...histeng import engine_mesh
 from ...models.api import MODEL_REGISTRY, FittedParams, ModelFamily
 from ...robustness import faults
 from ...robustness.guards import (
@@ -384,7 +385,11 @@ class ModelSelector(AllowLabelAsInput, Estimator):
             try:
                 faults.inject("selector.refit", key=fam_name)
                 garr = family.grid_to_arrays([hyper])
-                params_b = family.fit_batch(Xf, yf, W, garr, num_classes)
+                # rows are 'data'-sharded under a mesh: trace the refit
+                # with the engine's sharded contractions, like the sweep
+                with engine_mesh(self.mesh):
+                    params_b = family.fit_batch(Xf, yf, W, garr,
+                                                num_classes)
                 sel_params = family.select_params(params_b, 0)
                 if not params_finite(sel_params,
                                      getattr(family, "inf_ok_params", ())):
@@ -565,7 +570,8 @@ class SelectedModel(AllowLabelAsInput, Transformer):
             from jax.sharding import NamedSharding, PartitionSpec as P
             X = jax.device_put(X, NamedSharding(mesh, P("data", None)))
         family = MODEL_REGISTRY[self.fitted.family]
-        parts = family.predict_one(self.fitted, X)
+        with engine_mesh(mesh):
+            parts = family.predict_one(self.fitted, X)
         if n_pad != n:
             parts = {k: v[:n] for k, v in parts.items()}
         parts = dict(parts,

@@ -162,16 +162,12 @@ def _environment() -> Dict[str, Any]:
     }
     try:
         import jax
-        env["jax"] = getattr(jax, "__version__", None)
-        try:
-            import jaxlib
-            env["jaxlib"] = getattr(jaxlib, "__version__", None)
-        except Exception:
-            env["jaxlib"] = None
+        import jaxlib
+        env["jax"] = jax.__version__
+        env["jaxlib"] = jaxlib.__version__
         devs = jax.devices()
-        env["backend"] = devs[0].platform if devs else None
-        env["devices"] = [{"id": d.id, "kind": getattr(d, "device_kind", "")}
-                          for d in devs]
+        env["backend"] = devs[0].platform
+        env["devices"] = [{"id": d.id, "kind": d.device_kind} for d in devs]
     except Exception as e:  # pragma: no cover - jax must never fail a dump
         env["jaxError"] = f"{type(e).__name__}: {e}"[:200]
     _ENV_CACHE = env

@@ -17,26 +17,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# jax moved shard_map from jax.experimental to the top-level namespace
-# (0.4.35 added jax.shard_map; the experimental path still exists but warns
-# on newer releases). Export the resolved symbol so framework + tests bind
-# one name across jax versions.
-try:  # pragma: no cover - version dependent
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+from jax import shard_map  # noqa: F401  (re-exported: one name for callers)
 
 
 def axis_size(axis_name: str) -> int:
     """STATIC size of a mapped mesh axis from inside ``shard_map`` (drives
     Python-level hop loops, so it must be a concrete int, not a traced
-    ``psum(1)``). jax 0.4.38+ exposes ``jax.lax.axis_size``; fall back to
-    the trace-env frame on older releases."""
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:  # pragma: no cover - version dependent
-        return size(axis_name)
-    from jax._src import core as _core
-    return int(_core.axis_frame(axis_name))  # returns the size directly
+    ``psum(1)``)."""
+    return jax.lax.axis_size(axis_name)
 
 
 def psum(x, axis_name: str = "data"):

@@ -4,8 +4,8 @@ The paper's substrate swap is "jit-compiled kernels instead of Catalyst";
 round 5 proved the shape of the win by fusing the per-family sweep glue into
 single jitted programs (docs/benchmarks.md). This module applies the same
 cure to the fit-and-transform DAG: instead of dispatching every transformer
-as its own executable (each with a ~2.7 ms dispatch bubble, ~70-130 ms on
-tunneled backends), a *plan* partitions a topologically-ordered run of
+as its own executable (each a separate launch with its own dispatch
+bubble), a *plan* partitions a topologically-ordered run of
 fitted/pure transformer stages into maximal device-fusable segments — stages
 exposing a pure-jax ``device_columnar`` dual — separated by host stages
 (object-array text/map fronts, row lambdas), and traces each segment into
@@ -669,6 +669,18 @@ def schema_fingerprint(stages: Sequence[Any],
             for nm, dt, shape, maskless in fp]
 
 
+def _record_fallback(site: str, e: BaseException, stages: Sequence[Any],
+                     **detail: Any) -> None:
+    """The typed ``plan_fallback`` report: a plan that failed to build
+    (``plan.compile``) or to run (``plan.execute``) degrades to eager
+    dispatch, never silently (docs/plan.md "Fallback semantics")."""
+    from .robustness.policy import FaultLog, FaultReport
+    FaultLog.record(FaultReport(
+        site=site, kind="plan_fallback",
+        detail={"error": f"{type(e).__name__}: {e}"[:300], **detail,
+                "stages": [getattr(s, "uid", "?") for s in stages]}))
+
+
 def get_plan(stages: Sequence[Any], table: FeatureTable, *,
              keep_intermediates: bool = True,
              extra_keep: Sequence[str] = (),
@@ -701,6 +713,7 @@ def get_plan(stages: Sequence[Any], table: FeatureTable, *,
                            "eager dispatch for this stage sequence",
                            type(e).__name__, e)
             sp.set_attr(failed=f"{type(e).__name__}: {e}"[:200])
+            _record_fallback("plan.compile", e, stages)
             plan = None
         if plan is not None:
             sp.set_attr(segments=plan.num_segments,
@@ -865,12 +878,8 @@ def apply_planned(stages: Sequence[Any], table: FeatureTable, *,
     try:
         return _execute_adaptive(plan, table)
     except Exception as e:
-        from .robustness.policy import FaultLog, FaultReport
-        FaultLog.record(FaultReport(
-            site="plan.execute", kind="plan_fallback",
-            detail={"error": f"{type(e).__name__}: {e}"[:300],
-                    "segments": plan.num_segments,
-                    "stages": [getattr(s, "uid", "?") for s in stages]}))
+        _record_fallback("plan.execute", e, stages,
+                         segments=plan.num_segments)
         logger.warning(
             "planned transform run failed (%s: %s); falling back to eager "
             "per-stage dispatch for this run", type(e).__name__, e)

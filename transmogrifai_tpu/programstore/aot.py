@@ -22,46 +22,21 @@ store").
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Tuple
-
-_SUPPORTED: Optional[bool] = None
-
-
-def aot_supported() -> bool:
-    """True when this jax build can export + deserialize programs
-    (cached probe; False degrades every store path to a no-op)."""
-    global _SUPPORTED
-    if _SUPPORTED is None:
-        try:
-            from jax import export as _  # noqa: F401
-            _SUPPORTED = True
-        except Exception:
-            _SUPPORTED = False
-    return _SUPPORTED
+from typing import Any, Callable, Tuple
 
 
 def current_jaxlib() -> str:
-    try:
-        import jaxlib.version
-        return str(jaxlib.version.__version__)
-    except Exception:
-        try:
-            import jax
-            return str(jax.__version__)
-        except Exception:
-            return "unknown"
+    import jaxlib
+    return str(jaxlib.__version__)
 
 
 def current_device_kind() -> str:
     """``<platform>/<device_kind>`` of the first local device — one half
     of the store key: an artifact exported for one backend must never
     deserialize onto another."""
-    try:
-        import jax
-        d = jax.local_devices()[0]
-        return f"{d.platform}/{getattr(d, 'device_kind', d.platform)}"
-    except Exception:
-        return "unknown"
+    import jax
+    d = jax.local_devices()[0]
+    return f"{d.platform}/{d.device_kind}"
 
 
 def export_bytes(jitted_fn: Callable, args: Tuple[Any, ...]) -> bytes:

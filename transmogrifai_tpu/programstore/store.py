@@ -350,7 +350,7 @@ def snapshot() -> Dict[str, Any]:
                      "loaded": len(s.loaded)}
                     for s in _SESSIONS.values()]
         captures = len(_CAPTURES)
-    return {"enabled": aot_enabled(), "supported": _aot.aot_supported(),
+    return {"enabled": aot_enabled(),
             "sessions": sessions, "captures": captures, "stats": stats()}
 
 
@@ -388,10 +388,10 @@ def open_model_session(model_dir: str) -> Optional[_Session]:
     """Open (or refresh) the session over ``model_dir``'s manifest
     ``programs`` section — called by ``registry.load``/``swap`` BEFORE
     the warm pre-trace so every lookup can hit. Returns None (and opens
-    nothing) when the store is disabled, unsupported, or the manifest
+    nothing) when the store is disabled, or the manifest
     carries no (or a corrupt) ``programs`` section — all of which simply
     mean the existing trace path runs."""
-    if not aot_enabled() or not _aot.aot_supported():
+    if not aot_enabled():
         return None
     try:
         from ..manifest import CheckpointManifest
@@ -428,7 +428,7 @@ def open_env_session() -> Optional[_Session]:
     programs at train time live here; opened lazily on first use, entries
     read from the store metas — there is no manifest for it)."""
     d = os.environ.get(STORE_ENV)
-    if not d or not aot_enabled() or not _aot.aot_supported():
+    if not d or not aot_enabled():
         return None
     store = ProgramStore(d)
     with _LOCK:
@@ -634,8 +634,8 @@ def capture(model_dir: str):
     """Populate scope over ``model_dir``: traced first-bucket dispatches
     inside the block are exported into ``<model_dir>/programs/`` and
     committed into the manifest ``programs`` section on exit. No-op
-    context when the store is disabled/unsupported."""
-    if not aot_enabled() or not _aot.aot_supported():
+    context when the store is disabled."""
+    if not aot_enabled():
         yield None
         return
     cap = _Capture(ProgramStore(os.path.join(model_dir, PROGRAMS_DIR)),
@@ -670,7 +670,7 @@ def offer_segment(fingerprint: str, bucket: int, jitted_fn: Callable,
     env_sess = open_env_session() if os.environ.get(STORE_ENV) else None
     if env_sess is not None:
         targets.append((env_sess.store, None))
-    if not targets or not aot_enabled() or not _aot.aot_supported():
+    if not targets or not aot_enabled():
         return 0
     key = {"fingerprint": fingerprint, "bucket": int(bucket),
            "jaxlib": _aot.current_jaxlib(),
@@ -743,7 +743,7 @@ def populate_for_save(model, path: str, rows: Optional[int] = None) -> int:
     defers population to the first warm load). The export reconstructs
     each segment's traced avals from the plan's zero-row probe — no
     dispatch, no device work. Returns segments exported; never raises."""
-    if not save_populate_enabled() or not _aot.aot_supported():
+    if not save_populate_enabled():
         return 0
     try:
         from .. import plan as _plan

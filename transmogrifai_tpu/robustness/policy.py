@@ -28,7 +28,7 @@ from .resources import is_resource_exhausted
 
 #: substrings (lowercased) marking an error transient: the gRPC-style
 #: status codes surfaced by jax/PJRT transfer failures plus socket-level
-#: resets on tunneled backends
+#: resets
 TRANSIENT_PATTERNS = (
     "unavailable", "deadline exceeded", "deadline_exceeded", "data_loss",
     "connection reset", "connection refused", "broken pipe", "socket",

@@ -72,6 +72,28 @@ class ReplicaLostError(ServingError):
     failover budget produces when NO survivor remains."""
 
 
+class ChipHeldError(ReplicaLostError):
+    """A subprocess replica cannot start: this process has initialised
+    JAX on an accelerator, and an accelerator belongs to one process at
+    a time — the worker would fail or hang claiming it. The subprocess
+    tier needs one chip per worker and a parent that stays off JAX
+    (docs/serving.md "Replica fleet & front door")."""
+
+
+def _parent_holds_accelerator() -> Optional[str]:
+    """The accelerator platform this process has already initialised
+    (None: jax not imported, no backend initialised yet, or CPU). Never
+    initialises a backend itself — that would claim the chip."""
+    if "jax" not in sys.modules:
+        return None
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    import jax
+    platform = jax.default_backend()
+    return None if platform == "cpu" else platform
+
+
 class AdmissionRefusedError(OverloadError):
     """Pre-flight admission control refused the request: the predicted
     flush bytes exceed ``TG_DEVICE_BUDGET`` even at the minimum padding
@@ -306,20 +328,34 @@ class SubprocessReplica:
         cmd += ["--max-batch", str(cfg.max_batch),
                 "--queue-max", str(cfg.max_queue),
                 "--max-wait-ms", str(cfg.max_wait_ms)]
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # the worker inherits this process's environment — and with it
+        # the device JAX would pick here. No CPU default is slipped in: a
+        # replica silently scoring on the host is not the fleet asked for
+        held = _parent_holds_accelerator()
+        if held is not None:
+            raise ChipHeldError(
+                f"subprocess replica '{rid}' would need the {held} device "
+                f"this process already holds; one chip serves one process "
+                f"— use in-process replicas, or spawn the fleet from a "
+                f"parent that has not initialised JAX, one chip per worker")
         self._proc = subprocess.Popen(
             cmd, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            stderr=subprocess.DEVNULL, text=True, env=env)
+            stderr=subprocess.DEVNULL, text=True)
         self._ready = threading.Event()
         self._reader = threading.Thread(
             target=self._read_loop, name=f"tg-fleet-io[{rid}]", daemon=True)
         self._reader.start()
-        if not self._ready.wait(timeout=spawn_timeout_s):
+        # the reader also sets _ready when the pipe closes: a worker that
+        # dies during start-up fails the spawn at once, not at the timeout
+        ready = self._ready.wait(timeout=spawn_timeout_s)
+        if not ready or self._dead:
             self.kill()
             raise ReplicaLostError(
-                f"subprocess replica '{rid}' not ready within "
-                f"{spawn_timeout_s:.0f}s")
+                f"subprocess replica '{rid}' "
+                + (f"exited during start-up (code {self._proc.returncode}; "
+                   f"run `{' '.join(cmd[1:])}` by hand for its error)"
+                   if ready else
+                   f"not ready within {spawn_timeout_s:.0f}s"))
 
     @property
     def dead(self) -> bool:
@@ -382,6 +418,7 @@ class SubprocessReplica:
             # future fails AS replica loss, which the front door fails
             # over (zero lost futures even on SIGKILL)
             self._dead = True
+            self._ready.set()
             with self._plock:
                 pending = list(self._pending.values())
                 self._pending.clear()

@@ -1,28 +1,39 @@
 """Persistent XLA compilation cache setup.
 
-First compilation of each jitted program costs seconds (tens of seconds on
-remote-compile backends); the reference has no analog cost because Spark
-plans interpret immediately. Enabling jax's persistent compilation cache
-makes every run after the first skip straight to execution for unchanged
-program shapes. Applied once, lazily, from the modules that first touch jax;
-a user-set ``jax_compilation_cache_dir`` (or ``JAX_COMPILATION_CACHE_DIR``)
-always wins.
+First compilation of each jitted program costs seconds to minutes; the
+reference has no analog cost because Spark plans interpret immediately.
+jax's persistent compilation cache lets every run after the first skip
+straight to execution for unchanged program shapes. Applied once, lazily,
+from the modules that first touch jax.
+
+The rule: a cache placed from outside wins — when
+``JAX_COMPILATION_CACHE_DIR`` (or ``jax.config.jax_compilation_cache_dir``)
+is set, nothing is set here. Otherwise the cache lives in ONE fixed
+directory inside the checkout (``CACHE_DIR``, git-ignored): the directory
+is part of the cache key's life — a cache that moves never hits.
 """
 from __future__ import annotations
 
+import logging
 import os
 import threading
 from typing import Dict
 
+logger = logging.getLogger(__name__)
+
+#: the in-checkout cache directory used when none is placed from outside
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
 _done = False
 
 # -- compile-cache hit/miss accounting ---------------------------------------
-# jax announces persistent-cache outcomes through its internal monitoring
-# events ('/jax/compilation_cache/cache_hits' / 'cache_misses'); a
-# best-effort listener folds them into plain process counters that
-# StageProfiler.app_metrics() and observability.summarize() report, and that
-# sweep spans diff to tag each family branch hit/miss. The monitoring module
-# is private API — if it moves, the counters simply stay at zero.
+# jax announces persistent-cache outcomes through its monitoring events
+# ('/jax/compilation_cache/cache_hits' / 'cache_misses'); a listener folds
+# them into plain process counters that StageProfiler.app_metrics() and
+# observability.summarize() report, and that sweep spans diff to tag each
+# family branch hit/miss.
 _CACHE_EVENTS: Dict[str, int] = {"hits": 0, "misses": 0}
 _listener_lock = threading.Lock()
 _listener_done = False
@@ -40,18 +51,15 @@ def _install_listener() -> None:
         if _listener_done:
             return
         _listener_done = True
-    try:
-        from jax._src import monitoring
+    import jax.monitoring
 
-        def _on_event(event: str, **kw) -> None:
-            if event == "/jax/compilation_cache/cache_hits":
-                record_cache_event(True)
-            elif event == "/jax/compilation_cache/cache_misses":
-                record_cache_event(False)
+    def _on_event(event: str, **kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            record_cache_event(True)
+        elif event == "/jax/compilation_cache/cache_misses":
+            record_cache_event(False)
 
-        monitoring.register_event_listener(_on_event)
-    except Exception:
-        pass  # counters stay zero; never break compilation for telemetry
+    jax.monitoring.register_event_listener(_on_event)
 
 
 def cache_stats() -> Dict[str, int]:
@@ -66,25 +74,13 @@ def ensure_compilation_cache() -> None:
         return
     _done = True
     _install_listener()
+    import jax
+    if jax.config.jax_compilation_cache_dir:
+        return  # placed from outside (environment or jax.config)
     try:
-        import jax
-        # partition-invariant counter-based threefry: the RF/GBT bootstrap
-        # streams (models/trees.py jax.random calls inside sharded fit
-        # programs) must generate the SAME bits whether the sweep runs on
-        # one device or row-sharded over the mesh 'data' axis — the legacy
-        # stream is not partition-stable and forces XLA to serialize the
-        # generator. jax flipped this default back and forth across 0.4.x;
-        # pin it (an explicit user/env setting still wins).
-        if "JAX_THREEFRY_PARTITIONABLE" not in os.environ:
-            jax.config.update("jax_threefry_partitionable", True)
-        if jax.config.jax_compilation_cache_dir:
-            return  # user already configured one
-        d = os.environ.get(
-            "TRANSMOGRIFAI_TPU_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache",
-                         "transmogrifai_tpu", "jax"))
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass  # cacheless operation is only slower, never wrong
+        os.makedirs(CACHE_DIR, exist_ok=True)
+    except OSError as e:  # read-only checkout: cacheless is only slower
+        logger.warning("compile cache directory %s is not writable (%s); "
+                       "running without a persistent cache", CACHE_DIR, e)
+        return
+    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
