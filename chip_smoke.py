@@ -1,7 +1,7 @@
 """Chip smoke: train -> score -> serve on the TPU through the normal entry
 points, plus the five Pallas kernels against their XLA twins.
 
-    python chip_smoke.py [--seed N] [--rows N]
+    python chip_smoke.py [--seed N]
 
 The quickest proof that the system still starts on the chip. One process,
 data made from ``--seed``, no network. The shape is the repository's
@@ -506,6 +506,15 @@ def phase_serve(model, data, lo: int, hi: int) -> Dict[str, Any]:
 # Phase: four chips
 # ---------------------------------------------------------------------------
 
+def _device_memory(devices) -> List[Dict[str, int]]:
+    """``memory_stats()`` of each device; a device that reports none fails
+    the run (the check below could not see it)."""
+    stats = [dev.memory_stats() for dev in devices]
+    silent = [str(d) for d, st in zip(devices, stats) if st is None]
+    check(not silent, f"mesh: no memory_stats() from {silent}")
+    return stats
+
+
 def phase_mesh(table, holdout_table, single: Dict[str, Any],
                single_auroc: float) -> Dict[str, Any]:
     """Item 1's train and score under a ``data=4`` mesh, same process: no
@@ -517,28 +526,41 @@ def phase_mesh(table, holdout_table, single: Dict[str, Any],
 
     devices = jax.devices()[:4]
     mesh = make_mesh(MeshSpec(data=4, model=1), devices=devices)
+    before = _device_memory(devices)
     out = phase_train(table, mesh=mesh)
-    downgrades = sum(obs_metrics.registry().snapshot().get(
-        "tg_mesh_downgrade_total", {}).values())
-    sharding = out["selector"].validator.last_sweep_sharding
+    validator = out["selector"].validator
+    sharding, shards = validator.last_sweep_sharding, validator.last_sweep_shards
     held = out["model"].score(table=holdout_table)
+    check(bool(np.all(np.isfinite(np.asarray(
+        held[out["pred"].name].values)))), "mesh: non-finite score")
     auc = auroc(_scores(held, out["pred"]),
                 np.asarray(holdout_table["label"].values))
-    width = int(np.asarray(held[out["checked"].name].values).shape[1])
-    shard_bytes = table.num_rows // 4 * width * 4
-    # None on backends that report no memory statistics
-    stats = [dev.memory_stats() for dev in devices]
-    peaks = [int(s["peak_bytes_in_use"]) for s in stats if s is not None]
+    after = _device_memory(devices)
+    downgrades = sum(obs_metrics.registry().snapshot().get(
+        "tg_mesh_downgrade_total", {}).values())
+    # peak_bytes_in_use is a process-lifetime peak: the first device's
+    # includes the single-chip phases, the others were idle until now
+    peaks = [(int(b["peak_bytes_in_use"]), int(a["peak_bytes_in_use"]))
+             for b, a in zip(before, after)]
     print(f"mesh observed: winner {out['family']} {out['hyper']} "
-          f"(CV {out['metric']:.6f}), held-out AuROC {auc:.6f}, "
-          f"downgrades {downgrades}, sweep sharding {sharding}, "
-          f"peak bytes {peaks} (one input shard: {shard_bytes})",
-          flush=True)
+          f"(CV {out['metric']:.6f}), held-out AuROC {auc:.10f}, "
+          f"downgrades {downgrades}, sweep sharding {sharding}, shards "
+          f"{shards}, peak bytes (before, after) {peaks}", flush=True)
 
     check(downgrades == 0, f"mesh: {downgrades} sweep downgrade(s)")
     check(sharding is not None and set(sharding.device_set) == set(devices),
           f"mesh: sweep inputs were placed as {sharding}")
-    for dev, peak in zip(devices, peaks):
+    # one shard of the sweep's table per device, all of one shape, together
+    # at least the rows the splitter leaves for training
+    shapes = {shape for _, shape in shards}
+    check(sorted(d.id for d, _ in shards) == sorted(d.id for d in devices)
+          and len(shapes) == 1,
+          f"mesh: sweep table shards are {shards}")
+    (shard_rows, width), = shapes
+    check(4 * shard_rows >= table.num_rows // 2,
+          f"mesh: shards of {shard_rows} rows for {table.num_rows}")
+    shard_bytes = shard_rows * width * 4
+    for dev, (_, peak) in zip(devices, peaks):
         check(peak >= shard_bytes,
               f"mesh: {dev} peaked at {peak} bytes, below one input shard "
               f"({shard_bytes})")
@@ -556,7 +578,8 @@ def phase_mesh(table, holdout_table, single: Dict[str, Any],
               f"mesh: winner {out['hyper']} vs single-chip "
               f"{single['hyper']} (CV metric gap {gap:.3g})")
     return {"family": out["family"], "hyper": out["hyper"], "auroc": auc,
-            "fits": out["fits"], "peakBytes": peaks}
+            "fits": out["fits"], "shards": [shape for _, shape in shards],
+            "peakBytes": [peak for _, peak in peaks]}
 
 
 # ---------------------------------------------------------------------------
@@ -566,9 +589,6 @@ def phase_mesh(table, holdout_table, single: Dict[str, Any],
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--rows", type=int, default=ROWS,
-                    help="train rows (default: the documented full-train "
-                         "shape; cut only if the time limit forces it)")
     args = ap.parse_args(argv)
 
     import jax
@@ -608,7 +628,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     print(f"native libraries in use: {report['native']}", flush=True)
     report["kernelErr"] = timed("kernels", phase_kernels, args.seed)
 
-    n = args.rows
+    n = ROWS
     data = timed("data", make_data, n + HOLDOUT_ROWS, args.seed)
     table = table_of(data, 0, n)
     holdout = table_of(data, n, n + HOLDOUT_ROWS)

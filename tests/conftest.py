@@ -198,12 +198,19 @@ def _no_mesh_sharding_leak():
     whole session. Assert no ambient mesh context on entry and exit;
     hard-drop mesh-keyed programs on exit (mesh tests recompile cheaply —
     CPU programs — and must not subsidize later tests)."""
+    import jax
     from jax._src import mesh as _jmesh
 
     from transmogrifai_tpu.impl.tuning import validators as _validators
 
     def _ambient_mesh():
+        # ``with mesh:`` lands in thread_resources; ``jax.set_mesh`` (what
+        # histeng.engine_mesh enters) lands in the abstract-mesh context,
+        # which is part of every jit cache key
         m = _jmesh.thread_resources.env.physical_mesh
+        if not m.empty:
+            return m
+        m = jax.sharding.get_abstract_mesh()
         return None if m.empty else m
 
     assert _ambient_mesh() is None, (

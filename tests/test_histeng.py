@@ -103,6 +103,36 @@ def test_pinned_kernel_bit_exact_under_mesh_sharding(monkeypatch):
     np.testing.assert_array_equal(sharded, plain)
 
 
+def test_build_node_hist_under_jit_with_engine_mesh(monkeypatch):
+    """The node-resolved entry point, jitted and traced under
+    `engine_mesh`, shards its row blocks and gives the single-device bits.
+    `engine_mesh` enters ``jax.set_mesh``, so it wraps the jitted call from
+    outside — entering it inside a trace is refused by jax."""
+    monkeypatch.setenv("TG_TREE_PALLAS", "0")
+    rng = np.random.RandomState(5)
+    S, d, nb, T, Wl = 257, 5, 8, 3, 4
+    codes = jnp.asarray(rng.randint(0, nb, (S, d)).astype(np.int32))
+    node = jnp.asarray(rng.randint(0, Wl, (S, T)).astype(np.int32))
+    sw = jnp.asarray(rng.randn(S, T).astype(np.float32))
+    plain = np.asarray(histeng.build_node_hist(codes, node, [sw], nb,
+                                               n_nodes=Wl))
+    mesh = make_mesh(MeshSpec(data=4, model=2))
+    fn = jax.jit(lambda c, n, s: histeng.build_node_hist(c, n, [s], nb,
+                                                         n_nodes=Wl))
+    with histeng.engine_mesh(mesh):
+        sharded = np.asarray(fn(codes, node, sw))
+    assert histeng.current_engine_mesh() is None
+    assert jax.sharding.get_abstract_mesh().empty
+    np.testing.assert_array_equal(sharded, plain)
+
+    def inside(c, n, s):
+        with histeng.engine_mesh(mesh):
+            return histeng.build_node_hist(c, n, [s], nb, n_nodes=Wl)
+    with pytest.raises(ValueError, match="set_mesh"):
+        jax.jit(inside)(codes, node, sw)
+    assert histeng.current_engine_mesh() is None
+
+
 def test_build_node_hist_device_layout_matches_flat_kernel():
     """The structured (k, n_nodes, T, d, nb) output is a pure reshape of
     the flat kernel's lane layout."""

@@ -238,6 +238,45 @@ def test_downgraded_sweep_is_bit_identical_and_observable():
     assert not mesh_program_keys()
 
 
+def test_downgraded_sweep_gathers_sharded_rows_onto_one_device(monkeypatch):
+    """A downgraded sweep is single-device for its INPUTS too. Rows that an
+    upstream mesh stage left sharded would turn the fused one-device program
+    into a GSPMD program; on the chip a tree family's Mosaic kernels (traced
+    with no engine mesh) then refuse to lower — "Mosaic kernels cannot be
+    automatically partitioned", PR 21's four-chip run of a 3-fit forest
+    sweep."""
+    from collections import OrderedDict
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from transmogrifai_tpu.impl.tuning import validators as V
+
+    X, y = _synth(n=332)
+    mesh = make_mesh(MeshSpec(data=4, model=2))
+    Xs = jax.device_put(X, NamedSharding(mesh, P("data", None)))
+    ys = jax.device_put(y, NamedSharding(mesh, P("data")))
+    seen = []
+    build = V._make_fused_program
+
+    def spying_build(*a, **kw):
+        prog, grid_keys = build(*a, **kw)
+
+        def spy(*args):
+            seen.extend(len(x.sharding.device_set) for x in args)
+            return prog(*args)
+        return spy, grid_keys
+    monkeypatch.setattr(V, "_make_fused_program", spying_build)
+    monkeypatch.setattr(V, "_FUSED_CACHE", OrderedDict())   # force a build
+    models = _models(("OpLogisticRegression", LR_GRID))
+    down = OpCrossValidation(num_folds=3, seed=7, mesh=mesh).validate(
+        models, Xs, ys, "binary", "AuPR", True, 2)
+    assert seen and set(seen) == {1}
+    plain = OpCrossValidation(num_folds=3, seed=7).validate(
+        models, X, y, "binary", "AuPR", True, 2)
+    np.testing.assert_array_equal(down.results[0].fold_metrics,
+                                  plain.results[0].fold_metrics)
+
+
 # ---------------------------------------------------------------------------
 # donation safety
 # ---------------------------------------------------------------------------

@@ -58,8 +58,9 @@ __all__ = [
 @contextmanager
 def engine_mesh(mesh):
     """Activate ``mesh`` as the engine's sharding target for the duration of
-    the block (``None``: no-op). Must wrap the *trace* (the first call of a
-    jitted fit / fused program, and any re-trace such as AOT export) — the
+    the block (``None``: no-op). Must wrap the *trace* from outside (the
+    first call of a jitted fit / fused program, and any re-trace such as AOT
+    export; ``jax.set_mesh`` refuses to be entered inside a trace) — the
     kernels read the context at trace time, like their env knobs.
 
     ``jax.set_mesh`` puts the mesh into jax's trace context, which is part
@@ -89,7 +90,7 @@ def build_hist(codes, A, n_bins: int, exact: bool = False):
 
 
 def build_node_hist(codes, node, stats: Sequence, n_bins: int, *,
-                    n_nodes: int = 1, stride: int = 1, mesh=None,
+                    n_nodes: int = 1, stride: int = 1,
                     backend: Optional[str] = None):
     """(node, feature, bin) sufficient statistics — the one tree-growth
     primitive shared by in-core growers, StreamingGBT, and the mesh sweep.
@@ -108,9 +109,9 @@ def build_node_hist(codes, node, stats: Sequence, n_bins: int, *,
     (k, n_nodes, d, n_bins) f64 — no tree axis, streamed growth is
     single-tree per pass.
 
-    ``mesh``: shard the build's row blocks over that mesh's 'data' axis
-    (equivalent to tracing under `engine_mesh`; the fused sweep path uses
-    the context form).
+    To shard the build's row blocks over a mesh's 'data' axis, trace the
+    caller under `engine_mesh` (from outside any jit: it enters
+    ``jax.set_mesh``).
     """
     if backend not in (None, "host", "xla", "pallas"):
         raise ValueError(f"unknown histogram backend {backend!r}")
@@ -120,10 +121,8 @@ def build_node_hist(codes, node, stats: Sequence, n_bins: int, *,
         if stride != 1:
             raise ValueError("host histogram backend is stride-1 only")
         return build_node_hist_host(codes, node, stats, n_bins, n_nodes)
-    import jax.numpy as jnp
-    with engine_mesh(mesh):
-        flat = node_hist_matmul(codes, node, list(stats), n_nodes, n_bins,
-                                stride=stride)
+    flat = node_hist_matmul(codes, node, list(stats), n_nodes, n_bins,
+                            stride=stride)
     k = len(stats)
     T = node.shape[1]
     d = codes.shape[1]

@@ -367,10 +367,12 @@ class OpValidator:
         #: (OpValidator.getSummary:270-312 full-data fits) at several times
         #: the sweep cost
         self.exact_sweep_fits = exact_sweep_fits
-        #: wiring attr: where the last ENGAGED mesh sweep placed its table
-        #: (None until one ran) — lets a multi-chip check assert every
-        #: device held shards, like SanityChecker._stats_input_sharding
+        #: wiring attrs: where the last ENGAGED mesh sweep placed its table
+        #: (None until one ran) and the (device, shard shape) of every shard
+        #: it held — lets a multi-chip check assert every device held
+        #: shards, like SanityChecker._stats_input_sharding
         self.last_sweep_sharding = None
+        self.last_sweep_shards = None
 
     # -- fold construction ---------------------------------------------------
     def make_splits(self, y: np.ndarray) -> np.ndarray:
@@ -454,6 +456,12 @@ class OpValidator:
                 _obs_trace.add_event("sweep.mesh_downgrade", **detail)
                 logger.info("mesh sweep downgraded to single-device: %s",
                             detail)
+                # "single-device" must hold for the inputs too: rows an
+                # upstream mesh stage left sharded would turn the fused
+                # one-device program into a GSPMD program, and a tree
+                # family's Mosaic kernels (traced with no engine mesh)
+                # cannot be partitioned — the compiler refuses them
+                X, y = jax.device_put((X, y), mesh.devices.flat[0])
                 mesh = None
         # bucket the row count so every fit/predict/metric program is reused
         # across datasets/folds/stages (utils/padding.py); under a mesh the
@@ -571,6 +579,8 @@ class OpValidator:
             ids_d = retrying_device_put(ids_d, row_sh,
                                         site="sweep.table_upload")
             self.last_sweep_sharding = X.sharding
+            self.last_sweep_shards = [(sh.device, tuple(sh.data.shape))
+                                      for sh in X.addressable_shards]
 
         def _dispatch(family, grid):
             """One family's sweep branch with adaptive degradation under
