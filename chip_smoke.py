@@ -16,7 +16,9 @@ second time under a ``data=4`` mesh and must agree with the first.
 Exits non-zero unless ``jax.default_backend() == "tpu"``. No phase is
 wrapped in a ``try`` that lets the script reach exit 0: any exception, and
 any non-empty fault section, fails the run. The walls it prints are smoke
-timings, not metrics. The last stdout line is one JSON object.
+timings, not metrics. A passing run ends with two JSON lines on stdout: the
+summary (phases, walls, compile-cache counts, ``"claim": null``) and, last,
+``{"ok": true, "device": {"platform", "kind", "count"}}``.
 """
 from __future__ import annotations
 
@@ -648,11 +650,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                                report["score"]["auroc"])
     check_no_faults("end of run")
 
-    print(json.dumps({"smokeTimingsSecs": walls, **report},
-                     default=str), flush=True)
-    print(json.dumps({"ok": True, "device": device, "rows": n,
+    print(json.dumps({"smokeTimingsSecs": walls, **report, "rows": n,
                       "seed": args.seed, "compileCache": cache_stats(),
-                      "claim": None}), flush=True)
+                      "claim": None}, default=str), flush=True)
+    # the driver reads the last line: exactly these keys
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

@@ -2,6 +2,8 @@
 serve at a few thousand rows, ``main()`` refuses a backend that is not a
 TPU, and a recovery anywhere on the path fails the smoke's fault check
 (on the chip a fallback is a bug, not resilience)."""
+import json
+
 import numpy as np
 import pytest
 
@@ -40,6 +42,39 @@ def test_main_refuses_a_backend_that_is_not_a_tpu(capsys):
     captured = capsys.readouterr()
     assert captured.out == ""            # no result line
     assert "no accelerator" in captured.err
+
+
+def test_a_passing_run_ends_with_the_result_line(monkeypatch, capsys):
+    """The driver reads the last stdout line: exactly ``ok`` and ``device``
+    (``platform``, ``kind``, ``count``). The summary, ``"claim": null``
+    included, is the line before it. Phases are stubbed: this drives
+    main()'s own head and tail."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(cs, "ROWS", 64)
+    monkeypatch.setattr(cs, "HOLDOUT_ROWS", 16)
+    monkeypatch.setattr(cs, "phase_native", lambda: {"textops": True})
+    monkeypatch.setattr(cs, "phase_kernels", lambda seed: {"k": 0.0})
+    monkeypatch.setattr(cs, "phase_train", lambda table, mesh=None: {
+        "model": None, "pred": None, "family": "OpLogisticRegression",
+        "hyper": {}, "metric": 0.99, "fits": cs.DEFAULT_GRID_FITS})
+    monkeypatch.setattr(cs, "phase_score", lambda *a: {"auroc": 0.99})
+    monkeypatch.setattr(cs, "phase_serve", lambda *a: {"requests": 1})
+    monkeypatch.setattr(cs, "phase_mesh", lambda *a: {"auroc": 0.99})
+
+    assert cs.main(["--seed", "3"]) == 0
+    lines = capsys.readouterr().out.strip().splitlines()
+    last = json.loads(lines[-1])
+    assert set(last) == {"ok", "device"} and last["ok"] is True
+    dev = jax.devices()[0]
+    assert last["device"] == {"platform": dev.platform,
+                              "kind": dev.device_kind,
+                              "count": len(jax.devices())}
+    assert isinstance(last["device"]["count"], int)
+    summary = json.loads(lines[-2])
+    assert lines[-2].endswith('"claim": null}')
+    assert summary["seed"] == 3 and summary["rows"] == 64
+    assert "hits" in summary["compileCache"]
 
 
 @pytest.mark.chaos
