@@ -62,17 +62,7 @@ def validate_dag(result_features: Sequence[Feature]) -> None:
             seen_stage[st.uid] = st
 
 
-class _NullProfiler:
-    def track(self, stage, op, layer=-1):
-        import contextlib
-        return contextlib.nullcontext()
-
-
-_NULL_PROFILER = _NullProfiler()
-
-
 def fit_and_transform_dag(table: FeatureTable, layers: List[StageLayer],
-                          profiler: Optional[Any] = None,
                           checkpoint: Optional[Any] = None,
                           preloaded: Optional[Dict[str, Any]] = None,
                           retry_policy: Optional[Any] = None,
@@ -96,7 +86,6 @@ def fit_and_transform_dag(table: FeatureTable, layers: List[StageLayer],
     """
     from .robustness import faults
     from .robustness.policy import FaultLog, FaultReport
-    prof = profiler or _NULL_PROFILER
     pre = preloaded or {}
     fitted: Dict[str, Any] = {}
     for li, layer in enumerate(layers):
@@ -124,8 +113,7 @@ def fit_and_transform_dag(table: FeatureTable, layers: List[StageLayer],
                         with _obs_span("stage.fit", cat="train",
                                        uid=stage.uid,
                                        stage=type(stage).__name__,
-                                       layer=li), \
-                                prof.track(stage, "fit", li):
+                                       layer=li):
                             return stage.fit(table)
                     if retry_policy is not None:
                         model = retry_policy.execute(
@@ -141,45 +129,41 @@ def fit_and_transform_dag(table: FeatureTable, layers: List[StageLayer],
             else:
                 raise TypeError(f"unexpected stage kind {type(stage).__name__}")
         table = _transform_stages(table, models, cat="train", layer=li,
-                                  profiler=profiler,
                                   retry_policy=retry_policy)
     return table, fitted
 
 
 def _transform_stages(table: FeatureTable, models: Sequence[Any], *,
                       cat: str, layer: int = -1,
-                      profiler: Optional[Any] = None,
                       retry_policy: Optional[Any] = None) -> FeatureTable:
     """Run a topologically-ordered transformer sequence: as a compiled plan
     (one XLA program per device-fusable segment, ``plan.apply_planned``)
     when eligible, else eagerly stage by stage.
 
-    Eager runs whenever per-stage semantics matter: a profiler wants
-    per-stage wall-clock, a retry policy wants per-stage fault isolation
-    (PR 1), or chaos is active (``plan.planning_applicable``). A planned
+    Eager runs whenever per-stage semantics matter: a retry policy wants
+    per-stage fault isolation (PR 1), or chaos is active
+    (``plan.planning_applicable``). A profiler is no reason: it reads the
+    spans of whichever path ran (``utils/profiler.py``). A planned
     run that raises falls back to eager for the run — recorded, never
     silent — so results are identical either way."""
     from . import plan as _plan
-    if profiler is None and retry_policy is None and len(models) > 1:
+    if retry_policy is None and len(models) > 1:
         # ≥2 fusable stages: a lone-stage run gains nothing over eager
         # dispatch but would still pay the plan's probe/compile cost
         out = _plan.apply_planned(models, table, keep_intermediates=True,
                                   cat=cat, min_device_stages=2)
         if out is not None:
             return out
-    prof = profiler or _NULL_PROFILER
     for model in models:
         _plan.count_eager_dispatch(model)
         with _obs_span("stage.transform", cat=cat,
                        uid=getattr(model, "uid", "?"),
-                       stage=type(model).__name__, layer=layer), \
-                prof.track(model, "transform", layer):
+                       stage=type(model).__name__, layer=layer):
             table = model.transform(table)
     return table
 
 
-def apply_transformations_dag(table: FeatureTable, layers: List[StageLayer],
-                              profiler: Optional[Any] = None,
+def apply_transformations_dag(table: FeatureTable, layers: List[StageLayer]
                               ) -> FeatureTable:
     """Score-time pass: all stages must already be transformers (reference
     OpWorkflowCore.applyTransformationsDAG:321-345). The flattened
@@ -192,4 +176,4 @@ def apply_transformations_dag(table: FeatureTable, layers: List[StageLayer],
                     f"stage {stage.uid} is an unfitted estimator; "
                     "score requires a fitted workflow model")
     flat = [stage for layer in layers for stage, _ in layer]
-    return _transform_stages(table, flat, cat="score", profiler=profiler)
+    return _transform_stages(table, flat, cat="score")

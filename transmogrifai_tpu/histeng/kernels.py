@@ -335,6 +335,7 @@ def _make(n_bins: int, exact: bool = False):
     return hist
 
 
+@jax.named_scope("hist.build")
 def hist_matmul(codes: jnp.ndarray, A: jnp.ndarray,
                 n_bins: int, exact: bool = False) -> jnp.ndarray:
     """hist[a, f*n_bins + b] = Σ_s A[s, a]·1[codes[s, f] == b], f32.
@@ -381,6 +382,7 @@ def _node_hist_xla(codes, node, sws, Wl_eff, n_bins, stride, k, exact=False):
 
 
 
+@jax.named_scope("hist.build")
 def node_hist_matmul(codes: jnp.ndarray, node: jnp.ndarray,
                      sw_list, Wl: int, n_bins: int,
                      stride: int = 1) -> jnp.ndarray:

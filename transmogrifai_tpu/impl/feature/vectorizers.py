@@ -21,6 +21,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ...features import Feature
+from ...observability.trace import span as _obs_span
+from ...parallel.distributed import _count_transfer_bytes
 from ...stages.base import Estimator, SequenceTransformer, Transformer, UnaryTransformer
 from ...table import Column, FeatureTable
 from ...types import (
@@ -348,17 +350,22 @@ class OneHotVectorizer(Estimator):
     def fit(self, table: FeatureTable) -> Transformer:
         vocabs: List[List[str]] = []
         for f in self.input_features:
-            col = table[f.name]
-            vals = np.asarray(col.values)
-            m = col.valid_mask()
-            if col.kind == "multipicklist":
-                cnt = Counter(v for vs, ok in zip(vals, m) if ok for v in (vs or ()))
-            else:
-                cnt = Counter(str(v) for v, ok in zip(vals, m) if ok)
-            top = [v for v, c in cnt.most_common() if c >= self.min_support]
-            # deterministic: count desc then value asc
-            top = sorted(top, key=lambda v: (-cnt[v], v))[: self.top_k]
-            vocabs.append(top)
+            with _obs_span("onehot.count", cat="train", column=f.name,
+                           rows=table.num_rows) as count_span:
+                col = table[f.name]
+                vals = np.asarray(col.values)
+                m = col.valid_mask()
+                if col.kind == "multipicklist":
+                    cnt = Counter(v for vs, ok in zip(vals, m) if ok
+                                  for v in (vs or ()))
+                else:
+                    cnt = Counter(str(v) for v, ok in zip(vals, m) if ok)
+                top = [v for v, c in cnt.most_common()
+                       if c >= self.min_support]
+                # deterministic: count desc then value asc
+                top = sorted(top, key=lambda v: (-cnt[v], v))[: self.top_k]
+                vocabs.append(top)
+                count_span.set_attr(levels=len(cnt))
         model = OneHotVectorizerModel(vocabs=vocabs, track_nulls=self.track_nulls)
         return self._finalize_model(model)
 
@@ -380,34 +387,41 @@ class OneHotVectorizerModel(_VectorModelBase):
             block = np.zeros((n, k + 1 + (1 if self.track_nulls else 0)),
                              dtype=np.float32)
             index = {v: i for i, v in enumerate(vocab)}
-            if col.kind == "multipicklist":
-                for i, (vs, ok) in enumerate(zip(vals, m)):
-                    if not ok:
-                        continue
-                    for v in (vs or ()):
-                        j = index.get(v)
-                        if j is None:
-                            block[i, k] = 1.0
-                        else:
-                            block[i, j] = 1.0
-            else:
-                codes = np.full(n, -2, dtype=np.int64)  # -2 null, -1 OTHER
-                svals = np.array([str(v) if ok else "" for v, ok in zip(vals, m)],
-                                 dtype=object)
-                for i_ok in np.nonzero(m)[0]:
-                    codes[i_ok] = index.get(svals[i_ok], -1)
-                rows = np.arange(n)
-                hit = codes >= 0
-                block[rows[hit], codes[hit]] = 1.0
-                block[rows[codes == -1], k] = 1.0
-            if self.track_nulls:
-                block[~m, k + 1] = 1.0
+            multi = col.kind == "multipicklist"
+            # values to codes: the str() pass and the dictionary loop (a
+            # multi-valued column has no code per row and fills its block
+            # as it goes)
+            with _obs_span("onehot.encode", column=f.name):
+                if multi:
+                    for i, (vs, ok) in enumerate(zip(vals, m)):
+                        if not ok:
+                            continue
+                        for v in (vs or ()):
+                            block[i, index.get(v, k)] = 1.0
+                else:
+                    codes = np.full(n, -2, dtype=np.int64)  # -2 null, -1 OTHER
+                    svals = np.array([str(v) if ok else ""
+                                      for v, ok in zip(vals, m)], dtype=object)
+                    for i_ok in np.nonzero(m)[0]:
+                        codes[i_ok] = index.get(svals[i_ok], -1)
+            # codes to the dense block
+            with _obs_span("onehot.expand", column=f.name):
+                if not multi:
+                    rows = np.arange(n)
+                    hit = codes >= 0
+                    block[rows[hit], codes[hit]] = 1.0
+                    block[rows[codes == -1], k] = 1.0
+                if self.track_nulls:
+                    block[~m, k + 1] = 1.0
             blocks.append(block)
             mc = [(f.name, v) for v in vocab] + [(f.name, OTHER_INDICATOR)]
             if self.track_nulls:
                 mc.append((f.name, NULL_INDICATOR))
             meta.extend(_meta_cols(f, mc))
-        return self._emit(np.concatenate(blocks, axis=1), meta)
+        with _obs_span("onehot.concat") as concat_span:
+            out = self._emit(np.concatenate(blocks, axis=1), meta)
+            concat_span.set_attr(bytes=int(out.values.nbytes))
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -981,8 +995,10 @@ class VectorsCombiner(SequenceTransformer):
             from jax.sharding import NamedSharding, PartitionSpec as P
             arr = jax.device_put(jnp.asarray(mat),
                                  NamedSharding(mesh, P("data", None)))
-            return Column(OPVector, arr, None, {"vector_meta": vm})
-        return Column(OPVector, jnp.asarray(mat), None, {"vector_meta": vm})
+        else:
+            arr = jnp.asarray(mat)
+        _count_transfer_bytes(arr, "h2d")
+        return Column(OPVector, arr, None, {"vector_meta": vm})
 
     def transform_row(self, row: Dict[str, Any]) -> Any:
         out: List[float] = []

@@ -20,6 +20,8 @@ import numpy as np
 
 from ...histeng import engine_mesh
 from ...models.api import MODEL_REGISTRY, FittedParams, ModelFamily
+from ...observability.trace import span as _obs_span
+from ...parallel.distributed import _count_transfer_bytes
 from ...robustness import faults
 from ...robustness.guards import (
     AllCandidatesFailedError, params_finite, quarantine_non_finite,
@@ -306,32 +308,48 @@ class ModelSelector(AllowLabelAsInput, Estimator):
     # -- fit (reference ModelSelector.fit :135-196) --------------------------
     def fit(self, table: FeatureTable) -> Transformer:
         label_f, vec_f = self.input_features
-        y_all = np.asarray(table[label_f.name].values, dtype=np.float32).reshape(-1)
-        # the feature matrix never visits the host: row selections for the
-        # holdout/balancer are index gathers on device
-        Xd_all = jnp.asarray(table[vec_f.name].values, dtype=jnp.float32)
-        n = len(y_all)
+        # label preparation, the split, and the one upload of the feature
+        # matrix and labels: everything before the sweep
+        with _obs_span("selector.prepare", cat="train"):
+            y_all = np.asarray(table[label_f.name].values,
+                               dtype=np.float32).reshape(-1)
+            # the feature matrix never visits the host again: row selections
+            # for the holdout/balancer are index gathers on device
+            vec = table[vec_f.name].values
+            Xd_all = jnp.asarray(vec, dtype=jnp.float32)
+            if isinstance(vec, np.ndarray):
+                _count_transfer_bytes(Xd_all, "h2d")
+            n = len(y_all)
 
-        # reserve holdout (reference splitter.split in workflow fitStages)
-        if self.splitter is not None and self.splitter.reserve_test_fraction > 0:
-            train_idx, test_idx = self.splitter.split(n)
-        else:
-            train_idx, test_idx = np.arange(n), np.array([], dtype=np.int64)
+            # reserve holdout (reference splitter.split in workflow fitStages)
+            if (self.splitter is not None
+                    and self.splitter.reserve_test_fraction > 0):
+                train_idx, test_idx = self.splitter.split(n)
+            else:
+                train_idx = np.arange(n)
+                test_idx = np.array([], dtype=np.int64)
 
-        y_train_raw = y_all[train_idx]
-        prep = (self.splitter.pre_validation_prepare(y_train_raw)
-                if self.splitter is not None
-                else PreparedData(indices=np.arange(len(y_train_raw))))
-        sel = train_idx[prep.indices]
-        y = y_all[sel]
-        if prep.label_mapping:
-            y = np.vectorize(lambda v: prep.label_mapping.get(int(v), -1))(y).astype(np.float32)
-        num_classes = int(y.max()) + 1 if self.problem != "regression" else 1
-        if self.problem == "binary":
-            num_classes = 2
+            y_train_raw = y_all[train_idx]
+            prep = (self.splitter.pre_validation_prepare(y_train_raw)
+                    if self.splitter is not None
+                    else PreparedData(indices=np.arange(len(y_train_raw))))
+            sel = train_idx[prep.indices]
+            y = y_all[sel]
+            if prep.label_mapping:
+                y = np.vectorize(
+                    lambda v: prep.label_mapping.get(int(v), -1)
+                )(y).astype(np.float32)
+            num_classes = (int(y.max()) + 1 if self.problem != "regression"
+                           else 1)
+            if self.problem == "binary":
+                num_classes = 2
 
-        metric_name, larger_better = self.validation_metric
-        Xd, yd = Xd_all[jnp.asarray(sel)], jnp.asarray(y)
+            metric_name, larger_better = self.validation_metric
+            sel_d, yd = jnp.asarray(sel), jnp.asarray(y)
+            _count_transfer_bytes(sel_d, "h2d")
+            _count_transfer_bytes(yd, "h2d")
+            Xd = Xd_all[sel_d]
+            del sel_d        # an index vector, not to outlive its gather
         preset = getattr(self, "_preset_best", None)
         if preset is not None:
             # workflow-level CV already ran (find_best_estimator); skip the
@@ -375,37 +393,42 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         # on the full prepared train (the sweep fit at a sample/cap; the
         # refit is the exact program) is quarantined and the next-ranked
         # finite candidate refits instead. With no fault the first candidate
-        # IS the sweep winner, bit-identically.
-        fitted = None
-        best_used = (best.family_name, dict(best.hyper), best.metric_value)
-        refit_quarantine: List[Dict[str, Any]] = []
-        for fam_name, hyper, value in self._ranked_candidates(
-                best, larger_better)[:_MAX_REFIT_ATTEMPTS]:
-            family = MODEL_REGISTRY[fam_name]
-            try:
-                faults.inject("selector.refit", key=fam_name)
-                garr = family.grid_to_arrays([hyper])
-                # rows are 'data'-sharded under a mesh: trace the refit
-                # with the engine's sharded contractions, like the sweep
-                with engine_mesh(self.mesh):
-                    params_b = family.fit_batch(Xf, yf, W, garr,
-                                                num_classes)
-                sel_params = family.select_params(params_b, 0)
-                if not params_finite(sel_params,
-                                     getattr(family, "inf_ok_params", ())):
-                    raise ArithmeticError(
-                        "refit produced non-finite fitted params")
-                fitted = FittedParams(
-                    family=fam_name, params=sel_params,
-                    hyper=dict(hyper), num_classes=num_classes)
-                best_used = (fam_name, dict(hyper), value)
-                break
-            except Exception as e:
-                rec = {"family": fam_name, "hyper": dict(hyper),
-                       "reason": f"refit failed: {type(e).__name__}: {e}"}
-                refit_quarantine.append(rec)
-                FaultLog.record(FaultReport(site="selector.refit",
-                                            kind="quarantine", detail=rec))
+        # IS the sweep winner, bit-identically. params_finite fetches, so
+        # the span closes where the host has waited for the winner's fit.
+        with _obs_span("selector.refit", cat="train") as refit_span:
+            fitted = None
+            best_used = (best.family_name, dict(best.hyper), best.metric_value)
+            refit_quarantine: List[Dict[str, Any]] = []
+            for fam_name, hyper, value in self._ranked_candidates(
+                    best, larger_better)[:_MAX_REFIT_ATTEMPTS]:
+                family = MODEL_REGISTRY[fam_name]
+                try:
+                    faults.inject("selector.refit", key=fam_name)
+                    garr = family.grid_to_arrays([hyper])
+                    # rows are 'data'-sharded under a mesh: trace the refit
+                    # with the engine's sharded contractions, like the sweep
+                    with engine_mesh(self.mesh):
+                        params_b = family.fit_batch(Xf, yf, W, garr,
+                                                    num_classes)
+                    sel_params = family.select_params(params_b, 0)
+                    if not params_finite(sel_params,
+                                         getattr(family, "inf_ok_params", ())):
+                        raise ArithmeticError(
+                            "refit produced non-finite fitted params")
+                    fitted = FittedParams(
+                        family=fam_name, params=sel_params,
+                        hyper=dict(hyper), num_classes=num_classes)
+                    best_used = (fam_name, dict(hyper), value)
+                    break
+                except Exception as e:
+                    rec = {"family": fam_name, "hyper": dict(hyper),
+                           "reason": f"refit failed: {type(e).__name__}: {e}"}
+                    refit_quarantine.append(rec)
+                    FaultLog.record(FaultReport(site="selector.refit",
+                                                kind="quarantine", detail=rec))
+            refit_span.set_attr(family=best_used[0],
+                                attempts=(len(refit_quarantine)
+                                          + (fitted is not None)))
         if fitted is None:
             raise AllCandidatesFailedError(
                 list(best.quarantined) + refit_quarantine)
@@ -430,16 +453,18 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         model = self._finalize_model(model)
 
         # train/holdout evaluation (reference :168-188)
-        ev = self._default_evaluator()
-        ev.set_label_col(label_f.name)
-        ev.set_prediction_col(model.get_output().name)
-        train_tbl = table.take(train_idx)
-        summary.train_evaluation = _scalar_metrics(
-            ev.evaluate_all(model.transform(train_tbl)))
-        if len(test_idx):
-            test_tbl = table.take(test_idx)
-            summary.holdout_evaluation = _scalar_metrics(
-                ev.evaluate_all(model.transform(test_tbl)))
+        with _obs_span("selector.evaluate", cat="train",
+                       rows=len(train_idx) + len(test_idx)):
+            ev = self._default_evaluator()
+            ev.set_label_col(label_f.name)
+            ev.set_prediction_col(model.get_output().name)
+            train_tbl = table.take(train_idx)
+            summary.train_evaluation = _scalar_metrics(
+                ev.evaluate_all(model.transform(train_tbl)))
+            if len(test_idx):
+                test_tbl = table.take(test_idx)
+                summary.holdout_evaluation = _scalar_metrics(
+                    ev.evaluate_all(model.transform(test_tbl)))
         model.summary_metadata = summary.to_json()
         return model
 

@@ -31,6 +31,7 @@ from ...observability import ledger as _obs_ledger
 from ...observability import metrics as _obs_metrics
 from ...observability import trace as _obs_trace
 from ...observability.trace import span as _obs_span, tracing_enabled
+from ...parallel.distributed import _count_transfer_bytes
 from ...robustness import faults
 from ...robustness.guards import (
     AllCandidatesFailedError, quarantine_non_finite,
@@ -244,7 +245,13 @@ def _make_fused_program(family, garr_np, G: int, F: int, problem: str,
                    and getattr(family, "traced_grid_ok", False))
     grid_keys = tuple(sorted(tiled)) if traced_grid else None
 
-    def prog(X, y, ids_d, *rest):
+    def prog(*args):
+        # a trace-time name only: the module stays ``jit_prog``, the ops of
+        # this family's branch carry ``sweep.<family>`` in their op names
+        with jax.named_scope(f"sweep.{family.name}"):
+            return branch(*args)
+
+    def branch(X, y, ids_d, *rest):
         # call convention: [Xf, yf, fvalid] when sliced, then [gblock]
         # when the family takes its grid as a traced (donated) argument
         Xf = yf = fvalid = gblock = None
@@ -485,6 +492,7 @@ class OpValidator:
         fold_ids = np.where(vm_np.any(axis=0), vm_np.argmax(axis=0),
                             F).astype(np.uint8)
         ids_d = jnp.asarray(fold_ids)
+        _count_transfer_bytes(ids_d, "h2d")
         if n_pad != n:  # sentinel F+1: never trains, never validates
             ids_d = jnp.pad(ids_d, (0, n_pad - n), constant_values=F + 1)
         # fold-sliced scoring: every (fold, config) pair only needs ITS
@@ -526,9 +534,11 @@ class OpValidator:
                     fidx[f, :len(rows)] = rows
                     fvalid[f, :len(rows)] = True
                 fidx_d = jnp.asarray(fidx.reshape(-1))
+                _count_transfer_bytes(fidx_d, "h2d")
                 Xf = X[fidx_d].reshape((F, nf_b) + X.shape[1:])
                 yf = y[fidx_d].reshape(F, nf_b)
                 fvalid_d = jnp.asarray(fvalid)
+                _count_transfer_bytes(fvalid_d, "h2d")
                 if mesh is not None:
                     # Xf rows shard over 'data' (feeds the row-parallel
                     # per-fold predicts); yf / fvalid replicate — they are
@@ -637,6 +647,8 @@ class OpValidator:
             # crash evidence: a kill past this point happened inside a
             # fused sweep dispatch (run sentinel, docs/robustness.md)
             sentinel_phase("device_sweep")
+            nonlocal launched
+            launched += 1
             if getattr(family, "uses_hist_engine", False):
                 # chaos site hist.build: a raise quarantines THIS family
                 # (same recovery as validator.family_fit) before any of
@@ -797,6 +809,11 @@ class OpValidator:
         # lineage; only all-candidates-failed raises, aggregated, below)
         pending: List[Any] = []
         fit_failures: Dict[int, str] = {}
+        #: device programs launched so far (one per family unless the
+        #: exhaustion ladder split a grid) and families dispatched: the
+        #: ``programs`` and ``order`` attrs of the sweep.family spans
+        launched = 0
+        dispatched = 0
         #: host-resident (F, G) metrics by family index — filled by sweep
         #: checkpoint restore AND by the eager per-family fetch that
         #: checkpointing requires (durability costs the single-sync
@@ -834,9 +851,10 @@ class OpValidator:
             # and the compile-cache hit/miss delta of dispatching this
             # branch (utils/jax_cache.py listener) — the attribution the
             # 0.381x mesh regression lacked (compile vs execute)
+            launched_before = launched
             with _obs_span("sweep.family", cat="sweep", family=family.name,
-                           configs=len(grid), folds=F,
-                           metric=metric_name) as sweep_span:
+                           configs=len(grid), folds=F, metric=metric_name,
+                           order=dispatched) as sweep_span:
                 # flight-recorder: each family dispatch, stamped with the
                 # owning run's correlation id (workflow.train) — a sweep
                 # post-mortem shows which family the incident interrupted
@@ -862,12 +880,14 @@ class OpValidator:
                     fit_failures[fi] = reason
                     sweep_span.add_event("sweep.family_quarantined",
                                          family=family.name, reason=reason)
+                sweep_span.set_attr(programs=launched - launched_before)
                 if cs0 is not None:
                     from ...utils.jax_cache import cache_stats
                     cs1 = cache_stats()
                     sweep_span.set_attr(
                         cacheHits=cs1["hits"] - cs0["hits"],
                         cacheMisses=cs1["misses"] - cs0["misses"])
+            dispatched += 1
             if sweep_ckpt is not None:
                 from ...parallel.distributed import fetch_to_host
                 from .sweep_checkpoint import SweepCheckpoint, params_hash
@@ -890,11 +910,19 @@ class OpValidator:
                     "reason": fit_failures.get(fi),
                 })
 
+        # every family is dispatched. _dispatch calls itself (the exhaustion
+        # ladder), so its closure is a reference cycle that would keep the
+        # sweep's padded X, y, fold ids and fold gather (0.5 GB of device
+        # memory at 1M x 105) alive until the cyclic collector happens to
+        # run — before or after the refit allocates, by luck (PERF.md,
+        # PR 24). Cut it here.
+        _dispatch = None
         # fuse every family's metric vector into ONE device array so finish()
         # pays a single blocking host transfer instead of one per family
         valid_m = [p[2] for p in pending if p[2] is not None]
         all_m = (jnp.concatenate([m.reshape(-1) for m in valid_m])
-                 if len(valid_m) > 1 else None)
+                 if len(valid_m) > 1
+                 else valid_m[0].reshape(-1) if valid_m else None)
 
         def finish() -> BestEstimator:
             import time as _time
@@ -909,7 +937,10 @@ class OpValidator:
             # the device->host metric fetch is the sweep's "transfer" phase;
             # its histogram lets bench.py split compile/execute/transfer
             t0_fetch = _time.perf_counter()
-            m_host = fetch_to_host(all_m) if all_m is not None else None
+            # the one statement where the host waits for the device sweep
+            with _obs_span("sweep.collect", cat="sweep",
+                           families=len(valid_m)):
+                m_host = fetch_to_host(all_m) if all_m is not None else None
             if m_host is not None:
                 _obs_metrics.observe(
                     "tg_sweep_transfer_seconds",
@@ -921,12 +952,9 @@ class OpValidator:
                     fold_metrics = host_metrics[fi]
                 elif m is None:  # the family's fit threw before dispatch
                     fold_metrics = np.full((F, G), np.nan, dtype=np.float64)
-                elif m_host is not None:
+                else:
                     m_fam = m_host[off:off + m.size]
                     off += m.size
-                    fold_metrics = m_fam[:B_true].reshape(F, G)
-                else:
-                    m_fam = fetch_to_host(m).reshape(-1)
                     fold_metrics = m_fam[:B_true].reshape(F, G)
                 fold_metrics = faults.poison("validator.fold_metrics",
                                              fold_metrics, key=fam_name)

@@ -223,7 +223,9 @@ def _fit_logreg_batch(X, y, W, reg, elastic_net, newton_iters=10, cg_iters=8,
 
     A0 = jnp.zeros((nB, d), X.dtype)
     b0 = jnp.zeros((nB,), X.dtype)
-    (A, b), _ = jax.lax.scan(newton_step, (A0, b0), None, length=newton_iters)
+    with jax.named_scope("linear.newton_cg"):
+        (A, b), _ = jax.lax.scan(newton_step, (A0, b0), None,
+                                 length=newton_iters)
     return std.unscale(A, b)
 
 

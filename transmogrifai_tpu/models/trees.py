@@ -197,6 +197,7 @@ def _exact_leaf_stats(codes: jnp.ndarray, feat_heaps: jnp.ndarray,
     return out[..., :-1], out[..., -1]
 
 
+@jax.named_scope("hist.split")
 def _split_gain(SL, SR, total, cfg, mode: str):
     """Gain + validity for every candidate split.
 

@@ -5,10 +5,16 @@ utils/.../spark/OpSparkListener.scala:55-110 — per-stage task metrics pushed
 by the cluster scheduler); a JAX process has no cluster scheduler to listen
 to, so the spans are emitted by the framework itself at every interesting
 boundary: ``workflow.train`` → ``stage.fit``/``stage.transform`` (per layer),
-``sweep.family`` (per ModelSelector candidate family), ``score.micro_batch``
-(per serving batch). Fault recoveries (robustness/) land as span *events* on
-whatever span is open, so a trace shows retries and quarantines in line with
-the work they interrupted.
+inside the model selector ``selector.prepare`` / ``sweep.family`` (per
+candidate family, the dispatch) / ``sweep.collect`` (the wait) /
+``selector.refit`` / ``selector.evaluate``, inside the scoring plan
+``plan.stage_inputs`` / ``plan.segment`` / ``plan.collect``,
+``score.micro_batch`` (per serving batch); docs/observability.md has the
+whole table. A span never adds a sync: one that launches asynchronous device
+work covers the launch, and the wait belongs to the span around the statement
+where the program blocks anyway. Fault recoveries (robustness/) land as span
+*events* on whatever span is open, so a trace shows retries and quarantines
+in line with the work they interrupted.
 
 Cost model: a disabled tracer is one env/flag check per ``span()`` call —
 no Span objects, no buffer writes — so the always-compiled call sites add
@@ -54,22 +60,40 @@ def enable_tracing(on: Optional[bool]) -> None:
     _enabled_override = None if on is None else bool(on)
 
 
+@contextmanager
+def forced_tracing():
+    """Tracing on inside the block whatever the switches say, and as it
+    was after: a profiled run (``OpWorkflow.with_profiler``) records its
+    spans without the caller having to set ``TG_TRACE``."""
+    global _enabled_override
+    prev = _enabled_override
+    _enabled_override = True
+    try:
+        yield
+    finally:
+        _enabled_override = prev
+
+
 class Span:
     """One timed operation. ``ts_ns``/``dur_ns`` are monotonic-clock
     nanoseconds relative to the owning tracer's epoch; ``dur_ns`` is None
     while open (and stays None for instant events). ``events`` are
     point-in-time annotations: ``(name, ts_ns, attrs)``."""
 
-    __slots__ = ("name", "cat", "span_id", "parent_id", "ts_ns", "dur_ns",
-                 "attrs", "events", "tid")
+    __slots__ = ("name", "cat", "span_id", "parent_id", "root_id", "ts_ns",
+                 "dur_ns", "attrs", "events", "tid")
 
     def __init__(self, name: str, cat: str, span_id: int,
                  parent_id: Optional[int], ts_ns: int, tid: int,
-                 attrs: Optional[Dict[str, Any]] = None):
+                 attrs: Optional[Dict[str, Any]] = None,
+                 root_id: Optional[int] = None):
         self.name = name
         self.cat = cat
         self.span_id = span_id
         self.parent_id = parent_id
+        #: id of the outermost span this one nests under (its own id for a
+        #: root): every span of one train() or score() shares it
+        self.root_id = span_id if root_id is None else root_id
         self.ts_ns = ts_ns
         self.dur_ns: Optional[int] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
@@ -91,7 +115,8 @@ class Span:
     def to_json(self) -> Dict[str, Any]:
         return {
             "name": self.name, "cat": self.cat, "id": self.span_id,
-            "parent": self.parent_id, "tsNs": self.ts_ns,
+            "parent": self.parent_id, "root": self.root_id,
+            "tsNs": self.ts_ns,
             "durNs": self.dur_ns, "tid": self.tid, "attrs": dict(self.attrs),
             "events": [{"name": n, "tsNs": t, "attrs": dict(a)}
                        for n, t, a in self.events],
@@ -143,10 +168,12 @@ class Tracer:
     def start(self, name: str, cat: str = "",
               attrs: Optional[Dict[str, Any]] = None) -> Span:
         st = self._stack()
+        parent = st[-1] if st else None
         s = Span(name, cat, next(self._ids),
-                 st[-1].span_id if st else None,
+                 parent.span_id if parent else None,
                  time.perf_counter_ns() - self.epoch_ns,
-                 threading.get_ident(), attrs)
+                 threading.get_ident(), attrs,
+                 parent.root_id if parent else None)
         st.append(s)
         return s
 
@@ -216,6 +243,17 @@ def reset() -> None:
     _enabled_override = None
 
 
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name)
+
+
 def _now_rel_ns() -> int:
     return time.perf_counter_ns() - _TRACER.epoch_ns
 
@@ -230,7 +268,12 @@ def span(name: str, cat: str = "", **attrs: Any):
     t = _TRACER
     s = t.start(name, cat, attrs)
     try:
-        yield s
+        # the same name on the profiler's own clock: a jax.profiler session
+        # opened by anyone (a benchmark, an operator) shows the program's
+        # spans beside the device lines; costs nothing measurable with no
+        # session open
+        with _annotation(name):
+            yield s
     finally:
         t.end(s)
 

@@ -30,6 +30,7 @@ _LO = _NUM_BINS // _HI
 _HIST_CHUNK = 32768
 
 
+@jax.named_scope("metrics.binned")
 def _binned_hists(scores: jnp.ndarray, labels: jnp.ndarray,
                   mask: jnp.ndarray):
     """(pos_hist, total_hist), each (_NUM_BINS,), over the masked subset;

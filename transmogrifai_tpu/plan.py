@@ -231,7 +231,9 @@ class _DeviceSegment:
             env = {nm: (v, m)
                    for nm, v, m in zip(in_names, vals_list, mask_list)}
             for s in fused:
-                env[s.get_output().name] = s.device_columnar(env)
+                # trace-time name only (the module stays ``jit_chain``)
+                with jax.named_scope(f"stage.{type(s).__name__}"):
+                    env[s.get_output().name] = s.device_columnar(env)
             return tuple(env[nm] for nm in outs)
 
         self.chain = chain
@@ -338,33 +340,45 @@ class TransformPlan:
         t0 = (time.perf_counter()
               if _obs_metrics.metrics_enabled() else None)
         transfers = 0
+        nbytes = 0          # host bytes handed to the device (h2d)
         vals_list, mask_list = [], []
-        for nm in seg.in_names:
-            col = table[nm]
-            v, m = col.values, col.mask
-            if isinstance(v, np.ndarray):
-                v = np.asarray(v, dtype=np.float32)
-                if n_pad != n:
-                    v = np.concatenate(
-                        [v, np.zeros((n_pad - n,) + v.shape[1:], v.dtype)])
-                m = self._pad_mask_host(m, n, n_pad)
-                v, m = jnp.asarray(v), jnp.asarray(m)
-                transfers += 2
-            else:
-                if v.dtype != jnp.float32:
-                    v = v.astype(jnp.float32)
-                if n_pad != n:
-                    v = jnp.pad(v, ((0, n_pad - n),) + ((0, 0),) * (v.ndim - 1))
-                if m is None:
-                    m = self._pad_mask_host(None, n, n_pad)
-                    m = jnp.asarray(m)
-                    transfers += 1
-                else:
-                    m = jnp.asarray(m)
+        # cast, pad and upload of the segment's inputs: launches only, the
+        # uploads complete behind the dispatch below
+        with _obs_span("plan.stage_inputs", cat=self.cat,
+                       bucket=n_pad) as stage_span:
+            for nm in seg.in_names:
+                col = table[nm]
+                v, m = col.values, col.mask
+                if isinstance(v, np.ndarray):
+                    v = np.asarray(v, dtype=np.float32)
                     if n_pad != n:
-                        m = jnp.pad(m, (0, n_pad - n))
-            vals_list.append(v)
-            mask_list.append(m)
+                        v = np.concatenate(
+                            [v, np.zeros((n_pad - n,) + v.shape[1:],
+                                         v.dtype)])
+                    m = self._pad_mask_host(m, n, n_pad)
+                    nbytes += v.nbytes + m.nbytes
+                    v, m = jnp.asarray(v), jnp.asarray(m)
+                    transfers += 2
+                else:
+                    if v.dtype != jnp.float32:
+                        v = v.astype(jnp.float32)
+                    if n_pad != n:
+                        v = jnp.pad(v, ((0, n_pad - n),)
+                                    + ((0, 0),) * (v.ndim - 1))
+                    if m is None:
+                        m = self._pad_mask_host(None, n, n_pad)
+                        nbytes += m.nbytes
+                        m = jnp.asarray(m)
+                        transfers += 1
+                    else:
+                        if isinstance(m, np.ndarray):
+                            nbytes += m.nbytes
+                        m = jnp.asarray(m)
+                        if n_pad != n:
+                            m = jnp.pad(m, (0, n_pad - n))
+                vals_list.append(v)
+                mask_list.append(m)
+            stage_span.set_attr(bytes=nbytes, transfers=transfers)
         if t0 is not None:
             _obs_metrics.observe(
                 "tg_plan_transfer_seconds", time.perf_counter() - t0,
@@ -372,6 +386,9 @@ class TransformPlan:
             _obs_metrics.inc_counter(
                 "tg_device_transfer_total", float(transfers),
                 help="host→device uploads (packed: see docs/plan.md)")
+            _obs_metrics.inc_counter(
+                "tg_transfer_bytes_total", float(nbytes), direction="h2d",
+                help="bytes moved across the host<->device link")
         _obs_metrics.inc_counter(
             "tg_dispatch_total", kind="plan_segment",
             help="top-level device executable launches on the transform "
@@ -461,14 +478,19 @@ class TransformPlan:
             _devicemem.record_cost(seg_fp, n_pad, cost_bytes,
                                    execute_s=disp_secs)
         new_cols: Dict[str, Column] = {}
-        for nm, (arr, msk) in zip(seg.out_names, outs):
-            # slice padding back off; keep values device-resident (exactly
-            # what the eager fused-substrate stages hand downstream)
-            msk_np = None if msk is None else np.asarray(msk)[:n]
-            if msk_np is not None and msk_np.all():
-                msk_np = None
-            ftype, md = seg.out_meta[nm]
-            new_cols[nm] = Column(ftype, arr[:n], msk_np, dict(md))
+        # un-padding: the first ``np.asarray(msk)`` is where the host waits
+        # for the segment's program on the device
+        with _obs_span("plan.collect", cat=self.cat,
+                       outputs=len(seg.out_names)):
+            for nm, (arr, msk) in zip(seg.out_names, outs):
+                # slice padding back off; keep values device-resident
+                # (exactly what the eager fused-substrate stages hand
+                # downstream)
+                msk_np = None if msk is None else np.asarray(msk)[:n]
+                if msk_np is not None and msk_np.all():
+                    msk_np = None
+                ftype, md = seg.out_meta[nm]
+                new_cols[nm] = Column(ftype, arr[:n], msk_np, dict(md))
         return table.with_columns(new_cols)
 
     @staticmethod

@@ -1,17 +1,27 @@
-"""Per-stage wall-clock profiling.
+"""Per-stage wall-clock report over the tracer's spans.
 
 The analog of the reference's Spark-listener metrics collection (reference:
 utils/.../spark/OpSparkListener.scala:55-110 — per-stage run time aggregated
-into AppMetrics at app end, wired by OpWorkflowRunner.scala:139-154). Here the
-scheduler itself times every fit/transform; ``jax.profiler`` traces can be
-layered on top for device-level detail (start_trace/stop_trace around train).
+into AppMetrics at app end, wired by OpWorkflowRunner.scala:139-154). The
+clock is the tracer's (``observability/trace.py``): a profiled run switches
+tracing on and its ``stage.fit`` / ``stage.transform`` / ``plan.segment``
+spans are aggregated here when it ends, so a profiled run takes the same
+path (planned segments included) as any other.
 """
 from __future__ import annotations
 
+import os
 import time
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
+
+from ..observability import trace as _trace
+
+#: the spans a profiler aggregates: per-stage fits and transforms, and a
+#: fused plan segment under its own name (its ``stages`` attr says how many
+#: stages it ran as one program)
+_STAGE_SPANS = ("stage.fit", "stage.transform", "plan.segment")
 
 
 class StageProfiler:
@@ -24,60 +34,76 @@ class StageProfiler:
     def __init__(self, max_records: int = 10_000):
         self.records: deque = deque(maxlen=max_records)
         self.app_start = time.time()
-        #: monotonic epoch for span-compatible record timestamps
-        self._epoch = time.perf_counter()
         self._total = 0.0
         self._count = 0
         self._by_stage: Dict[str, float] = {}
         self._by_layer: Dict[str, float] = {}
         self._by_op: Dict[str, float] = {}
 
+    def collect(self, root: Any) -> None:
+        """Aggregate the stage spans that ran under ``root`` (the
+        ``workflow.train`` / ``workflow.score`` span of a finished run)."""
+        lo, hi = root.ts_ns, root.ts_ns + (root.dur_ns or 0)
+        for s in _trace.tracer().finished():
+            if (s.name in _STAGE_SPANS and s.dur_ns is not None
+                    and s.root_id == root.root_id and lo <= s.ts_ns <= hi):
+                self._record(
+                    s.attrs.get("stage", s.name), s.attrs.get("uid", "?"),
+                    "fit" if s.name == "stage.fit" else "transform",
+                    int(s.attrs.get("layer", -1)), s.ts_ns, s.dur_ns,
+                    s.attrs.get("stages"))
+
+    def _record(self, name: str, uid: str, op: str, layer: int, ts_ns: int,
+                dur_ns: int, stages: Any = None) -> None:
+        secs = dur_ns / 1e9
+        rec = {"stage": name, "uid": uid, "op": op, "layer": layer,
+               "seconds": secs,
+               # microseconds on the tracer's clock: the Chrome-trace
+               # timestamp of this op (see spans())
+               "ts": ts_ns / 1e3}
+        if stages is not None:
+            rec["stages"] = stages
+        self.records.append(rec)
+        self._total += secs
+        self._count += 1
+        self._by_stage[name] = self._by_stage.get(name, 0.0) + secs
+        self._by_op[op] = self._by_op.get(op, 0.0) + secs
+        lk = f"layer_{layer}" if layer >= 0 else "unlayered"
+        self._by_layer[lk] = self._by_layer.get(lk, 0.0) + secs
+
     @contextmanager
     def track(self, stage: Any, op: str, layer: int = -1):
-        t0 = time.perf_counter()
+        """Time one op by hand, outside any workflow run, on the same
+        clock and into the same aggregates."""
+        epoch = _trace.tracer().epoch_ns
+        t0 = time.perf_counter_ns()
         try:
             yield
         finally:
-            secs = time.perf_counter() - t0
-            name = type(stage).__name__
-            self.records.append({
-                "stage": name,
-                "uid": getattr(stage, "uid", "?"),
-                "op": op,
-                "layer": layer,
-                "seconds": secs,
-                # microseconds since profiler construction — the span/chrome
-                # timestamp of this op (see spans())
-                "ts": (t0 - self._epoch) * 1e6,
-            })
-            self._total += secs
-            self._count += 1
-            self._by_stage[name] = self._by_stage.get(name, 0.0) + secs
-            self._by_op[op] = self._by_op.get(op, 0.0) + secs
-            lk = f"layer_{layer}" if layer >= 0 else "unlayered"
-            self._by_layer[lk] = self._by_layer.get(lk, 0.0) + secs
+            self._record(type(stage).__name__, getattr(stage, "uid", "?"),
+                         op, layer, t0 - epoch, time.perf_counter_ns() - t0)
 
     def spans(self) -> List[Dict[str, Any]]:
         """The records ring as Chrome-trace complete events (``ph: "X"``,
         microsecond ``ts``/``dur``) — droppable straight into a trace-event
         document alongside the observability tracer's output. Bounded by the
         ring: only the newest ``maxlen`` ops survive a long run."""
-        import os
         pid = os.getpid()
         return [{
             "name": f"{r['stage']}.{r['op']}",
             "ph": "X",
-            "ts": r.get("ts", 0.0),
+            "ts": r["ts"],
             "dur": r["seconds"] * 1e6,
             "pid": pid,
             "tid": 0,
-            "args": {"uid": r["uid"], "op": r["op"], "layer": r["layer"]},
+            "args": {k: r[k] for k in ("uid", "op", "layer", "stages")
+                     if k in r},
         } for r in self.records]
 
     # -- aggregation (reference AppMetrics, OpSparkListener.scala:55-110) ----
     def app_metrics(self) -> Dict[str, Any]:
-        # accumulated in track() (NOT derived from the bounded records ring,
-        # which would undercount runs past its maxlen)
+        # accumulated as spans arrive (NOT derived from the bounded records
+        # ring, which would undercount runs past its maxlen)
         by_layer = self._by_layer
         from ..observability import devicemem as _devicemem
         from ..observability import ledger as _ledger

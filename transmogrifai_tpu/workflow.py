@@ -9,6 +9,7 @@ scores (batched, on device) and reports summaries.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -21,6 +22,36 @@ from .features import Feature
 from .readers.readers import DataFrameReader, Reader, dataframe_to_table
 from .stages.base import Estimator, FeatureGeneratorStage
 from .table import Column, FeatureTable
+
+
+def _h2d_bytes() -> Optional[float]:
+    """``tg_transfer_bytes_total{direction="h2d"}`` as it stands, for the
+    ``h2dBytes`` of a root span; None unless spans and metrics are both
+    being recorded (the registry is not touched otherwise)."""
+    from .observability import metrics as _obs_metrics
+    from .observability.trace import tracing_enabled
+    if not (tracing_enabled() and _obs_metrics.metrics_enabled()):
+        return None
+    return _obs_metrics.registry().counter(
+        "tg_transfer_bytes_total", direction="h2d").value
+
+
+@contextlib.contextmanager
+def _root_span(name: str, profiler, **attrs):
+    """The outermost span of a train() or score(). With a profiler
+    (``with_profiler``) tracing is on for the run and the profiler
+    aggregates the run's stage spans when it ends; a traced run with
+    metrics on gets ``h2dBytes``, the bytes that went up during it."""
+    from .observability.trace import forced_tracing, span
+    with forced_tracing() if profiler is not None \
+            else contextlib.nullcontext():
+        with span(name, **attrs) as root:
+            h2d0 = _h2d_bytes()
+            yield root
+            if h2d0 is not None:
+                root.set_attr(h2dBytes=_h2d_bytes() - h2d0)
+    if profiler is not None:
+        profiler.collect(root)
 
 
 def _open_run_sentinel(ckpt_dir: Optional[str], resume: bool):
@@ -158,7 +189,9 @@ class OpWorkflow(_WorkflowCore):
 
     def with_profiler(self, profiler=None) -> "OpWorkflow":
         """Collect per-stage wall-clock metrics during train (the reference's
-        OpSparkListener/logStageMetrics knob, OpParams.scala:66-72)."""
+        OpSparkListener/logStageMetrics knob, OpParams.scala:66-72): the
+        tracer is switched on for the run and the profiler aggregates its
+        stage spans, on whichever path (planned or eager) the run takes."""
         from .utils.profiler import StageProfiler
         self.profiler = profiler or StageProfiler()
         return self
@@ -266,7 +299,6 @@ class OpWorkflow(_WorkflowCore):
         accounting (chunks, uploaded bytes, peak device residency,
         overlap)."""
         from .observability import blackbox as _blackbox
-        from .observability.trace import span as _obs_span
         from .robustness.policy import FaultLog
         fault_log = FaultLog()
         # one flight-recorder correlation id per run: every black-box
@@ -277,8 +309,8 @@ class OpWorkflow(_WorkflowCore):
         corr = (_blackbox.new_correlation_id("run")
                 if _blackbox.blackbox_enabled() else None)
         with fault_log.activate(), _blackbox.correlated(corr), \
-                _obs_span("workflow.train", cat="train", resume=resume,
-                          stream=stream is not None):
+                _root_span("workflow.train", self.profiler, cat="train",
+                           resume=resume, stream=stream is not None):
             _blackbox.record("workflow.train", resume=resume,
                              stream=stream is not None)
             if stream is not None:
@@ -441,8 +473,7 @@ class OpWorkflow(_WorkflowCore):
                 table, fitted = self._fit_with_workflow_cv(table, layers)
             else:
                 table, fitted = fit_and_transform_dag(
-                    table, layers, profiler=self.profiler,
-                    checkpoint=checkpoint, preloaded=preloaded,
+                    table, layers, checkpoint=checkpoint, preloaded=preloaded,
                     retry_policy=retry_policy)
         if sentinel is not None:
             # clean-exit commit: a kill anywhere above leaves the sentinel
@@ -549,8 +580,7 @@ class OpWorkflow(_WorkflowCore):
                           if s.uid not in tainted_stage_uids]
                          for layer in layers]
         table1, fitted_before = fit_and_transform_dag(
-            table, before_layers, profiler=self.profiler,
-            retry_policy=retry_policy)
+            table, before_layers, retry_policy=retry_policy)
 
         # the in-CV DAG refit per fold: tainted estimator stages on the
         # selector-input ancestry (not the selector, not its downstream)
@@ -569,8 +599,7 @@ class OpWorkflow(_WorkflowCore):
         try:
             sel.find_best_estimator(table1, during_layers)
             table2, fitted_rest = fit_and_transform_dag(
-                table1, rest_layers, profiler=self.profiler,
-                retry_policy=retry_policy)
+                table1, rest_layers, retry_policy=retry_policy)
         except Exception:
             # don't leave a recorded winner behind: a later plain train()
             # on the same stage objects must validate from scratch, not
@@ -663,16 +692,15 @@ class OpWorkflowModel(_WorkflowCore):
         """Batch scoring over the fitted transformer DAG. The pass runs on
         the fused substrate: the transform-plan compiler (``plan.py``)
         traces each device-fusable segment into one XLA program (eager
-        per-stage dispatch under a profiler, ``TG_PLAN=0``, or active
-        chaos — results are bit-identical either way, docs/plan.md)."""
+        per-stage dispatch under ``TG_PLAN=0`` or active chaos — results
+        are bit-identical either way, docs/plan.md)."""
         if df is not None:
             table = dataframe_to_table(df, self.raw_features)
         if table is None:
             table = self._generate_raw_table()
-        from .observability.trace import span as _obs_span
-        with _obs_span("workflow.score", cat="score", rows=table.num_rows):
-            scored = apply_transformations_dag(table, self._layers,
-                                               profiler=self.profiler)
+        with _root_span("workflow.score", self.profiler, cat="score",
+                        rows=table.num_rows):
+            scored = apply_transformations_dag(table, self._layers)
         if keep_raw_features and keep_intermediate_features:
             return scored
         keep = [f.name for f in self.result_features if f.name in scored.column_names]
