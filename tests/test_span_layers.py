@@ -162,11 +162,38 @@ def test_one_hot_spans_one_per_column(spans):
         got = _named(mine, name)
         assert sorted(s.attrs["column"] for s in got) == ["c1", "c2"], name
     counts = {s.attrs["column"]: s for s in _named(mine, "onehot.count")}
-    assert counts["c1"].attrs == {"column": "c1", "rows": ROWS, "levels": 4}
+    assert counts["c1"].attrs == {"column": "c1", "rows": ROWS, "levels": 4,
+                                  "path": "hashed"}
     assert counts["c2"].attrs["levels"] == 2
+    # every value of c1 and c2 is a str: hashed as it is, fit and transform
+    assert [s.attrs for s in _named(mine, "onehot.encode")] == [
+        {"column": "c1", "path": "hashed"}, {"column": "c2", "path": "hashed"}]
     (concat,) = _named(mine, "onehot.concat")
     # 4 + OTHER + null and 2 + OTHER + null columns of float32
     assert concat.attrs["bytes"] == ROWS * (6 + 4) * 4
+
+
+@pytest.mark.parametrize("values,path", [
+    (["a", "b", "a", None], "hashed"),
+    (list(np.array(["a", "b", "a"])), "hashed"),        # numpy's str
+    ([3, 1, 3, None], "str_pass"),
+    (["1", 1, True, 1.0], "str_pass"),
+    ([None, None], "hashed"),                           # nothing to str()
+], ids=["str", "numpy_str", "ints", "mixed", "all_null"])
+def test_one_hot_spans_say_which_pass_ran(values, path):
+    from transmogrifai_tpu.impl.feature import OneHotVectorizer
+    from transmogrifai_tpu.types import PickList
+    for reps in (1, 100):       # the small pass and pandas' say the same
+        table = tg.FeatureTable.from_columns({"c": (PickList, values * reps)})
+        st = OneHotVectorizer()
+        st.set_input(FeatureBuilder.PickList("c").extract_field().as_predictor())
+        ot.reset()
+        ot.enable_tracing(True)
+        st.fit(table).transform_column(table)
+        got = {s.name: s.attrs for s in ot.tracer().finished()}
+        ot.reset()
+        assert got["onehot.count"]["path"] == path
+        assert got["onehot.encode"] == {"column": "c", "path": path}
 
 
 def test_every_span_of_a_train_shares_the_roots_id(spans):

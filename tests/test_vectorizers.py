@@ -149,3 +149,166 @@ def test_transmogrify_end_to_end():
     assert parents == {"age", "cnt", "vip", "color"}
     # deterministic order: groups sorted, features sorted within group
     assert np.asarray(col.values).shape[0] == 20
+
+
+# -- values to codes by one hash pass: same answers as the per-row loops ----
+
+def _reference_pivot(fit, transform, top_k, min_support, track_nulls, multi):
+    """The per-row loops the hash pass replaced, as plain as they come:
+    (vocabulary, matrix) of one column fitted on ``fit`` and applied to
+    ``transform``, each a (values, valid mask) pair."""
+    import collections
+    cnt = collections.Counter()
+    for v, ok in zip(*fit):
+        if ok:
+            cnt.update((v or ()) if multi else [str(v)])
+    top = sorted((v for v, c in cnt.items() if c >= min_support),
+                 key=lambda v: (-cnt[v], v))[:top_k]
+    index = {v: i for i, v in enumerate(top)}
+    k = len(top)
+    mat = np.zeros((len(transform[0]), k + 1 + int(track_nulls)), np.float32)
+    for i, (v, ok) in enumerate(zip(*transform)):
+        if ok:
+            for level in ((v or ()) if multi else [str(v)]):
+                mat[i, index.get(level, k)] = 1.0
+        elif track_nulls:
+            mat[i, k + 1] = 1.0
+    return top, mat
+
+
+def _pivot_case(values, masked=(), transform=None, top_k=20, min_support=1,
+                track_nulls=True):
+    """``masked``: positions the column's mask calls null whatever they
+    hold; ``transform``: values met after the fit (default: the fit's)."""
+    return dict(values=values, masked=masked, transform=transform,
+                top_k=top_k, min_support=min_support, track_nulls=track_nulls)
+
+
+PIVOT_CASES = {
+    "all_str": _pivot_case(["red"] * 5 + ["blue"] * 3 + ["green"]),
+    "nulls_tracked": _pivot_case(
+        ["a", None, "b", "a", None, "masked", "b", "a"], masked=(5,)),
+    "nulls_untracked": _pivot_case(
+        ["a", None, "b", "a", None, "masked", "b", "a"], masked=(5,),
+        track_nulls=False),
+    "all_null": _pivot_case([None, None, None]),
+    "ints": _pivot_case([3, 1, 3, 2, 3, 1, None, 10]),
+    # str() keeps apart what == and hash() merge
+    "mixed_1_True_str1_float1": _pivot_case(
+        [1, True, "1", 1.0, True, "1", 1, 1, False, 0, 0.0, "0"]),
+    "nan_held_valid": _pivot_case(
+        ["a", float("nan"), "nan", "a", None], masked=(4,)),
+    "numpy_str": _pivot_case(list(np.array(["u", "v", "u", "w", "u"]))),
+    "ties_in_count": _pivot_case(["b", "a", "c", "b", "a", "c", "d"],
+                                 top_k=2),
+    "min_support_cuts": _pivot_case(["x"] * 4 + ["y"] * 3 + ["z"] * 2 + ["w"],
+                                    min_support=3),
+    "more_levels_than_top_k": _pivot_case(
+        [f"l{i % 7}" for i in range(30)] + ["l0", "l1", "l1"], top_k=3),
+    "unseen_at_transform": _pivot_case(
+        ["a", "b", "a"], transform=["b", "never seen", None, "a", 7]),
+    "empty_table": _pivot_case([]),
+    "one_row": _pivot_case(["only"]),
+    "one_null_row": _pivot_case([None]),
+}
+#: repeated past the point where the hash pass hands over to pandas
+PIVOT_PARAMS = [(name, reps) for name in PIVOT_CASES for reps in (1, 64)
+                if not (reps > 1 and name in ("empty_table", "one_row",
+                                              "one_null_row"))]
+
+
+def _scalar_column(ftype, values, masked, reps):
+    from transmogrifai_tpu.table import Column, _is_missing
+    arr = np.empty(len(values) * reps, dtype=object)
+    arr[:] = list(values) * reps
+    mask = np.array([not _is_missing(v) for v in arr], dtype=bool)
+    for i in masked:
+        mask[i::len(values)] = False
+    return Column(ftype, arr, mask)
+
+
+@pytest.mark.parametrize("stage", ["one_hot", "smart_text"])
+@pytest.mark.parametrize("name,reps", PIVOT_PARAMS)
+def test_pivot_equals_the_per_row_loops(stage, name, reps):
+    from transmogrifai_tpu.impl.feature import vectorizers
+    case = PIVOT_CASES[name]
+    ftype = PickList if stage == "one_hot" else Text
+    feat = getattr(FeatureBuilder, ftype.__name__)("c").extract_field().as_predictor()
+    fit_col = _scalar_column(ftype, case["values"], case["masked"], reps)
+    seen = (fit_col if case["transform"] is None
+            else _scalar_column(ftype, case["transform"], (), reps))
+    for c in (fit_col, seen):   # the repeated cases reach the pandas pass
+        assert (reps == 1 or name == "all_null"
+                or c.mask.sum() > vectorizers._SMALL_HASH_PASS)
+    kw = dict(top_k=case["top_k"], min_support=case["min_support"] * reps,
+              track_nulls=case["track_nulls"])
+    st = (OneHotVectorizer(**kw) if stage == "one_hot"
+          else SmartTextVectorizer(max_cardinality=10 ** 6, **kw))
+    st.set_input(feat)
+    model = st.fit(FeatureTable({"c": fit_col}, len(fit_col)))
+    col = model.transform_column(FeatureTable({"c": seen}, len(seen)))
+
+    vocab, mat = _reference_pivot(
+        (fit_col.values, fit_col.mask), (seen.values, seen.mask),
+        kw["top_k"], kw["min_support"], kw["track_nulls"], multi=False)
+    got = model.vocabs[0] if stage == "one_hot" else model.plans[0]["vocab"]
+    assert got == vocab and all(type(v) is str for v in got)
+    vals = np.asarray(col.values)
+    assert vals.dtype == np.float32 and vals.shape == mat.shape
+    assert np.array_equal(vals, mat)
+    want = vocab + [OTHER_INDICATOR] + (
+        [NULL_INDICATOR] if kw["track_nulls"] else [])
+    assert [(c.parent_feature_name, c.parent_feature_type, c.grouping,
+             c.indicator_value, c.descriptor_value, c.index)
+            for c in col.metadata["vector_meta"].columns] == [
+        ("c", ftype.__name__, "c", v, None, i) for i, v in enumerate(want)]
+    # the row dual goes through the same code
+    for i in range(min(len(seen), 12)):
+        row = {"c": seen.values[i] if seen.mask[i] else None}
+        assert model.transform_row(row) == mat[i].tolist()
+
+
+@pytest.mark.parametrize("track_nulls", [True, False])
+def test_pivot_of_sets_equals_the_per_row_loops(track_nulls):
+    from transmogrifai_tpu.table import Column
+    tags = FeatureBuilder.MultiPickList("c").extract_field().as_predictor()
+    data = [{"a", "b"}, {"a"}, set(), None, {"c", "a"}, {"b"}, {"zz"}] * 3
+    seen = data + [{"new", "a"}, None]
+    st = OneHotVectorizer(top_k=3, min_support=3, track_nulls=track_nulls)
+    st.set_input(tags)
+    fit_col = Column.of_values(MultiPickList, data)
+    seen_col = Column.of_values(MultiPickList, seen)
+    model = st.fit(FeatureTable({"c": fit_col}, len(data)))
+    col = model.transform_column(FeatureTable({"c": seen_col}, len(seen)))
+    vocab, mat = _reference_pivot(
+        (fit_col.values, fit_col.mask), (seen_col.values, seen_col.mask),
+        3, 3, track_nulls, multi=True)
+    assert model.vocabs[0] == vocab == ["a", "b", "c"]   # "zz" ties, cut
+    assert np.array_equal(np.asarray(col.values), mat)
+    assert [c.indicator_value for c in col.metadata["vector_meta"].columns] \
+        == vocab + [OTHER_INDICATOR] + ([NULL_INDICATOR] if track_nulls else [])
+
+
+def test_hashing_raw_mixed_objects_would_not_pass(monkeypatch):
+    """What the mixed case is there for: a hash pass over the raw objects,
+    stringified afterwards, merges ``1``, ``True`` and ``1.0``."""
+    from transmogrifai_tpu.impl.feature import vectorizers
+
+    def raw_hash(vals, m):
+        import pandas as pd
+        valid = vals[m]
+        sub, uniques = pd.factorize(valid)
+        levels = [str(u) for u in uniques]
+        codes = np.full(len(vals), -1, dtype=np.intp)
+        codes[m] = sub
+        return codes, dict(zip(levels, np.bincount(
+            sub, minlength=len(levels)).tolist())), "hashed"
+
+    monkeypatch.setattr(vectorizers, "_factorize_valid", raw_hash)
+    with pytest.raises(AssertionError):
+        test_pivot_equals_the_per_row_loops(
+            "one_hot", "mixed_1_True_str1_float1", 64)
+    with pytest.raises(AssertionError):
+        test_pivot_equals_the_per_row_loops(
+            "smart_text", "mixed_1_True_str1_float1", 1)
+    test_pivot_equals_the_per_row_loops("one_hot", "all_str", 64)

@@ -19,6 +19,7 @@ from collections import Counter
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+import pandas as pd
 
 from ...features import Feature
 from ...observability.trace import span as _obs_span
@@ -332,6 +333,73 @@ class RealNNVectorizer(SequenceTransformer):
 # Categorical pivot (one-hot) vectorizer
 # ---------------------------------------------------------------------------
 
+#: up to this many values a dict comprehension beats the call into pandas
+#: (a serving request transforms one row; crossover read at 128-256 values)
+_SMALL_HASH_PASS = 128
+
+
+def _hash_pass(values: np.ndarray) -> Tuple[np.ndarray, List[str]]:
+    """``str`` values → (each value's index into the distinct values, the
+    distinct values in order of first appearance), one hash-table pass."""
+    if len(values) <= _SMALL_HASH_PASS:
+        seen: Dict[str, int] = {}
+        return np.array([seen.setdefault(v, len(seen)) for v in values],
+                        dtype=np.intp), list(seen)
+    codes, uniques = pd.factorize(values)
+    return codes, list(uniques)
+
+
+def _factorize_valid(vals: np.ndarray, m: np.ndarray
+                     ) -> Tuple[np.ndarray, Dict[str, int], str]:
+    """Values of the rows where ``m`` holds → ``(codes, counts, path)``:
+    ``counts`` maps each distinct value, as ``str`` and in order of first
+    appearance, to its occurrences; ``codes`` (n,) is each valid row's
+    position among them and -1 where ``m`` is false. ``path`` is
+    ``"hashed"`` where every valid value is a ``str`` already and the
+    objects are hashed as they are; anything else goes through ``str()``
+    first (``"str_pass"``), since ``1 == True == 1.0`` would merge under the
+    hash what ``str()`` keeps apart. No per-row Python on the hashed path."""
+    every = bool(m.all())
+    valid = vals if every else vals[m]
+    hashed = valid.dtype == object and pd.api.types.infer_dtype(
+        valid, skipna=False) in ("string", "empty")
+    if hashed:
+        sub, uniques = _hash_pass(valid)
+        levels = [str(u) for u in uniques]
+        # a str subclass with a str() of its own: levels that str() merges
+        hashed = len(set(levels)) == len(levels)
+    if not hashed:
+        sub, levels = _hash_pass(
+            np.array([str(v) for v in valid], dtype=object))
+    counts = dict(zip(levels,
+                      np.bincount(sub, minlength=len(levels)).tolist()))
+    path = "hashed" if hashed else "str_pass"
+    if every:
+        return sub, counts, path
+    codes = np.full(len(vals), -1, dtype=np.intp)
+    codes[m] = sub
+    return codes, counts, path
+
+
+def _top_levels(counts: Dict[str, int], min_support: int, top_k: int
+                ) -> List[str]:
+    """The pivot's vocabulary: levels seen at least ``min_support`` times,
+    by count descending then value ascending, cut at ``top_k``."""
+    top = [v for v, c in counts.items() if c >= min_support]
+    return sorted(top, key=lambda v: (-counts[v], v))[:top_k]
+
+
+def _encode_valid(vals: np.ndarray, m: np.ndarray, index: Dict[str, int]
+                  ) -> Tuple[np.ndarray, str]:
+    """Each row's vocabulary index under ``index`` (-1 for a level not in
+    it, -2 where ``m`` is false) and the path ``_factorize_valid`` took:
+    the dictionary is asked once per level, not once per row."""
+    codes, counts, path = _factorize_valid(vals, m)
+    # the last entry is the one the null rows' -1 reaches
+    lut = np.array([index.get(v, -1) for v in counts] + [-2], dtype=np.int64)
+    return lut[codes], path
+
+
 class OneHotVectorizer(Estimator):
     """Seq[Text-ish] → OPVector: top-K pivot with OTHER + null indicator
     (reference OpOneHotVectorizer.scala / OpTextPivotVectorizer — TopK by
@@ -359,12 +427,9 @@ class OneHotVectorizer(Estimator):
                     cnt = Counter(v for vs, ok in zip(vals, m) if ok
                                   for v in (vs or ()))
                 else:
-                    cnt = Counter(str(v) for v, ok in zip(vals, m) if ok)
-                top = [v for v, c in cnt.most_common()
-                       if c >= self.min_support]
-                # deterministic: count desc then value asc
-                top = sorted(top, key=lambda v: (-cnt[v], v))[: self.top_k]
-                vocabs.append(top)
+                    _, cnt, path = _factorize_valid(vals, m)
+                    count_span.set_attr(path=path)
+                vocabs.append(_top_levels(cnt, self.min_support, self.top_k))
                 count_span.set_attr(levels=len(cnt))
         model = OneHotVectorizerModel(vocabs=vocabs, track_nulls=self.track_nulls)
         return self._finalize_model(model)
@@ -388,10 +453,10 @@ class OneHotVectorizerModel(_VectorModelBase):
                              dtype=np.float32)
             index = {v: i for i, v in enumerate(vocab)}
             multi = col.kind == "multipicklist"
-            # values to codes: the str() pass and the dictionary loop (a
+            # values to codes: one hash pass and a look-up per level (a
             # multi-valued column has no code per row and fills its block
             # as it goes)
-            with _obs_span("onehot.encode", column=f.name):
+            with _obs_span("onehot.encode", column=f.name) as encode_span:
                 if multi:
                     for i, (vs, ok) in enumerate(zip(vals, m)):
                         if not ok:
@@ -399,11 +464,8 @@ class OneHotVectorizerModel(_VectorModelBase):
                         for v in (vs or ()):
                             block[i, index.get(v, k)] = 1.0
                 else:
-                    codes = np.full(n, -2, dtype=np.int64)  # -2 null, -1 OTHER
-                    svals = np.array([str(v) if ok else ""
-                                      for v, ok in zip(vals, m)], dtype=object)
-                    for i_ok in np.nonzero(m)[0]:
-                        codes[i_ok] = index.get(svals[i_ok], -1)
+                    codes, path = _encode_valid(vals, m, index)
+                    encode_span.set_attr(path=path)
             # codes to the dense block
             with _obs_span("onehot.expand", column=f.name):
                 if not multi:
@@ -883,11 +945,10 @@ class SmartTextVectorizer(Estimator):
             col = table[f.name]
             vals = np.asarray(col.values)
             m = col.valid_mask()
-            cnt = Counter(str(v) for v, ok in zip(vals, m) if ok)
+            _, cnt, _ = _factorize_valid(vals, m)
             if len(cnt) <= self.max_cardinality:
-                top = [v for v, c in cnt.most_common() if c >= self.min_support]
-                top = sorted(top, key=lambda v: (-cnt[v], v))[: self.top_k]
-                plans.append({"kind": "pivot", "vocab": top})
+                plans.append({"kind": "pivot", "vocab": _top_levels(
+                    cnt, self.min_support, self.top_k)})
             else:
                 plans.append({"kind": "hash"})
         model = SmartTextVectorizerModel(
@@ -915,9 +976,8 @@ class SmartTextVectorizerModel(_VectorModelBase):
                 k = len(vocab)
                 block = np.zeros((n, k + 1), dtype=np.float32)
                 index = {v: i for i, v in enumerate(vocab)}
-                for i in np.nonzero(m)[0]:
-                    j = index.get(str(vals[i]), -1)
-                    block[i, j if j >= 0 else k] = 1.0
+                codes = _encode_valid(vals, m, index)[0][m]
+                block[m, np.where(codes >= 0, codes, k)] = 1.0
                 blocks.append(block)
                 meta.extend(_meta_cols(
                     f, [(f.name, v) for v in vocab] + [(f.name, OTHER_INDICATOR)]))
