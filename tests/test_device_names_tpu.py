@@ -62,3 +62,22 @@ def test_the_descent_kernel_keeps_the_name_score_descent_s_reads(
     assert kernels_named, "no Pallas kernel in the compiled program"
     assert all("_predict_rf_chain_batch" in name for name in kernels_named), \
         kernels_named
+
+
+def test_the_softmax_refit_compiles_for_the_chip_at_the_cells_size(one_chip):
+    """``train-kddcup99``'s refit: one lane of 23 classes over the selector's
+    900 000 rows (bucket 1 048 576) x 76 columns, float32 temporaries and
+    HIGHEST-precision contractions. The chip's compiler takes it, and it
+    asks for well under the chip's 16 GB."""
+    from transmogrifai_tpu.models import linear
+    n, d, C = 1048576, 76, 23
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    compiled = jax.jit(
+        lambda X, y, W, r, e: linear._fit_softmax_batch(X, y, W, r, e, C)
+    ).lower(shape((n, d), jnp.float32), shape((n,), jnp.int32),
+            shape((1, n), jnp.float32), shape((1,), jnp.float32),
+            shape((1,), jnp.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+    assert "while" in compiled.as_text()       # the schedule stays a loop

@@ -310,7 +310,7 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         label_f, vec_f = self.input_features
         # label preparation, the split, and the one upload of the feature
         # matrix and labels: everything before the sweep
-        with _obs_span("selector.prepare", cat="train"):
+        with _obs_span("selector.prepare", cat="train") as prepare_span:
             y_all = np.asarray(table[label_f.name].values,
                                dtype=np.float32).reshape(-1)
             # the feature matrix never visits the host again: row selections
@@ -350,6 +350,10 @@ class ModelSelector(AllowLabelAsInput, Estimator):
             _count_transfer_bytes(yd, "h2d")
             Xd = Xd_all[sel_d]
             del sel_d        # an index vector, not to outlive its gather
+            if "labelsKept" in prep.summary:     # what DataCutter kept
+                prepare_span.set_attr(
+                    labelsKept=len(prep.summary["labelsKept"]),
+                    rowsKept=prep.summary["rowsKept"])
         preset = getattr(self, "_preset_best", None)
         if preset is not None:
             # workflow-level CV already ran (find_best_estimator); skip the
@@ -428,7 +432,12 @@ class ModelSelector(AllowLabelAsInput, Estimator):
                                                 kind="quarantine", detail=rec))
             refit_span.set_attr(family=best_used[0],
                                 attempts=(len(refit_quarantine)
-                                          + (fitted is not None)))
+                                          + (fitted is not None)),
+                                classes=num_classes, lanes=1, rows=n_fit,
+                                features=int(Xf.shape[1]),
+                                **MODEL_REGISTRY[best_used[0]].fit_span_attrs(
+                                    n_pad, int(Xf.shape[1]), [best_used[1]],
+                                    num_classes, False))
         if fitted is None:
             raise AllCandidatesFailedError(
                 list(best.quarantined) + refit_quarantine)

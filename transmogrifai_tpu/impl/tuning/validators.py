@@ -855,6 +855,16 @@ class OpValidator:
             with _obs_span("sweep.family", cat="sweep", family=family.name,
                            configs=len(grid), folds=F, metric=metric_name,
                            order=dispatched) as sweep_span:
+                if tracing_enabled():
+                    # the fit's shape, and what the family's own schedule
+                    # fixes about it (contractions, chunks of lanes)
+                    sweep_span.set_attr(
+                        classes=num_classes, lanes=F * len(grid), rows=n,
+                        features=int(X.shape[-1]),
+                        **family.fit_span_attrs(
+                            int(X.shape[0]), int(X.shape[-1]),
+                            list(grid) * F, num_classes,
+                            not self.exact_sweep_fits))
                 # flight-recorder: each family dispatch, stamped with the
                 # owning run's correlation id (workflow.train) — a sweep
                 # post-mortem shows which family the incident interrupted

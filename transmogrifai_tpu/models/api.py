@@ -91,6 +91,17 @@ class ModelFamily(abc.ABC):
         to ``fit_batch``."""
         return self.fit_batch(X, y, weights, grid, num_classes)
 
+    def fit_span_attrs(self, rows: int, features: int,
+                       grid: Sequence[Dict[str, Any]], num_classes: int,
+                       sweep: bool) -> Dict[str, Any]:
+        """What this family's own schedule fixes about one fit of ``grid``
+        (one entry per lane) over a (rows, features) matrix, as attributes
+        for the ``sweep.family`` and ``selector.refit`` spans: how many
+        contractions a solver runs, into how many chunks a chunker splits
+        the lanes. Computed from shapes by the rule the program itself
+        uses; nothing is fetched. Default: none."""
+        return {}
+
     @abc.abstractmethod
     def predict_batch(self, params: Any, X: jnp.ndarray,
                       num_classes: int) -> jnp.ndarray:

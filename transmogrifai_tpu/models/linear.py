@@ -81,15 +81,17 @@ class _BatchStd:
     and hence Spark's regularization semantics (standardization=true) — is
     invariant to the global affine map. X is never copied per config."""
 
-    def __init__(self, X, W):
+    def __init__(self, X, W, precision=None):
         g_mean = X.mean(axis=0)
         g_scale = jnp.sqrt(jnp.maximum(X.var(axis=0), 1e-12))
         self.g_mean, self.g_scale = g_mean, g_scale
         self.Xg = (X - g_mean) / g_scale
         self.Wt = W.T                                        # (n, B)
         self.cnt = jnp.maximum(W.sum(axis=1), 1.0)           # (B,)
-        mean = (self.Wt.T @ self.Xg) / self.cnt[:, None]     # (B, d)
-        ex2 = (self.Wt.T @ (self.Xg * self.Xg)) / self.cnt[:, None]
+        mean = jnp.matmul(self.Wt.T, self.Xg,
+                          precision=precision) / self.cnt[:, None]  # (B, d)
+        ex2 = jnp.matmul(self.Wt.T, self.Xg * self.Xg,
+                         precision=precision) / self.cnt[:, None]
         var_raw = ex2 - mean ** 2
         self.var = jnp.maximum(var_raw, 1e-12)
         # a column that is CONSTANT within a config's weighted rows (e.g. a
@@ -256,8 +258,13 @@ class LogisticRegressionFamily(ModelFamily):
             coef, bias = _fit_logreg_batch(
                 X, y, weights, grid["regParam"], grid["elasticNetParam"])
             return {"coef": coef, "bias": bias}
+        return self._fit_softmax(X, y, weights, grid, num_classes, False)
+
+    @staticmethod
+    def _fit_softmax(X, y, weights, grid, num_classes, sweep):
         W, b = _fit_softmax_batch(X, y.astype(jnp.int32), weights,
-                                  grid["regParam"], num_classes)
+                                  grid["regParam"], grid["elasticNetParam"],
+                                  num_classes, sweep=sweep)
         return {"W": W, "b": b}
 
     def sweep_fit_batch(self, X, y, weights, grid, num_classes):
@@ -269,7 +276,14 @@ class LogisticRegressionFamily(ModelFamily):
                 X, y, weights, grid["regParam"], grid["elasticNetParam"],
                 newton_iters=8, cg_iters=6, sweep=True)
             return {"coef": coef, "bias": bias}
-        return self.fit_batch(X, y, weights, grid, num_classes)
+        return self._fit_softmax(X, y, weights, grid, num_classes, True)
+
+    def fit_span_attrs(self, rows, features, grid, num_classes, sweep):
+        if num_classes <= 2:
+            return {}
+        chunk = softmax_lane_chunk(rows, len(grid), num_classes)
+        return {"contractions": softmax_contractions(sweep),
+                "laneChunks": -(-len(grid) // chunk)}
 
     def predict_batch(self, params, X, num_classes):
         if num_classes <= 2:
@@ -288,8 +302,10 @@ class LogisticRegressionFamily(ModelFamily):
             prob = jnp.stack([1 - p1, p1], axis=1)
             raw = jnp.stack([-margin, margin], axis=1)
         else:
-            raw = X @ jnp.asarray(fitted.params["W"]) \
-                + jnp.asarray(fitted.params["b"])
+            # a real (n,d)@(d,C) product: at the chip's default precision
+            # its bfloat16 passes move a probability by 1e-2 (PR 26)
+            raw = jnp.dot(X, jnp.asarray(fitted.params["W"]),
+                          precision=_PREC) + jnp.asarray(fitted.params["b"])
             prob = jax.nn.softmax(raw, axis=-1)
         pred = prob.argmax(axis=1).astype(jnp.float32)
         return {"prediction": pred, "probability": prob, "rawPrediction": raw}
@@ -299,70 +315,234 @@ class LogisticRegressionFamily(ModelFamily):
                 for k, v in self.predict_parts(fitted, X).items()}
 
 
-@partial(jax.jit, static_argnames=("num_classes", "iters"))
-def _fit_softmax_batch(X, y_idx, W_rows, reg, num_classes, iters=200):
-    """Multinomial logistic regression, all B configs in one program of
-    shared matmuls: full-batch Adam whose forward/backward are single
-    (n,d)@(d,B·C) / (d,n)@(n,B·C) contractions via the same standardization
-    algebra as the binary solver. W_rows: (B, n) row weights; reg: (B,).
-    Returns (W (B, d, C), b (B, C)) in original scale."""
+# ---------------------------------------------------------------------------
+# Multinomial logistic regression — batched orthant-wise Newton-CG
+#
+# Built as the binary solver above: every heavy op is one of the two shared
+# contractions (n,d)@(d,B·C) and (d,n)@(n,B·C) over the globally standardized
+# matrix, per-lane standardization is coefficient algebra (_BatchStd), the
+# Newton direction comes from a fixed-length conjugate-gradient solve. What
+# C classes add:
+# * the curvature of a rare class's free intercept is its share of the rows
+#   (1e-5 here and there), so the solve is preconditioned by the Hessian's
+#   diagonal (two more contractions a Newton step), and the intercepts start
+#   at the log class shares, where every Newton step is a small one;
+# * the binary solver's L1 step (soft threshold in the diagonal metric after
+#   the Newton step) over-shrinks where that diagonal is a rare class's, so
+#   the L1 term is handled orthant-wise with two metrics: coordinates off
+#   zero take the Newton-CG step on the pseudo-gradient together and stop at
+#   zero where they would change sign; one AT zero whose gradient exceeds l1
+#   leaves by its own diagonal step. Its fixed point is the elastic-net
+#   optimum whatever the Hessian's off-diagonal;
+# * a step is tried at 1, 1/4 and 1/16 of its length (one contraction each)
+#   and the first that does not raise the objective is taken: orthant
+#   projections make full Newton steps cycle at small penalties.
+# ---------------------------------------------------------------------------
+
+#: element budget of ONE (rows, lanes, classes) temporary of the softmax
+#: solver (a lane is one grid point on one fold): lanes are batched until
+#: rows·lanes·classes reaches it, then ``lax.map`` runs the chunks of lanes
+#: one after another, as the tree growers chunk their configurations
+_SOFTMAX_LANE_ELEMS = 1 << 28
+
+#: (Newton steps, conjugate-gradient steps per Newton step): the refit's
+#: schedule (float32 temporaries) and the sweep's (bfloat16 temporaries).
+#: An L2 fit is at its float32 floor after 6 Newton steps; a weak L1 term
+#: (regParam 0.01, elasticNetParam 0.5) needs some 20 to settle which
+#: coordinates are zero, and the refit is one lane, so it gets 24
+_SOFTMAX_SCHEDULE = {False: (24, 12), True: (8, 8)}
+
+#: step lengths tried, longest first
+_SOFTMAX_STEPS = (1.0, 0.25, 0.0625)
+
+
+def softmax_lane_chunk(rows: int, lanes: int, num_classes: int) -> int:
+    """Lanes per chunk under ``_SOFTMAX_LANE_ELEMS``, the chunks evened out
+    (18 lanes under a budget of 12 run as 9 + 9, not 12 + 6)."""
+    cap = max(1, _SOFTMAX_LANE_ELEMS // max(1, rows * num_classes))
+    n_chunks = -(-lanes // min(cap, lanes))
+    return -(-lanes // n_chunks)
+
+
+def softmax_contractions(sweep: bool) -> int:
+    """How many (n,d)x(d,lanes·C) products one softmax fit runs per chunk
+    of lanes: the starting objective, then per Newton step the margins, the
+    gradient, two for the Hessian's diagonal, two per conjugate-gradient
+    step and one per step length tried."""
+    newton, cg = _SOFTMAX_SCHEDULE[bool(sweep)]
+    return 1 + newton * (4 + 2 * cg + len(_SOFTMAX_STEPS))
+
+
+@partial(jax.jit, static_argnames=("num_classes", "sweep", "lane_chunk"))
+def _fit_softmax_batch(X, y_idx, W_rows, reg, elastic_net, num_classes,
+                       sweep=False, lane_chunk=None):
+    """Multinomial logistic regression, B lanes at once. W_rows: (B, n) row
+    weights; reg/elastic_net: (B,). Returns (W (B, d, C), b (B, C)) in
+    original scale, the intercepts centred over the classes.
+
+    ``sweep``: keep the (n, lanes, C) temporaries in bfloat16 and run the
+    shorter schedule (CV candidates need metric-ranking accuracy; every
+    reduction still accumulates f32); the winner's refit runs with
+    sweep=False, its contractions at full float32 precision.
+    ``lane_chunk``: lanes per chunk (default: what ``_SOFTMAX_LANE_ELEMS``
+    allows); each lane's fit is its own, so the result does not depend on
+    it."""
     C = num_classes
-    nB = W_rows.shape[0]
+    nB, n = W_rows.shape
     d = X.shape[1]
-    std = _BatchStd(X, W_rows)
-    Xg, cnt = std.Xg, std.cnt
-    mean, scale = std.mean, std.scale                   # (B, d)
-    Wt = W_rows.T                                       # (n, B)
-    Y = jax.nn.one_hot(y_idx, C, dtype=X.dtype)         # (n, C)
+    newton_iters, cg_iters = _SOFTMAX_SCHEDULE[bool(sweep)]
+    prec = None if sweep else _PREC
+    std = _BatchStd(X, W_rows, precision=prec)
+    cdt = jnp.bfloat16 if sweep else X.dtype
+    f32 = jnp.float32
+    # an objective may rise by this share and the step still be taken: the
+    # rounding of the objective's own sum, below which steps are Newton's
+    slack = 1e-3 if sweep else 1e-6
+    Xg_c = std.Xg.astype(cdt)
+    Xg2_c = Xg_c * Xg_c
+    Y_c = jax.nn.one_hot(y_idx, C, dtype=cdt)               # (n, C)
 
-    def grads(Wc, b):
-        """Wc: (B, d, C) per-config standardized coefs; b: (B, C)."""
-        At = Wc / scale[:, :, None]                     # (B, d, C)
-        off = (mean[:, :, None] * At).sum(axis=1)       # (B, C)
-        Z = jnp.einsum("nd,bdc->nbc", Xg, At) + (b - off)[None]
-        P = jax.nn.softmax(Z, axis=-1)
-        R = Wt[:, :, None] * (P - Y[:, None, :])        # (n, B, C)
-        GX = jnp.einsum("nd,nbc->bdc", Xg, R)           # Xgᵀ R
-        Rsum = R.sum(axis=0)                            # (B, C)
-        g_W = ((GX - mean[:, :, None] * Rsum[:, None, :]) / scale[:, :, None]
-               / cnt[:, None, None]) + reg[:, None, None] * Wc
-        g_b = Rsum / cnt[:, None]
-        return g_W, g_b
+    def one_chunk(lane):
+        W_c, mean, scale, var, cnt, l1, l2 = lane           # leading axis cb
+        cb = W_c.shape[0]
+        Wt_c = W_c.T.astype(cdt)[:, :, None]                # (n, cb, 1)
+        inv = (1.0 / scale)[:, :, None]                     # (cb, d, 1)
+        m3 = mean[:, :, None]
+        cnt3, l1_3, l2_3 = (v[:, None, None] for v in (cnt, l1, l2))
 
-    # hand-rolled Adam: a few lines here, no optimizer library needed
-    lr, b1, b2, eps = 0.1, 0.9, 0.999, 1e-8
-    params = (jnp.zeros((nB, d, C), X.dtype), jnp.zeros((nB, C), X.dtype))
-    zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
+        def xs_dot(A, b):
+            """Xs·A + b for A (cb, d, C), b (cb, C) -> (n, cb, C) cdt."""
+            At = A * inv
+            off = b - (m3 * At).sum(axis=1)                 # (cb, C)
+            Z = jnp.dot(Xg_c, At.transpose(1, 0, 2).reshape(d, cb * C)
+                        .astype(cdt), preferred_element_type=cdt,
+                        precision=prec)
+            return Z.reshape(n, cb, C) + off[None].astype(cdt)
 
-    def step(carry, i):
-        params, m, v = carry
-        g = grads(*params)
-        m = jax.tree_util.tree_map(lambda a, b_: b1 * a + (1 - b1) * b_, m, g)
-        v = jax.tree_util.tree_map(
-            lambda a, b_: b2 * a + (1 - b2) * b_ * b_, v, g)
-        t = i + 1.0
-        params = jax.tree_util.tree_map(
-            lambda p, mm, vv: p - lr * (mm / (1 - b1 ** t)) /
-            (jnp.sqrt(vv / (1 - b2 ** t)) + eps), params, m, v)
-        return (params, m, v), None
+        def xt_dot(V, Xc):
+            """Xcᵀ·V for V (n, cb, C) cdt -> (cb, d, C) f32."""
+            vt = jnp.dot(V.reshape(n, cb * C).T, Xc,
+                         preferred_element_type=f32, precision=prec)
+            return vt.reshape(cb, C, d).transpose(0, 2, 1)
 
-    (params, _, _), _ = jax.lax.scan(
-        step, (params, zeros, zeros), jnp.arange(iters, dtype=X.dtype))
-    Wc, b = params
-    # per-config standardized → Xg space → original space (per class)
-    W_g = Wc / scale[:, :, None]
-    b_g = b - (W_g * mean[:, :, None]).sum(axis=1)
-    Wx = W_g / std.g_scale[None, :, None]
-    bx = b_g - (Wx * std.g_mean[None, :, None]).sum(axis=1)
-    return Wx, bx
+        def xs_t_dot(V):
+            """Xsᵀ·V -> (cb, d, C) f32, and V's column sums (cb, C)."""
+            vsum = jnp.sum(V, axis=0, dtype=f32)
+            return (xt_dot(V, Xg_c) - m3 * vsum[:, None, :]) * inv, vsum
 
+        def zero_mean(vb):               # the softmax's null direction out
+            return vb - vb.mean(axis=1, keepdims=True)
 
-def _fit_softmax(X, y_idx, w, reg, num_classes, iters=200):
-    """Single-config fit: the B=1 slice of the batched solver."""
-    W, b = _fit_softmax_batch(X, y_idx, w[None, :],
-                              jnp.asarray([reg], X.dtype), num_classes,
-                              iters=iters)
-    return W[0], b[0]
+        def objective(A, b):
+            """(cb,) mean cross-entropy + penalty."""
+            Z = xs_dot(A, b).astype(f32)
+            ce = jax.nn.logsumexp(Z, axis=-1) - (Z * Y_c[:, None, :]
+                                                 ).sum(axis=-1)
+            return ((Wt_c[:, :, 0] * ce).sum(axis=0, dtype=f32) / cnt
+                    + (0.5 * l2_3 * A * A + l1_3 * jnp.abs(A)
+                       ).sum(axis=(1, 2)))
+
+        def newton_step(carry, _):
+            A, b, loss = carry                    # (cb,d,C) (cb,C) (cb,)
+            P = jax.nn.softmax(xs_dot(A, b).astype(f32), axis=-1).astype(cdt)
+            R = Wt_c * (P - Y_c[:, None, :])
+            gA, rsum = xs_t_dot(R)
+            gA = gA / cnt3 + l2_3 * A
+            gb = zero_mean(rsum / cnt[:, None])
+            pg = jnp.where(A != 0, gA + l1_3 * jnp.sign(A),
+                           jnp.sign(gA) * jnp.maximum(jnp.abs(gA) - l1_3,
+                                                      0.0))
+            free = ((A != 0) | (l1_3 <= 0)).astype(f32)
+            # the Hessian's diagonal: Σ s·xs² = (SᵀXg² − 2 mean·SᵀXg
+            # + Σs·mean²) / var, s = w·p(1−p)
+            S = Wt_c * (P * (1 - P))
+            ssum = jnp.sum(S, axis=0, dtype=f32)            # (cb, C)
+            DA = (xt_dot(S, Xg2_c) - 2 * m3 * xt_dot(S, Xg_c)
+                  + ssum[:, None, :] * m3 ** 2) / var[:, :, None]
+            DA = jnp.maximum(DA, 0.0) / cnt3 + l2_3 + 1e-8
+            Db = jnp.maximum(ssum / cnt[:, None], 1e-12)
+
+            def hv(VA, vb):                                 # H·[v; v_b]
+                U = xs_dot(VA, vb).astype(f32)
+                Pf = P.astype(f32)
+                T = Wt_c * (Pf * (U - (Pf * U).sum(axis=-1, keepdims=True))
+                            ).astype(cdt)
+                hA, tsum = xs_t_dot(T)
+                hA = (hA / cnt3 + jnp.maximum(l2_3, 1e-4) * VA) * free
+                return hA, zero_mean(tsum / cnt[:, None])
+
+            def dots(uA, ub, vA, vb):
+                return (uA * vA).sum(axis=(1, 2)) + (ub * vb).sum(axis=1)
+
+            def cg_step(c, _):
+                dA, db, rA, rb, pA, pb, rz = c
+                hA, hb = hv(pA, pb)
+                alpha = rz / jnp.maximum(dots(pA, pb, hA, hb), 1e-30)
+                a3 = alpha[:, None, None]
+                dA = dA + a3 * pA
+                db = db + alpha[:, None] * pb
+                rA = rA - a3 * hA
+                rb = rb - alpha[:, None] * hb
+                zA, zb = rA / DA, zero_mean(rb / Db)
+                rz_new = dots(rA, rb, zA, zb)
+                beta = rz_new / jnp.maximum(rz, 1e-30)
+                pA = zA + beta[:, None, None] * pA
+                pb = zb + beta[:, None] * pb
+                return (dA, db, rA, rb, pA, pb, rz_new), None
+
+            rA = pg * free
+            zA, zb = rA / DA, zero_mean(gb / Db)
+            (dA, db, *_), _ = jax.lax.scan(
+                cg_step, (jnp.zeros_like(A), jnp.zeros_like(b), rA, gb,
+                          zA, zb, dots(rA, gb, zA, zb)), None,
+                length=cg_iters)
+            dA = dA + (1.0 - free) * pg / DA
+
+            def try_step(c, t):
+                A_k, b_k, loss_k, done = c
+                A_t = A - t * dA
+                # a coordinate off zero may not change sign in one step
+                A_t = jnp.where((l1_3 > 0) & (A_t * A < 0), 0.0, A_t)
+                b_t = b - t * db
+                loss_t = objective(A_t, b_t)
+                take = ~done & (loss_t <= loss + slack * jnp.abs(loss))
+                return (jnp.where(take[:, None, None], A_t, A_k),
+                        jnp.where(take[:, None], b_t, b_k),
+                        jnp.where(take, loss_t, loss_k), done | take), None
+
+            (A, b, loss, _), _ = jax.lax.scan(
+                try_step, (A, b, loss, jnp.zeros((cb,), bool)),
+                jnp.asarray(_SOFTMAX_STEPS, f32))
+            return (A, b, loss), None
+
+        prior = jnp.maximum(
+            jnp.dot(W_c, Y_c.astype(f32), preferred_element_type=f32,
+                    precision=prec), 0.5) / cnt[:, None]
+        A0 = jnp.zeros((cb, d, C), f32)
+        b0 = zero_mean(jnp.log(prior))
+        with jax.named_scope("linear.softmax_newton_cg"):
+            (A, b, _), _ = jax.lax.scan(
+                newton_step, (A0, b0, objective(A0, b0)), None,
+                length=newton_iters)
+        # per-lane standardized -> Xg space -> original space (per class)
+        W_g = A * inv
+        b_g = b - (W_g * m3).sum(axis=1)
+        Wx = W_g / std.g_scale[None, :, None]
+        bx = b_g - (Wx * std.g_mean[None, :, None]).sum(axis=1)
+        return Wx, zero_mean(bx)
+
+    cb = lane_chunk or softmax_lane_chunk(n, nB, C)
+    n_chunks = -(-nB // cb)
+    lanes = (W_rows, std.mean, std.scale, std.var, std.cnt,
+             reg * elastic_net, reg * (1.0 - elastic_net))
+    if n_chunks == 1:
+        return one_chunk(lanes)
+    idx = jnp.arange(n_chunks * cb) % nB
+    lanes = jax.tree_util.tree_map(
+        lambda a: a[idx].reshape((n_chunks, cb) + a.shape[1:]), lanes)
+    Wx, bx = jax.lax.map(one_chunk, lanes)
+    return (Wx.reshape((n_chunks * cb, d, C))[:nB],
+            bx.reshape((n_chunks * cb, C))[:nB])
 
 
 # ---------------------------------------------------------------------------

@@ -63,6 +63,7 @@ from ..ops.forest import (
     forest_predict_chain,
 )
 from ..histeng import build_hist, build_node_hist, pinned_row_sum
+from ..histeng.kernels import _hist_shards
 from .api import FittedParams, ModelFamily, register_family
 
 N_BINS = 32  # Spark maxBins default (reference DefaultSelectorParams.MaxBin)
@@ -150,6 +151,11 @@ _CHAIN_SIBLING_MIN_TB = 128
 #: through the cumsum/gain chain, so ~1 GB per tensor keeps peak HBM well
 #: inside a 16 GB chip even with that multiplier
 _LEVEL_HIST_ELEMS = 1 << 28
+
+#: element budget (f32) of the row-block partials of one flat histogram
+#: build (histeng ``_hist_xla_pinned``: K blocks x stat columns x d·n_bins);
+#: bounds the chunk only where there are more than two statistic planes
+_HIST_PARTIAL_ELEMS = 1 << 30
 
 
 # ---------------------------------------------------------------------------
@@ -792,6 +798,41 @@ def _fit_dt_batch(X, y, weights, max_depth, min_inst, min_gain, *,
             "edges": edges}
 
 
+def _rf_config_chunk(B: int, S: int, n_trees: int, depth: int, n_slots: int,
+                     k: int, d: int, n_bins: int) -> int:
+    """Configurations per chunk of ``_fit_rf_batch``'s ``lax.map``."""
+    deep = n_slots > 0
+    # chunk budget covers BOTH the grower's bf16 (S, Tb·nodes) transients
+    # and the sweep leaf-stat path's f32 (S, k+1, Tb) A_cols tensor (f32
+    # counts double in the bf16-element budget); the capped grower's level
+    # width is n_slots·k (no sibling subtraction, k stat planes per slot)
+    lane_w = (min(2 ** (depth - 1), n_slots) * k if deep
+              else 2 ** (depth - 1))
+    cb = max(1, min(B, _CFG_CHUNK_ELEMS
+                    // (S * n_trees * max(lane_w, 2 * (k + 1)))))
+    # ...AND the per-level histogram/gain pipeline, whose (Tb·nodes, d,
+    # n_bins, k) f32 tensors scale with the FEATURE count, not the sample:
+    # at small S the first bound lets whole wide grids through, and a
+    # 600-column text-hashed vector at depth 12 then asks for >25 GB of
+    # HBM (seen on the Titanic pipeline; XLA holds several of these
+    # alive across the cumsum/gain chain)
+    nodes_w = min(2 ** depth, n_slots) if deep else 2 ** (depth - 1)
+    cb = max(1, min(cb, _LEVEL_HIST_ELEMS
+                    // (n_trees * nodes_w * d * n_bins * k)))
+    if k > 2:
+        # ...AND, with more than two statistic planes (a many-class label),
+        # the flat histogram's row-blocked contraction: past the Pallas
+        # kernel's 1024 stat columns the engine keeps one f32 partial of
+        # (trees·nodes·k, d·n_bins) per row block alive at once. At 23
+        # planes, 9 depth-6 configurations a chunk asked the chip's compiler
+        # for 19.2 GB (6.8 GB of it this one tensor) and the sweep fell down
+        # the exhaustion ladder (PR 26). Two planes never bind here.
+        cb = max(1, min(cb, _HIST_PARTIAL_ELEMS
+                        // (_hist_shards() * n_trees * nodes_w * d * n_bins
+                            * k)))
+    return cb
+
+
 @partial(jax.jit, static_argnames=("depth", "n_bins", "num_classes", "task",
                                    "n_trees", "sweep", "n_slots"))
 def _fit_rf_batch(X, y, weights, max_depth, min_inst, min_gain, num_trees,
@@ -811,23 +852,7 @@ def _fit_rf_batch(X, y, weights, max_depth, min_inst, min_gain, num_trees,
     deep = n_slots > 0
     L = min(2 ** depth, n_slots) if deep else 2 ** depth
     B = weights.shape[0]
-    # chunk budget covers BOTH the grower's bf16 (S, Tb·nodes) transients
-    # and the sweep leaf-stat path's f32 (S, k+1, Tb) A_cols tensor (f32
-    # counts double in the bf16-element budget); the capped grower's level
-    # width is n_slots·k (no sibling subtraction, k stat planes per slot)
-    lane_w = (min(2 ** (depth - 1), n_slots) * k if deep
-              else 2 ** (depth - 1))
-    cb = max(1, min(B, _CFG_CHUNK_ELEMS
-                    // (S * n_trees * max(lane_w, 2 * (k + 1)))))
-    # ...AND the per-level histogram/gain pipeline, whose (Tb·nodes, d,
-    # n_bins, k) f32 tensors scale with the FEATURE count, not the sample:
-    # at small S the first bound lets whole wide grids through, and a
-    # 600-column text-hashed vector at depth 12 then asks for >25 GB of
-    # HBM (seen on the Titanic pipeline; XLA holds several of these
-    # alive across the cumsum/gain chain)
-    nodes_w = min(2 ** depth, n_slots) if deep else 2 ** (depth - 1)
-    cb = max(1, min(cb, _LEVEL_HIST_ELEMS
-                    // (n_trees * nodes_w * d * n_bins * k)))
+    cb = _rf_config_chunk(B, S, n_trees, depth, n_slots, k, d, n_bins)
 
     def one_chunk(w_c, md, mi, mg, ss, seed):
         """Grow a chunk of cb configs — cb·n_trees trees — in one
@@ -1585,6 +1610,34 @@ class RandomForestFamilyBase(_TreeFamilyBase):
         return _fit_depth_grouped(
             grid, weights, fit_group, N_BINS, leaf_axis=-2,
             fit_group_deep=fit_group, n_slots=n_slots)
+
+    def fit_span_attrs(self, rows, features, grid, num_classes, sweep):
+        """``configChunks``: how many chunks of configurations the fit's
+        ``lax.map``s run over its depth groups, by the rules of
+        ``fit_batch``, ``_fit_depth_grouped`` and ``_rf_config_chunk``
+        (tests/test_multiclass_support.py holds them to the traced count)."""
+        if any("maxDepth" not in g for g in grid):
+            return {}
+        n_trees = int(max(g.get("numTrees", 20.0) for g in grid))
+        if sweep and not round4_defaults():
+            n_trees = min(n_trees, _SWEEP_RF_TREES)
+        S = min(rows, _sweep_hist_sample() if sweep else _HIST_SAMPLE)
+        k = (max(num_classes, 2)
+             if self._task(num_classes) == "classification" else 3)
+        depths = [int(g["maxDepth"]) for g in grid]
+        uniq = sorted(set(depths))
+        slots = _SWEEP_SLOTS if sweep else _REFIT_SLOTS
+        if len(uniq) > 1 and uniq[-1] > _MAX_HEAP_DEPTH:
+            slots = max(slots, 2 ** max(
+                [u for u in uniq if u <= _MAX_HEAP_DEPTH], default=0))
+        chunks = 0
+        for u in uniq:
+            B = depths.count(u)
+            cb = _rf_config_chunk(B, S, n_trees, u,
+                                  slots if u > _MAX_HEAP_DEPTH else 0, k,
+                                  features, N_BINS)
+            chunks += -(-B // cb)
+        return {"configChunks": chunks}
 
     def predict_batch(self, params, X, num_classes):
         edges = self._edges_of(params)
