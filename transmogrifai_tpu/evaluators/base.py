@@ -14,6 +14,7 @@ import numpy as np
 from ..features import Feature
 from ..table import Column, FeatureTable
 from ..types import Prediction
+from ..utils.padding import bucket_for, pad_rows, padded_valid_mask
 
 
 def prediction_parts(col: Column) -> Dict[str, np.ndarray]:
@@ -68,9 +69,23 @@ class OpEvaluatorBase(abc.ABC):
         parts = prediction_parts(table[self.prediction_col])
         return label, parts
 
-    @abc.abstractmethod
     def evaluate_all(self, table: FeatureTable) -> Dict[str, float]:
-        """Compute all metrics for this evaluator."""
+        """Compute all metrics for this evaluator: :meth:`evaluate_parts`
+        of the table's label and prediction columns. An evaluator states its
+        metrics in ``evaluate_parts``; one that overrides this method instead
+        is handed whole tables (see :func:`evaluates_parts`)."""
+        return self.evaluate_parts(*self._extract(table))
+
+    def evaluate_parts(self, label, parts: Dict[str, Any],
+                       mask=None) -> Dict[str, float]:
+        """All metrics from the label (n,) and the prediction parts
+        (``prediction`` (n,), ``probability`` / ``rawPrediction`` (n, k)) as
+        arrays, on the host or on the device. With ``mask`` the arrays are
+        already padded to a row bucket and ``mask`` is False on the padding;
+        without, they are padded here (:func:`pad_rows_to_bucket`), so that
+        the metric programs are shared across dataset sizes."""
+        raise NotImplementedError(
+            f"{type(self).__name__} states its metrics in evaluate_all")
 
     def evaluate(self, table: FeatureTable) -> float:
         """The single default metric (used by ModelSelector)."""
@@ -80,3 +95,27 @@ class OpEvaluatorBase(abc.ABC):
                         probability: Optional[np.ndarray] = None) -> float:
         """Array-level fast path used inside CV loops (no table plumbing)."""
         raise NotImplementedError
+
+
+def evaluates_parts(evaluator) -> bool:
+    """True where ``evaluator.evaluate_all(table)`` is the base's extraction
+    followed by the evaluator's own ``evaluate_parts``, so that a caller
+    holding label and prediction parts as arrays (the selector, on the
+    device) may skip the table. An evaluator that defines only
+    ``evaluate_all(table)``, or overrides it, keeps being handed tables."""
+    cls = type(evaluator)
+    return (getattr(cls, "evaluate_all", None) is OpEvaluatorBase.evaluate_all
+            and cls.evaluate_parts is not OpEvaluatorBase.evaluate_parts)
+
+
+def pad_rows_to_bucket(label, parts: Dict[str, Any], mask=None):
+    """(label, parts, mask) with every array padded by zero rows to the row
+    bucket of its length and ``mask`` False on the padding. Arrays that come
+    with a ``mask`` are padded already and pass through."""
+    if mask is not None:
+        return label, parts, mask
+    n = len(label)
+    n_pad = bucket_for(n)
+    return (pad_rows(label, n_pad),
+            {k: pad_rows(v, n_pad) for k, v in parts.items()},
+            padded_valid_mask(None, n, n_pad))

@@ -13,8 +13,7 @@ from ..ops.metrics import (
     log_loss, log_loss_masked, threshold_metrics,
 )
 from ..table import FeatureTable
-from ..utils.padding import bucket_for
-from .base import OpEvaluatorBase
+from .base import OpEvaluatorBase, pad_rows_to_bucket
 
 
 class OpBinaryClassificationEvaluator(OpEvaluatorBase):
@@ -28,23 +27,17 @@ class OpBinaryClassificationEvaluator(OpEvaluatorBase):
         super().__init__(**kw)
         self.num_threshold_bins = num_threshold_bins
 
-    def evaluate_all(self, table: FeatureTable) -> Dict[str, float]:
-        label, parts = self._extract(table)
+    def evaluate_parts(self, label, parts, mask=None) -> Dict[str, float]:
+        # rows bucket-padded (mask False, score below every threshold) so
+        # the metric programs are shared across dataset sizes
+        label, parts, mask = pad_rows_to_bucket(label, parts, mask)
         prob = parts.get("probability")
         scores = prob[:, 1] if prob is not None and prob.shape[1] > 1 else \
             parts["prediction"]
-        # rows bucket-padded (mask False, score below every threshold) so
-        # the metric programs are shared across dataset sizes
-        n = len(label)
-        n_pad = bucket_for(n)
-        lab = np.zeros(n_pad, np.float32)
-        lab[:n] = label
-        sc = np.full(n_pad, -1.0, np.float32)
-        sc[:n] = scores
-        mask = np.zeros(n_pad, bool)
-        mask[:n] = True
-        return self._metrics(jnp.asarray(lab), jnp.asarray(sc),
-                             jnp.asarray(mask))
+        mask = jnp.asarray(mask)
+        return self._metrics(
+            jnp.where(mask, jnp.asarray(label, jnp.float32), 0.0),
+            jnp.where(mask, jnp.asarray(scores, jnp.float32), -1.0), mask)
 
     def evaluate_arrays(self, label, scores, probability=None) -> float:
         s = probability if probability is not None else scores
@@ -77,9 +70,6 @@ class OpBinaryClassificationEvaluator(OpEvaluatorBase):
             "recallByThreshold": np.asarray(r_curve).tolist(),
             "f1ByThreshold": np.asarray(f1_curve).tolist(),
         }
-
-    def evaluate(self, table: FeatureTable) -> float:
-        return float(self.evaluate_all(table)[self.default_metric])
 
 
 class OpBinScoreEvaluator(OpEvaluatorBase):

@@ -7,9 +7,8 @@ from typing import Dict
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.metrics import regression_metrics
-from ..table import FeatureTable
-from .base import OpEvaluatorBase
+from ..ops.metrics import regression_metrics, regression_metrics_masked
+from .base import OpEvaluatorBase, pad_rows_to_bucket
 
 
 class OpRegressionEvaluator(OpEvaluatorBase):
@@ -18,11 +17,11 @@ class OpRegressionEvaluator(OpEvaluatorBase):
     default_metric = "RootMeanSquaredError"
     larger_better = False
 
-    def evaluate_all(self, table: FeatureTable) -> Dict[str, float]:
-        label, parts = self._extract(table)
-        pred = parts["prediction"]
-        return {k: float(v) for k, v in regression_metrics(
-            jnp.asarray(pred), jnp.asarray(label)).items()}
+    def evaluate_parts(self, label, parts, mask=None) -> Dict[str, float]:
+        label, parts, mask = pad_rows_to_bucket(label, parts, mask)
+        return {k: float(v) for k, v in regression_metrics_masked(
+            jnp.asarray(parts["prediction"], jnp.float32).reshape(-1),
+            jnp.asarray(label, jnp.float32), jnp.asarray(mask)).items()}
 
     def evaluate_arrays(self, label, scores, probability=None) -> float:
         return float(regression_metrics(

@@ -30,9 +30,12 @@ from ...robustness.policy import FaultLog, FaultReport
 from ...stages.base import AllowLabelAsInput, Estimator, Transformer
 from ...table import Column, FeatureTable
 from ...types import OPVector, Prediction, RealNN
-from ..tuning.splitters import DataSplitter, PreparedData, Splitter
+from ...evaluators.base import evaluates_parts
+from ..tuning.splitters import (
+    DataSplitter, PreparedData, Splitter, label_index,
+)
 from ..tuning.validators import BestEstimator, OpCrossValidation, OpValidator
-from ...utils.padding import bucket_for
+from ...utils.padding import bucket_for, pad_rows, padded_valid_mask
 
 #: refit-fallback depth: how many ranked candidates may be tried when the
 #: winner's full-data refit diverges before the train aborts aggregated
@@ -199,9 +202,9 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         sel_rows = train_idx[prep.indices]
         sub = table.take(sel_rows)
         y = y_all[sel_rows]
-        if prep.label_mapping:
-            y = np.vectorize(
-                lambda v: prep.label_mapping.get(int(v), -1))(y).astype(np.float32)
+        labels = label_index(prep.label_mapping)
+        if labels is not None:
+            y = labels.forward(y)
         num_classes = int(y.max()) + 1 if self.problem != "regression" else 1
         if self.problem == "binary":
             num_classes = 2
@@ -334,11 +337,13 @@ class ModelSelector(AllowLabelAsInput, Estimator):
                     if self.splitter is not None
                     else PreparedData(indices=np.arange(len(y_train_raw))))
             sel = train_idx[prep.indices]
-            y = y_all[sel]
-            if prep.label_mapping:
-                y = np.vectorize(
-                    lambda v: prep.label_mapping.get(int(v), -1)
-                )(y).astype(np.float32)
+            # the cutter's re-indexing, once for every row: the fit reads
+            # the rows kept, the evaluation below every row of the split
+            labels = label_index(prep.label_mapping)
+            y_dense = y_all if labels is None else labels.forward(y_all)
+            prepare_span.set_attr(
+                labelMap="none" if labels is None else "lookup")
+            y = y_dense[sel]
             num_classes = (int(y.max()) + 1 if self.problem != "regression"
                            else 1)
             if self.problem == "binary":
@@ -461,19 +466,45 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         model.mesh = self.mesh
         model = self._finalize_model(model)
 
-        # train/holdout evaluation (reference :168-188)
+        # train/holdout evaluation (reference :168-188). Where the winner
+        # predicts on the device and the evaluator states its metrics over
+        # arrays, a row is read where it already lies: the matrix of this
+        # fit, the family's device predict, the metrics beside it; the host
+        # gets the numbers. Else the table goes through the model's
+        # transform and the evaluator as a user's would.
         with _obs_span("selector.evaluate", cat="train",
-                       rows=len(train_idx) + len(test_idx)):
+                       rows=len(train_idx) + len(test_idx)) as eval_span:
             ev = self._default_evaluator()
             ev.set_label_col(label_f.name)
             ev.set_prediction_col(model.get_output().name)
-            train_tbl = table.take(train_idx)
-            summary.train_evaluation = _scalar_metrics(
-                ev.evaluate_all(model.transform(train_tbl)))
-            if len(test_idx):
-                test_tbl = table.take(test_idx)
-                summary.holdout_evaluation = _scalar_metrics(
-                    ev.evaluate_all(model.transform(test_tbl)))
+            on_device = model.device_fusable and evaluates_parts(ev)
+            results, host_bytes = [], 0
+            for idx in (train_idx, test_idx):
+                if not len(idx):
+                    results.append({})
+                elif on_device:
+                    if idx is train_idx and np.array_equal(sel, train_idx):
+                        # nothing dropped or resampled: the refit's operands
+                        X, lab, mask = Xf, yf, padded_valid_mask(
+                            None, n_fit, n_pad)
+                    else:
+                        X, lab, mask = self._rows_on_device(
+                            Xd_all, y_dense, idx)
+                    with engine_mesh(self.mesh):
+                        parts = MODEL_REGISTRY[fitted.family].predict_parts(
+                            fitted, X)
+                    results.append(ev.evaluate_parts(lab, parts, mask))
+                    host_bytes += 4 * _count_numbers(results[-1])
+                else:
+                    scored = model.transform(table.take(idx))
+                    results.append(ev.evaluate_all(scored))
+                    host_bytes += _host_bytes(scored)
+            summary.train_evaluation, summary.holdout_evaluation = (
+                _scalar_metrics(r) for r in results)
+            eval_span.set_attr(
+                labelMap="none" if labels is None else "lookup",
+                evalPath="device" if on_device else "table",
+                hostBytes=host_bytes)
         model.summary_metadata = summary.to_json()
         return model
 
@@ -494,6 +525,26 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         pool.sort(key=(lambda t: -t[2]) if larger_better else (lambda t: t[2]))
         return ranked + pool
 
+    def _rows_on_device(self, Xd_all, y_dense: np.ndarray, idx: np.ndarray):
+        """(X, label, mask): rows ``idx`` of the device matrix by one gather,
+        padded to a row bucket (index 0 again, mask False) and, under a
+        mesh, sharded as the refit's rows are; their labels, padded alike,
+        on the host."""
+        n = len(idx)
+        n_data = self.mesh.shape["data"] if self.mesh is not None else 1
+        n_pad = bucket_for(n, multiple_of=n_data)
+        idx_pad = pad_rows(idx, n_pad)
+        label = pad_rows(y_dense[idx], n_pad)
+        idx_d = jnp.asarray(idx_pad)
+        _count_transfer_bytes(idx_d, "h2d")
+        X = Xd_all[idx_d]
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            from ...parallel.distributed import retrying_device_put
+            X = retrying_device_put(X, NamedSharding(self.mesh,
+                                                     P("data", None)))
+        return X, label, padded_valid_mask(None, n, n_pad)
+
     def _default_evaluator(self):
         if self.evaluator is not None:
             return self.evaluator
@@ -507,6 +558,23 @@ class ModelSelector(AllowLabelAsInput, Estimator):
 
 def _scalar_metrics(metrics: Dict[str, Any]) -> Dict[str, float]:
     return {k: v for k, v in metrics.items() if isinstance(v, (int, float))}
+
+
+def _count_numbers(v: Any) -> int:
+    """Numbers in a metric dict (scalars, curves, count tables): what an
+    evaluation on the device sends to the host, four bytes each."""
+    if isinstance(v, dict):
+        return sum(_count_numbers(x) for x in v.values())
+    if isinstance(v, (list, tuple)):
+        return sum(_count_numbers(x) for x in v)
+    return 1
+
+
+def _host_bytes(table: FeatureTable) -> int:
+    """Bytes of a table's columns that lie on the host."""
+    return sum(int(a.nbytes) for name in table.column_names
+               for a in (table[name].values, table[name].mask)
+               if isinstance(a, np.ndarray))
 
 
 class SelectedModel(AllowLabelAsInput, Transformer):
@@ -530,12 +598,12 @@ class SelectedModel(AllowLabelAsInput, Transformer):
 
     def _unmap_prediction(self, pred: np.ndarray) -> np.ndarray:
         """Map dense class indices back to the original labels dropped/remapped
-        by DataCutter (reference PredictionDeIndexer semantics)."""
-        if not self.label_mapping or pred.size == 0:
+        by DataCutter (reference PredictionDeIndexer semantics; the lookup is
+        ``LabelIndex.inverse``, as on the device path)."""
+        labels = label_index(self.label_mapping)
+        if labels is None or pred.size == 0:
             return pred
-        inverse = {dense: orig for orig, dense in self.label_mapping.items()}
-        return np.vectorize(lambda v: inverse.get(int(v), int(v)),
-                            otypes=[np.float32])(pred)
+        return labels.inverse(pred)
 
     #: the predict is reduction-bearing (gemm / matvec / softmax): its
     #: summation order is only reproducible when X arrives as a program
@@ -568,17 +636,9 @@ class SelectedModel(AllowLabelAsInput, Transformer):
         family = MODEL_REGISTRY[self.fitted.family]
         parts = family.predict_parts(self.fitted, X)
         pred = parts["prediction"].reshape(-1)
-        if self.label_mapping:
-            # DataCutter label de-index (see _unmap_prediction), as a dense
-            # lookup table: unmapped dense indices pass through unchanged
-            inverse = {dense: orig for orig, dense in
-                       self.label_mapping.items()}
-            size = max(inverse) + 2
-            inv = np.arange(size, dtype=np.float32)
-            for dense, orig in inverse.items():
-                inv[dense] = orig
-            idx = jnp.clip(pred.astype(jnp.int32), 0, size - 1)
-            pred = jnp.take(jnp.asarray(inv), idx)
+        labels = label_index(self.label_mapping)
+        if labels is not None:     # DataCutter de-index: _unmap_prediction
+            pred = labels.inverse_device(pred)
         cols = [pred]
         for name in (Prediction.RawPredictionName,
                      Prediction.ProbabilityName):

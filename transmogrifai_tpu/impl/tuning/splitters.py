@@ -5,6 +5,7 @@ DataBalancer.scala, DataCutter.scala)
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
 
@@ -134,3 +135,66 @@ class DataCutter(Splitter):
         self.summary = summary
         return PreparedData(indices=np.nonzero(mask)[0], summary=summary,
                             label_mapping=mapping)
+
+
+class LabelIndex:
+    """A ``label_mapping`` (original label -> dense class index, what
+    :class:`DataCutter` hands out) as arrays: the one statement of the label
+    (de-)indexing that the selector's fit, its evaluation and every scoring
+    path of the fitted model share (reference DataCutter + PredictionDeIndexer).
+
+    * :meth:`forward`: ``mapping.get(int(v), -1)`` for every ``v``: the value
+      truncated toward zero as ``int()`` does, ``-1`` for a label that was not
+      kept. One ``searchsorted`` over the sorted kept labels, so labels may be
+      any integers (negative, seven digits): no table the size of the largest.
+    * :meth:`inverse` / :meth:`inverse_device`: ``inverse.get(int(v), int(v))``:
+      a dense index with an entry becomes its original label, one without
+      (negative, beyond the table, a gap in a hand-made mapping) passes through
+      truncated. One lookup in a dense table over ``0..K-1``.
+
+    Results are float32, the dtype of the label and prediction columns.
+    Build it through :func:`label_index`, which keeps one per mapping."""
+
+    def __init__(self, mapping: Dict[int, int]):
+        self.kept = np.array(sorted(int(k) for k in mapping), dtype=np.int64)
+        self.dense = np.array([int(mapping[int(k)]) for k in self.kept],
+                              dtype=np.int64)
+        if self.dense.min() < 0:
+            raise ValueError("label_mapping: a dense class index is >= 0")
+        self.table = np.arange(int(self.dense.max()) + 1, dtype=np.float32)
+        self.table[self.dense] = self.kept
+
+    def forward(self, labels) -> np.ndarray:
+        v = np.asarray(labels).astype(np.int64)
+        pos = np.minimum(np.searchsorted(self.kept, v), len(self.kept) - 1)
+        return np.where(self.kept[pos] == v, self.dense[pos],
+                        -1).astype(np.float32)
+
+    def inverse(self, dense) -> np.ndarray:
+        i = np.asarray(dense).astype(np.int64)
+        inside = (i >= 0) & (i < len(self.table))
+        return np.where(inside, self.table[np.where(inside, i, 0)],
+                        i).astype(np.float32)
+
+    def inverse_device(self, dense):
+        """:meth:`inverse` of a device array, jit-traceable."""
+        import jax.numpy as jnp
+        i = dense.astype(jnp.int32)
+        inside = (i >= 0) & (i < len(self.table))
+        return jnp.where(inside, jnp.take(jnp.asarray(self.table),
+                                          jnp.where(inside, i, 0)),
+                         i.astype(jnp.float32))
+
+
+@functools.lru_cache(maxsize=64)
+def _label_index(items: Tuple[Tuple[int, int], ...]) -> LabelIndex:
+    return LabelIndex(dict(items))
+
+
+def label_index(mapping: Optional[Dict[int, int]]) -> Optional[LabelIndex]:
+    """The :class:`LabelIndex` of ``mapping``, built once per mapping (at most
+    ``max_label_categories`` pairs make the key); None where there is no
+    mapping to apply."""
+    if not mapping:
+        return None
+    return _label_index(tuple(mapping.items()))

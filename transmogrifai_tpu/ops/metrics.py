@@ -367,8 +367,40 @@ def log_loss(prob_pos: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     return -(y * jnp.log(p) + (1 - y) * jnp.log(1 - p)).mean()
 
 
-@jax.jit
-def multiclass_log_loss(probs: jnp.ndarray, label_idx: jnp.ndarray) -> jnp.ndarray:
-    p = jnp.clip(probs, 1e-15, 1.0)
-    picked = jnp.take_along_axis(p, label_idx[:, None].astype(jnp.int32), axis=1)[:, 0]
-    return -jnp.log(picked).mean()
+@partial(jax.jit, static_argnames=("top_ns",))
+def multiclass_rank_metrics(probs: jnp.ndarray, label_idx: jnp.ndarray,
+                            mask: jnp.ndarray, thresholds: jnp.ndarray,
+                            top_ns: tuple):
+    """What a multiclass evaluation reads off the (n, C) probabilities, over
+    the masked rows, by counting: no sort.
+
+    The true class's rank is ``#{c : p_c > p_true, or p_c == p_true and
+    c < true}``: its place in a STABLE descending sort, so tied classes rank
+    by index. A row is a top-N hit when that rank is below N; a row whose
+    label has no column (below 0, C or above: a class the cutter dropped)
+    hits nothing and is left out of the log loss. A prediction is made at
+    threshold t when the largest probability is >= t; ``thresholds`` are
+    float32 (see ``evaluators.multi``).
+
+    Returns ``LogLoss`` (float32 mean over the rows with a column), ``rows``
+    (masked rows), ``hits`` (len(top_ns),), ``made`` (T,) and ``correct``
+    (len(top_ns), T): int32 counts."""
+    C = probs.shape[1]
+    has_col = (label_idx >= 0) & (label_idx < C) & mask
+    true = jnp.where(has_col, label_idx, 0).astype(jnp.int32)[:, None]
+    p_true = jnp.take_along_axis(probs, true, axis=1)
+    ahead = (probs > p_true) | ((probs == p_true)
+                                & (jnp.arange(C, dtype=jnp.int32)[None, :]
+                                   < true))
+    rank = ahead.sum(axis=1, dtype=jnp.int32)
+    hit = jnp.stack([has_col & (rank < n) for n in top_ns])       # (N, n)
+    made = (probs.max(axis=1)[:, None] >= thresholds[None, :]) \
+        & mask[:, None]                                           # (n, T)
+    w = has_col.astype(probs.dtype)
+    ll = -(jnp.log(jnp.clip(p_true[:, 0], 1e-15, 1.0)) * w).sum()
+    return {"LogLoss": ll / jnp.maximum(w.sum(), 1.0),
+            "rows": mask.sum(dtype=jnp.int32),
+            "hits": hit.sum(axis=1, dtype=jnp.int32),
+            "made": made.sum(axis=0, dtype=jnp.int32),
+            "correct": (hit[:, :, None] & made[None, :, :]).sum(
+                axis=1, dtype=jnp.int32)}
