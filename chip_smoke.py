@@ -64,8 +64,8 @@ def check(cond: bool, msg: str) -> None:
 # ---------------------------------------------------------------------------
 
 def make_data(n: int, seed: int) -> Dict[str, np.ndarray]:
-    """The full-train benchmark's generator (docs/experiments/
-    _full_train_bench.py), columns built in bulk."""
+    """1 M-row binary table of 12 reals, two categoricals and derived
+    columns, built in bulk from the seed."""
     rng = np.random.RandomState(seed)
     X = rng.randn(n, 12).astype(np.float32)
     c1 = rng.choice(["a", "b", "c", "d", "e"], size=n)

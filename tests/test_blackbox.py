@@ -462,7 +462,7 @@ def test_recorder_overhead_on_serve_burst(model):
     """The always-on recorder must be serve-burst cheap: score the same
     burst through the runtime with the recorder on and off; the on-path
     wall clock must stay within 1.5× of the off-path (generous for CI
-    noise — the strict ≤2% throughput gate runs in BENCH_MODE=serve)."""
+    noise)."""
     rows = _rows(256, seed=9)
     mb = micro_batch_score_function(model)
     mb(rows[:8])  # compile warmup outside the measured region

@@ -317,8 +317,8 @@ def test_seeded_campaign_full_coverage_zero_violations():
     """The headline acceptance path at tier-1 scale: a seeded campaign
     over every registered site (coverage singletons + randomized
     multi-site schedules) completes deterministically with 100% site
-    coverage, zero invariant violations, and full serve accounting. The
-    200-schedule version runs as BENCH_MODE=campaign."""
+    coverage, zero invariant violations, and full serve accounting. A
+    longer soak is ``op campaign --schedules 200``."""
     eng = ChaosCampaign(seed=7)
     try:
         report = eng.run(count=len(ALL_SITES) + 4)

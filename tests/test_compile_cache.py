@@ -41,7 +41,7 @@ def test_default_cache_is_the_fixed_in_checkout_directory(fresh_rule):
 
 def test_no_other_cache_path_is_set_in_code_or_tests():
     hits = []
-    for root in ("transmogrifai_tpu", "tests", "bench.py", "chip_smoke.py",
+    for root in ("transmogrifai_tpu", "tests", "chip_smoke.py",
                  "__graft_entry__.py"):
         path = os.path.join(REPO, root)
         files = ([path] if os.path.isfile(path) else

@@ -31,6 +31,20 @@ def _wire(sel):
     return sel
 
 
+@pytest.mark.parametrize("factory,per_fold,fits", [
+    (BinaryClassificationModelSelector, (6, 18, 18, 3), 135),
+    (MultiClassificationModelSelector, (6, 18), 72)])
+def test_stock_selector_fit_count(factory, per_fold, fits):
+    """The stock selectors' sweep sizes, which the benchmark cells' ``fits``
+    check and PERF.md section 2 rest on: folds x the families' default
+    grids (binary LR + RF + GBT + SVC, multiclass LR + RF). Counted from
+    ``default_grid``, so TG_FAST_GRIDS does not enter."""
+    sel = factory.with_cross_validation()
+    grids = tuple(len(fam.default_grid(sel.problem)) for fam, _ in sel.models)
+    assert grids == per_fold
+    assert sel.validator.num_folds * sum(grids) == fits
+
+
 def test_binary_selector_cv(monkeypatch):
     # this test pins the FULL reference default grids (6-point LR grid), so
     # opt out of the suite-wide TG_FAST_GRIDS shrink

@@ -1,14 +1,7 @@
 """node_hist_matmul parity: the production XLA contraction must equal the
-explicit masked-A_cat reference, and the RETIRED pallas kernel (archived
-measurement record, docs/experiments/node_hist_pallas.py) must still match
-it in interpret mode so the record stays executable."""
-import os
-import sys
-
+explicit masked-A_cat reference."""
 import numpy as np
 import pytest
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def _case(T, Wl, stride, seed=0):
@@ -23,7 +16,7 @@ def _case(T, Wl, stride, seed=0):
 
 def _reference(codes, node, sw, Wl, nb, stride, k):
     import jax.numpy as jnp
-    from transmogrifai_tpu.ops.tree_hist import hist_matmul
+    from transmogrifai_tpu.histeng import hist_matmul
     S = codes.shape[0]
     T = node.shape[1]
     j = stride * np.arange(Wl, dtype=np.int32)[None, :, None]
@@ -37,30 +30,11 @@ def _reference(codes, node, sw, Wl, nb, stride, k):
                                          (130, 16, 2), (20, 32, 2)])
 def test_node_hist_matches_acat(T, Wl, stride):
     import jax.numpy as jnp
-    from transmogrifai_tpu.ops.tree_hist import node_hist_matmul
+    from transmogrifai_tpu.histeng import node_hist_matmul
     S, d, nb, k, codes, node, sw = _case(T, Wl, stride)
     out = np.asarray(node_hist_matmul(
         jnp.asarray(codes), jnp.asarray(node),
         [jnp.asarray(s) for s in sw], Wl, nb, stride=stride))
     ref = _reference(codes, node, sw, Wl, nb, stride, k)
     assert out.shape == ref.shape == (k * Wl * T, d * nb)
-    np.testing.assert_allclose(out, ref, rtol=2e-2, atol=2e-2)
-
-
-@pytest.mark.parametrize("T,Wl,stride", [(54, 64, 1), (130, 16, 2)])
-def test_archived_pallas_kernel_still_matches(T, Wl, stride):
-    """The retired kernel is a measurement record; keep it runnable
-    (interpret mode off-TPU) so a future-hardware re-evaluation starts
-    from a known-correct artifact."""
-    import jax.numpy as jnp
-    from docs.experiments.node_hist_pallas import (_node_hist_pallas,
-                                                   pad_node_inputs)
-    S, d, nb, k, codes, node, sw = _case(T, Wl, stride)
-    node_p, sws, Wl_eff, T_pad = pad_node_inputs(
-        jnp.asarray(node), [jnp.asarray(s) for s in sw], Wl)
-    out = np.asarray(_node_hist_pallas(
-        jnp.asarray(codes), node_p, sws, Wl_eff, nb, stride, k))
-    out = (out.reshape(k, Wl_eff, T_pad, d * nb)[:, :Wl, :T]
-           .reshape(k * Wl * T, d * nb))
-    ref = _reference(codes, node, sw, Wl, nb, stride, k)
     np.testing.assert_allclose(out, ref, rtol=2e-2, atol=2e-2)

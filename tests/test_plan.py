@@ -392,6 +392,20 @@ def test_chaos_disables_planning_for_non_plan_sites():
     assert plan_mod.planning_applicable()
 
 
+def test_keeps_planner_is_the_old_prefix_rule():
+    """The registry's ``keeps_planner`` field replaced a tuple of site-name
+    prefixes in ``planning_applicable``: over every registered site the two
+    agree, and an armed name the registry does not hold runs eager."""
+    prefixes = ("plan.", "serve.", "drift.", "oom.", "fleet.", "aot.",
+                "place.")
+    for name, spec in faults.ALL_SITES.items():
+        assert spec.keeps_planner == name.startswith(prefixes), name
+        with faults.injected({name: {"mode": spec.modes[0]}}):
+            assert plan_mod.planning_applicable() == spec.keeps_planner, name
+    with faults.injected({"serve.unregistered": {"mode": "raise"}}):
+        assert not plan_mod.planning_applicable()
+
+
 def test_chaos_env_disables_planning(monkeypatch):
     monkeypatch.setenv(faults.CHAOS_ENV, "1")
     assert not plan_mod.planning_applicable()

@@ -406,20 +406,15 @@ def test_ensemble_cap_proportional_scaling(caplog):
     assert out[0] != out[1]
 
 
-def test_round4_fidelity_switch(monkeypatch):
+def test_sweep_sampling_defaults_and_explicit_eval_rows():
+    """The sweep's sampling constants are the values every ledger line was
+    taken with; an explicit ``max_eval_rows`` is kept as given."""
     from transmogrifai_tpu.impl.tuning.validators import OpCrossValidation
     from transmogrifai_tpu.models import trees
-    from transmogrifai_tpu.utils import fidelity
 
-    monkeypatch.delenv(fidelity.ENV, raising=False)
     assert OpCrossValidation().max_eval_rows == 32768
-    assert trees._sweep_hist_sample() == 8192
-
-    monkeypatch.setenv(fidelity.ENV, "round4")
-    assert OpCrossValidation().max_eval_rows == 65536
-    assert trees._sweep_hist_sample() == 16384
-    # ensemble caps disabled entirely under round-4 defaults
-    assert trees._sweep_ensemble_cap(np.array([50.0, 50.0]), 16, "t") is None
-    # an explicit caller choice always wins over the switch
+    assert trees._SWEEP_HIST_SAMPLE == 8192
+    assert trees._SWEEP_RF_TREES == 16
+    assert trees._SWEEP_GBT_ROUNDS == 12
     assert OpCrossValidation(max_eval_rows=1000).max_eval_rows == 1000
     assert OpCrossValidation(max_eval_rows=None).max_eval_rows is None

@@ -1,4 +1,4 @@
-"""Round-3 ADVICE fixes (see ADVICE.md round 2): deindexer rounding,
+"""Round-3 ADVICE fixes (round 2's review): deindexer rounding,
 forest n_bins guard, TimePeriodListTransformer width locking, persistence
 dangling stage-ref warning, max_eval_rows surfaced in the selector summary."""
 import warnings
@@ -234,8 +234,8 @@ def test_micro_batch_scorer_uses_compiled_path():
 def test_sweep_fidelity_ranking_agreement():
     """Sampled sweep (default max_eval_rows + sweep_fit_batch) ranks configs
     consistently with the exact sweep (max_eval_rows=None +
-    exact_sweep_fits) — CI-scale version of the 1M-row experiment in
-    docs/benchmarks.md (VERDICT r2 #4)."""
+    exact_sweep_fits): what the sweep's sampling constants (32768
+    evaluation rows, 8192 split-search rows, 16 trees, 12 rounds) rest on."""
     import jax.numpy as jnp
     from scipy import stats as sps
     from transmogrifai_tpu.impl.tuning.validators import OpCrossValidation

@@ -378,7 +378,7 @@ def test_fault_log_default_bound_and_ambient_record():
 
 @pytest.mark.chaos
 def test_chaos_soak_all_sites_with_overload(model):
-    """Acceptance shape (bench BENCH_MODE=serve runs the full version):
+    """Acceptance shape (``op serve`` drives the same generator longer):
     faults at serve.enqueue / serve.flush / serve.dispatch plus an
     open-loop load far above capacity over a tiny queue. The run must
     complete with every request resolved (result or typed shed), the
@@ -467,8 +467,7 @@ def test_serve_local_metrics_do_not_touch_global_registry(model):
 
 
 def test_vectorized_table_builder_byte_identical(model):
-    """The serve hot-path satellite (docs/benchmarks.md "Serving
-    runtime"): the vectorized request→FeatureTable assembly must build a
+    """The serve hot path: the vectorized request→FeatureTable assembly must build a
     byte-identical table to the per-cell ``Column.of_values`` path for
     homogeneous batches, heterogeneous batches (None/strings) must fall
     back with the same result, and the row-major record view must emit

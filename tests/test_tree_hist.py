@@ -1,5 +1,5 @@
 """Pallas tree-kernel tests: fused histogram and routing matmuls
-(ops/tree_hist.py). On the CPU test mesh the pallas path runs in interpret
+(histeng/kernels.py). On the CPU test mesh the pallas path runs in interpret
 mode (TG_TREE_PALLAS=1); the default CPU path is the XLA fallback — both are
 checked against direct numpy computation."""
 import os
@@ -9,7 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from transmogrifai_tpu.ops import tree_hist
+from transmogrifai_tpu.histeng import hist_matmul
 
 
 def _hist_direct(codes, A, nb):
@@ -46,8 +46,7 @@ def test_hist_matmul(use_pallas, shape, monkeypatch):
     rng = np.random.RandomState(0)
     codes = rng.randint(0, nb, (S, d)).astype(np.int32)
     A = rng.randn(S, B).astype(np.float32)
-    got = np.asarray(tree_hist.hist_matmul(jnp.asarray(codes),
-                                           jnp.asarray(A), nb))
+    got = np.asarray(hist_matmul(jnp.asarray(codes), jnp.asarray(A), nb))
     want = _hist_direct(codes, A, nb)
     # bf16 accumulate tolerance
     assert np.allclose(got, want, rtol=2e-2, atol=2e-2 * np.abs(want).max())
@@ -60,7 +59,7 @@ def test_hist_matmul_vmap_flattens(use_pallas, monkeypatch):
     codes = rng.randint(0, 8, (300, 6)).astype(np.int32)
     Ab = rng.randn(4, 300, 5).astype(np.float32)
     got = np.asarray(jax.vmap(
-        lambda a: tree_hist.hist_matmul(jnp.asarray(codes), a, 8))(
+        lambda a: hist_matmul(jnp.asarray(codes), a, 8))(
         jnp.asarray(Ab)))
     for v in range(4):
         want = _hist_direct(codes, Ab[v], 8)
@@ -122,8 +121,7 @@ def test_sentinel_codes_contribute_nothing():
     codes = rng.randint(0, nb, (100, 3)).astype(np.int32)
     codes[50:, 1] = nb                       # sentinel rows/features
     A = rng.randn(100, 2).astype(np.float32)
-    got = np.asarray(tree_hist.hist_matmul(jnp.asarray(codes),
-                                           jnp.asarray(A), nb))
+    got = np.asarray(hist_matmul(jnp.asarray(codes), jnp.asarray(A), nb))
     # feature 1 histogram over sentinel rows is zero: total mass of feature 1
     # equals the A-sum over non-sentinel rows only
     f1 = got[:, 1 * nb:(1 + 1) * nb].sum(1)

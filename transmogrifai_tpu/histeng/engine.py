@@ -11,8 +11,8 @@ backend        selected when               implementation
                disabled)                   (`kernels._hist_xla_pinned`)
 ``pallas``     device arrays on TPU with   VMEM one-hot expansion kernel
                TG_TREE_PALLAS unset/1      (`kernels._hist_pallas`)
-``host``       numpy inputs or             flat-index ``np.bincount``,
-               ``backend="host"``          bit-equal to StreamingGBT's
+``host``       numpy inputs                flat-index ``np.bincount``,
+                                           bit-equal to StreamingGBT's
                                            legacy inline block (`host`)
 =============  ==========================  ==================================
 
@@ -25,8 +25,8 @@ sweeps are then bit-identical across topologies the way linear families
 already were (docs/trees.md).
 
 Env knobs: TG_HIST_SHARDS (pinned block count, default 8; 0/1 → plain
-einsum), TG_HIST_BACKEND (force ``xla``/``pallas``; overrides
-TG_TREE_PALLAS). Both are read at trace time.
+einsum), TG_TREE_PALLAS (0/1 forces the ``xla``/``pallas`` backend). Both
+are read at trace time.
 
 Chaos: ``chaos_gate(family)`` is the host-side ``hist.build`` fault site —
 the fused sweep dispatcher calls it once per tree-family program dispatch,
@@ -39,7 +39,7 @@ winner may legitimately differ.
 from __future__ import annotations
 
 from contextlib import contextmanager
-from typing import Optional, Sequence
+from typing import Sequence
 
 import numpy as np
 
@@ -90,8 +90,7 @@ def build_hist(codes, A, n_bins: int, exact: bool = False):
 
 
 def build_node_hist(codes, node, stats: Sequence, n_bins: int, *,
-                    n_nodes: int = 1, stride: int = 1,
-                    backend: Optional[str] = None):
+                    n_nodes: int = 1, stride: int = 1):
     """(node, feature, bin) sufficient statistics — the one tree-growth
     primitive shared by in-core growers, StreamingGBT, and the mesh sweep.
 
@@ -101,7 +100,7 @@ def build_node_hist(codes, node, stats: Sequence, n_bins: int, *,
     ``stride``: slot-id multiplier (2 = heap left-children). Returns
     (k, n_nodes, T, d, n_bins) f32 on device.
 
-    Host backend (numpy inputs or ``backend="host"``): ``codes`` (d, n)
+    Host backend (numpy inputs): ``codes`` (d, n)
     int64 feature-major from `bin_codes_host` (feature-major on purpose —
     the bincount traversal order, and so the f64 sums bit for bit, depend
     on it), ``node`` (n,) int64, ``stats``: k entries each ``None``
@@ -113,11 +112,8 @@ def build_node_hist(codes, node, stats: Sequence, n_bins: int, *,
     caller under `engine_mesh` (from outside any jit: it enters
     ``jax.set_mesh``).
     """
-    if backend not in (None, "host", "xla", "pallas"):
-        raise ValueError(f"unknown histogram backend {backend!r}")
-    if backend == "host" or (backend is None and isinstance(codes, np.ndarray)
-                             and codes.dtype.kind in "iu"
-                             and isinstance(node, np.ndarray)):
+    if (isinstance(codes, np.ndarray) and codes.dtype.kind in "iu"
+            and isinstance(node, np.ndarray)):
         if stride != 1:
             raise ValueError("host histogram backend is stride-1 only")
         return build_node_hist_host(codes, node, stats, n_bins, n_nodes)

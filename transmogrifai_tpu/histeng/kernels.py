@@ -43,7 +43,7 @@ Pinned reduction (mesh determinism)
 Fallback: on non-TPU backends (CPU test mesh, virtual-device dry runs) the
 same contraction runs as the blocked XLA one-hot einsum.
 
-NOTE: `_use_pallas()` / `_interpret()` read TG_TREE_PALLAS / TG_HIST_BACKEND
+NOTE: `_use_pallas()` / `_interpret()` read TG_TREE_PALLAS
 and the backend at *trace time* inside jitted tree fits — once a shape is
 traced, flipping the env var has no effect for that shape until the jit
 caches are cleared (`jax.clear_caches()`), which tests that toggle the flag
@@ -70,11 +70,6 @@ _BLK_B = 128    # stat columns per tile
 
 
 def _use_pallas() -> bool:
-    forced = os.environ.get("TG_HIST_BACKEND", "")
-    if forced == "xla":
-        return False
-    if forced == "pallas":
-        return True
     env = os.environ.get("TG_TREE_PALLAS", "")
     if env in ("0", "false"):
         return False
@@ -390,8 +385,7 @@ def node_hist_matmul(codes: jnp.ndarray, node: jnp.ndarray,
     · 1[codes[s,f] == b] — the tree-growth histogram as one XLA contraction
     over the masked-stat operand (the (S, k·Wl·T) A_cat is materialized;
     a pallas kernel that expanded it tile-by-tile in VMEM measured SLOWER
-    at every production shape, sweep and refit alike — retired with its
-    measurement table to docs/experiments/node_hist_pallas.py).
+    at every production shape, sweep and refit alike, and was retired).
 
     codes: (S, d) int32 bin codes; node: (S, T) int32 current slot per tree
     (values < 0 never match); sw_list: k arrays (S, T) of per-tree stats;

@@ -27,7 +27,7 @@ class BinaryClassificationModelSelector:
                               **validator_kw) -> ModelSelector:
         # validator_kw passes through to OpCrossValidation — e.g.
         # max_eval_rows=None, exact_sweep_fits=True for reference-exact
-        # sweep semantics (docs/benchmarks.md "Sweep fidelity")
+        # sweep semantics
         return _build("binary",
                       OpCrossValidation(num_folds=num_folds, seed=seed, stratify=stratify,
                                         **validator_kw),

@@ -37,7 +37,6 @@ from ...robustness.guards import (
     AllCandidatesFailedError, quarantine_non_finite,
 )
 from ...robustness.policy import FaultLog, FaultReport
-from ...utils.fidelity import ROUND4_MAX_EVAL_ROWS, round4_defaults
 from ...utils.padding import bucket_for
 
 logger = logging.getLogger(__name__)
@@ -139,8 +138,8 @@ def _metric_fn(problem: str, metric: str, batched_y: bool = False,
 
 
 #: fused per-family sweep programs, keyed by (family, grid, fold/metric
-#: config) — reused across validate() calls so bench reps and repeated
-#: workflow fits pay one compile. LRU-bounded: each entry pins a jitted
+#: config) — reused across validate() calls so repeated workflow fits
+#: pay one compile. LRU-bounded: each entry pins a jitted
 #: executable plus its tiled host grid constants, so a long-lived process
 #: fitting many distinct grids would otherwise grow compiled-program memory
 #: without bound (eviction just re-pays the pre-existing compile cost)
@@ -346,16 +345,9 @@ class OpValidator:
     is its 8-thread Future pool (OpValidator.scala:318-333); here the
     parallel axes are mesh axes and XLA inserts the psum collectives."""
 
-    #: sentinel: "caller did not choose" — the constructor resolves it to
-    #: 32768 (round-5 default) or 65536 under TG_SWEEP_FIDELITY=round4
-    _EVAL_ROWS_DEFAULT = -1
-
     def __init__(self, seed: int = 42, stratify: bool = False, mesh=None,
-                 max_eval_rows: "Optional[int]" = _EVAL_ROWS_DEFAULT,
+                 max_eval_rows: "Optional[int]" = 32768,
                  exact_sweep_fits: bool = False):
-        if max_eval_rows == self._EVAL_ROWS_DEFAULT:
-            max_eval_rows = (ROUND4_MAX_EVAL_ROWS if round4_defaults()
-                             else 32768)
         self.seed = seed
         self.stratify = stratify
         self.mesh = mesh
@@ -363,10 +355,10 @@ class OpValidator:
         #: most this many of its fold's rows (deterministic strided
         #: subsample). Metric ESTIMATES only — refit, holdout and train
         #: evaluations always use full data. None = score every validation
-        #: row (exact reference parity); the default trades ~3e-3 of AuROC
-        #: estimator noise for a ~10x cut in sweep predict time at 1M+ rows.
-        #: Measured fidelity of the default vs the exact setting:
-        #: docs/benchmarks.md "Sweep fidelity".
+        #: row (exact reference parity); the default scores a 32768-row
+        #: sample of each fold, so the sweep's predict cost stops growing
+        #: with the table; tests/test_round3_fixes.py holds its ranking
+        #: against the exact setting's.
         self.max_eval_rows = max_eval_rows
         #: True = CV candidates fit through ``fit_batch`` (full precision /
         #: full split-search sample) instead of ``sweep_fit_batch``'s
@@ -944,8 +936,8 @@ class OpValidator:
             results: List[ValidationResult] = []
             quarantined: List[Dict[str, Any]] = []
             best: Optional[BestEstimator] = None
-            # the device->host metric fetch is the sweep's "transfer" phase;
-            # its histogram lets bench.py split compile/execute/transfer
+            # the device->host metric fetch is the sweep's "transfer" phase,
+            # timed into tg_sweep_transfer_seconds
             t0_fetch = _time.perf_counter()
             # the one statement where the host waits for the device sweep
             with _obs_span("sweep.collect", cat="sweep",

@@ -177,8 +177,7 @@ def serve_table_builder(model) -> Callable[[Sequence[Dict[str, Any]]], FeatureTa
     lookup per cell and convert through ``table.column_of_scalars`` (one
     numpy sweep) instead of ``Column.of_values``'s per-cell python loop;
     anything non-homogeneous (a None, a string, a custom extractor) falls
-    back to the exact original path, so outputs are byte-identical
-    (docs/benchmarks.md "Serving runtime" has the before/after)."""
+    back to the exact original path, so outputs are byte-identical."""
     from ..readers.readers import _field_name_of
     from ..table import column_of_scalars
     raw_features = model.raw_features
@@ -225,7 +224,7 @@ def serve_record_builder(model) -> Callable[[FeatureTable, int], List[Dict[str, 
         # (identical python values: tolist() and .item() both produce the
         # nearest python float/int), instead of a numpy scalar indexing +
         # .item() round-trip per cell — with the table build, this was the
-        # serve hot path (docs/benchmarks.md "Serving runtime")
+        # serve hot path
         per_col: List[Tuple[str, Optional[list], list, Optional[Tuple]]] = []
         for f in result_features:
             col = scored[f.name]

@@ -19,7 +19,7 @@ XGBoost's C++:
   XGBoost 'approx'/GOSS design point: split thresholds are order-statistic
   estimates and converge long before 65k rows), and each level's histogram
   is ONE matmul — (nodes⊗stats)ᵀ expanded against the int32 bin codes by
-  the fused pallas kernel (ops/tree_hist.py): the bin one-hot is built
+  the fused pallas kernel (histeng/kernels.py): the bin one-hot is built
   tile-by-tile in VMEM and never reaches HBM. Routing between levels is a
   *feature-select matmul*: the split feature's bin code is gathered by a
   (d, nodes) one-hot matmul and compared against the bin threshold —
@@ -54,8 +54,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.fidelity import ROUND4_SWEEP_HIST_SAMPLE, round4_defaults
-
 logger = logging.getLogger(__name__)
 
 from ..ops.forest import (
@@ -76,26 +74,19 @@ _HIST_SAMPLE = 65536
 
 #: sweep-time sample cap: CV candidates grow from a fraction of the refit
 #: sample — split thresholds are order statistics and the CV ranking is
-#: robust to the extra estimator noise (measured: docs/benchmarks.md "Sweep
-#: fidelity", re-run for this value); the refit winner regrows at
-#: _HIST_SAMPLE. Round 3 used 32768, round 4 16384; each halving halves
-#: every growth histogram's rows for the depth-12 default grids
+#: robust to the extra estimator noise (tests/test_round3_fixes.py holds
+#: the sampled sweep's ranking against the exact one's); the refit winner
+#: regrows at _HIST_SAMPLE. Each halving halves every growth histogram's
+#: rows for the depth-12 default grids
 _SWEEP_HIST_SAMPLE = 8192
 
 #: sweep-time ensemble caps: CV candidates RANK with this many RF trees /
 #: GBT boosting rounds — the metric is an ensemble-size-consistent estimate
 #: (every config gets the same cap), the winner refits at its full
 #: numTrees/maxIter through fit_batch(sweep=False). Same contract as the
-#: split-search sample above; fidelity-gated by docs/experiments/
-#: fidelity_1m64.py ("Sweep fidelity" in docs/benchmarks.md)
+#: split-search sample above
 _SWEEP_RF_TREES = 16
 _SWEEP_GBT_ROUNDS = 12
-
-
-def _sweep_hist_sample() -> int:
-    """Sweep-time split-search sample rows; TG_SWEEP_FIDELITY=round4
-    restores the round-4 value (utils/fidelity.py)."""
-    return ROUND4_SWEEP_HIST_SAMPLE if round4_defaults() else _SWEEP_HIST_SAMPLE
 
 
 def _sweep_ensemble_cap(vals: np.ndarray, cap: int,
@@ -110,10 +101,7 @@ def _sweep_ensemble_cap(vals: np.ndarray, cap: int,
     sizes scale PROPORTIONALLY (max → cap, floor 1) instead, preserving the
     grid's relative budgets; the warning flags that ranking across ensemble
     sizes is then an approximation. Returns the capped per-config values, or
-    None when no cap applies (all values ≤ cap, or round-4 fidelity
-    defaults disable sweep caps)."""
-    if round4_defaults():
-        return None
+    None when no cap applies (all values ≤ cap)."""
     vals = np.asarray(vals, dtype=np.float64)
     vmax = float(vals.max())
     if vmax <= cap:
@@ -126,8 +114,9 @@ def _sweep_ensemble_cap(vals: np.ndarray, cap: int,
         "ranking cap %d; candidates rank with proportionally scaled "
         "ensembles %s (a uniform cap would make them byte-identical and "
         "unrankable) and the winner refits at its full %s — an "
-        "approximation when ranking across ensemble sizes. Set "
-        "TG_SWEEP_FIDELITY=round4 to disable sweep ensemble caps.",
+        "approximation when ranking across ensemble sizes. The "
+        "validator's exact_sweep_fits=True (with max_eval_rows=None) ranks "
+        "every candidate at its full size.",
         param, sorted(set(vals.tolist())), cap,
         sorted(set(scaled.tolist())), param)
     return scaled
@@ -264,7 +253,7 @@ def _grow_tree(codes_s, edges, stats_s, w_s, feat_mask, cfg, *,
 
     Each level's histogram is ONE fused one-hot matmul — (node-one-hot ⊗
     weighted stats)ᵀ expanded against the bin codes (hist_matmul,
-    ops/tree_hist.py; the bin one-hot never reaches HBM on the pallas path)
+    histeng/kernels.py; the bin one-hot never reaches HBM on the pallas path)
     — and sample routing is a plain-XLA feature-select matmul: a (d, m)
     one-hot of the chosen split features gathers each node's bin code for
     an elementwise threshold compare. Batches under vmap over trees/configs
@@ -513,7 +502,7 @@ def _grow_forest_capped(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
         # node-histogram contraction (histeng.build_node_hist):
         # XLA's pipelined A_cat contraction — a pallas kernel that expanded
         # the operand in VMEM measured slower at every production shape and
-        # is retired to docs/experiments/node_hist_pallas.py
+        # was retired
         if level == 0 or Wl % 2 or not sibling:
             hist5 = build_node_hist(codes_s, node, sw_list, n_bins,
                                     n_nodes=Wl).transpose(1, 2, 3, 4, 0)
@@ -688,7 +677,7 @@ def _prep_tree_inputs(X, y, n_bins, num_classes, task, full_bin=True,
     ``sweep`` halves the split-search sample (_SWEEP_HIST_SAMPLE)."""
     n = X.shape[0]
     samp = jnp.asarray(_sample_rows(
-        n, _sweep_hist_sample() if sweep else _HIST_SAMPLE))
+        n, _SWEEP_HIST_SAMPLE if sweep else _HIST_SAMPLE))
     Xs = X[samp]
     edges = _quantile_edges(Xs, n_bins)
     if full_bin:
@@ -1619,9 +1608,9 @@ class RandomForestFamilyBase(_TreeFamilyBase):
         if any("maxDepth" not in g for g in grid):
             return {}
         n_trees = int(max(g.get("numTrees", 20.0) for g in grid))
-        if sweep and not round4_defaults():
+        if sweep:
             n_trees = min(n_trees, _SWEEP_RF_TREES)
-        S = min(rows, _sweep_hist_sample() if sweep else _HIST_SAMPLE)
+        S = min(rows, _SWEEP_HIST_SAMPLE if sweep else _HIST_SAMPLE)
         k = (max(num_classes, 2)
              if self._task(num_classes) == "classification" else 3)
         depths = [int(g["maxDepth"]) for g in grid]
@@ -1737,7 +1726,7 @@ class GBTFamilyBase(_TreeFamilyBase):
             # stages of it alive (observed 24.5 GB on the fidelity
             # experiment's exact arm)
             S_est = min(X.shape[0],
-                        _sweep_hist_sample() if sweep else _HIST_SAMPLE)
+                        _SWEEP_HIST_SAMPLE if sweep else _HIST_SAMPLE)
             lanes_max = max((1 << 29) // max(S_est, 1), 192)
             cb = int(max(1, min(cb, lanes_max // (3 * nodes_w * C_g))))
             if cb >= B_g:

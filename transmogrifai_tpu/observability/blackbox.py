@@ -7,7 +7,7 @@ in the seconds before. This module is the aviation-style black box the
 resilience layer (PRs 6–10) was missing: a process-wide, **always-on**
 (``TG_BLACKBOX=0`` opts out), fixed-size, lock-cheap ring of compact
 events that is cheap enough to leave running under full serving load
-(≤2% on the BENCH_MODE=serve clean line — asserted) and that
+(tests/test_blackbox.py holds a coarse overhead guard) and that
 ``observability/postmortem.py`` snapshots into a self-contained bundle
 the moment a trigger event fires.
 

@@ -197,7 +197,7 @@ class DeviceMemObservatory:
 
     def peaks(self) -> Dict[str, Any]:
         """``{"predicted": max over subsystems, "measured": ... | None}``
-        — the two numbers every bench line reports."""
+        — the pair a run reports side by side."""
         with self._lock:
             pred = max((s["predictedPeakBytes"]
                         for s in self._subsystems.values()), default=0)

@@ -308,8 +308,7 @@ def read_bundle(path: str) -> Dict[str, Any]:
 
 def validate_bundle(doc: Dict[str, Any]) -> List[str]:
     """Schema check → list of problems (empty = valid). The acceptance
-    gate every trigger-class test and the serve bench run bundles
-    through."""
+    gate every trigger-class test runs bundles through."""
     problems: List[str] = []
     version = doc.get("schemaVersion")
     if version not in SUPPORTED_SCHEMA_VERSIONS:

@@ -206,8 +206,8 @@ class SLOTracker:
         self._lock = threading.Lock()
         #: (objective, severity) → alert currently active
         self._active: Dict[Tuple[str, str], bool] = {}
-        #: cumulative alert activations by severity (asserted by the
-        #: bench chaos line — a fired-then-cleared page still counts)
+        #: cumulative alert activations by severity (a fired-then-cleared
+        #: page still counts)
         self.fired: Dict[str, int] = {s: 0 for s in SEVERITIES}
         #: objectives currently inside a budget-exhaustion episode (one
         #: post-mortem per episode, re-armed when the budget recovers)

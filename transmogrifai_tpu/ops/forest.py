@@ -37,7 +37,8 @@ node vector m times lines tree t up with every candidate j at lane j·T_pad+t.
 
 Fallback: non-TPU backends (CPU test mesh, dry runs) and shapes outside the
 VMEM envelope (depth > 7 or > 128 trees) run the same math as XLA einsums.
-Dispatch reads the backend at trace time (see tree_hist.py note).
+Dispatch reads the backend at trace time (see the histeng/kernels.py
+note).
 """
 from __future__ import annotations
 
@@ -47,11 +48,9 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 
-from .tree_hist import _interpret, _pad_to, _tile_lanes, _use_pallas
+from ..histeng.kernels import _interpret, _pad_to, _tile_lanes, _use_pallas
 
-import os as _os
-
-_BLK_R = int(_os.environ.get("TG_FOREST_BLK_R", "128"))  # rows per VMEM block
+_BLK_R = 128  # rows per VMEM block
 _MAX_DEPTH_PALLAS = 7  # beyond this the (R, T·m) block outgrows VMEM
 _MAX_TREES_PALLAS = 128
 

@@ -33,9 +33,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 #: win): at 8192 rows/chip the sharded sweep executes ~2.5x the
 #: single-device fused wall; the overhead first falls inside run-to-run
 #: noise above ~16k rows per chip and a handful of configs per model shard
-#: (docs/benchmarks.md "Mesh cost model"). Below the thresholds the sweep
-#: transparently downgrades to the single-device fused path — bit-identical
-#: results, observable via tg_mesh_downgrade_total + span event.
+#: (CPU, rounds 6-20; never re-measured on the chip: ROADMAP S8). Below
+#: the thresholds the sweep transparently downgrades to the single-device
+#: fused path — bit-identical results, observable via
+#: tg_mesh_downgrade_total + span event.
 MESH_MIN_ROWS_PER_CHIP_ENV = "TG_MESH_MIN_ROWS_PER_CHIP"
 MESH_MIN_CONFIGS_PER_CHIP_ENV = "TG_MESH_MIN_CONFIGS_PER_CHIP"
 MESH_FORCE_ENV = "TG_MESH_FORCE"
@@ -49,7 +50,7 @@ def sweep_mesh_decision(mesh: Mesh, n_rows: int,
 
     Returns ``(engage, detail)``; ``detail`` carries the measured sizes and
     thresholds for the downgrade span event. ``TG_MESH_FORCE=1`` pins the
-    mesh on regardless (bench A/B and mesh-path tests); setting either
+    mesh on regardless (mesh-path tests, chip_smoke.py); setting either
     threshold env var to 0 disables that axis of the check."""
     if os.environ.get(MESH_FORCE_ENV, "") in ("1", "true"):
         return True, {"forced": True}

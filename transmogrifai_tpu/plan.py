@@ -1,8 +1,8 @@
 """Transform-plan compiler: one XLA program per device-fusable segment.
 
 The paper's substrate swap is "jit-compiled kernels instead of Catalyst";
-round 5 proved the shape of the win by fusing the per-family sweep glue into
-single jitted programs (docs/benchmarks.md). This module applies the same
+the model selector fuses each family's sweep glue into a single jitted
+program (impl/tuning/validators.py). This module applies the same
 cure to the fit-and-transform DAG: instead of dispatching every transformer
 as its own executable (each a separate launch with its own dispatch
 bubble), a *plan* partitions a topologically-ordered run of
@@ -62,11 +62,8 @@ from .table import Column, FeatureTable
 
 logger = logging.getLogger(__name__)
 
-#: env switch: TG_PLAN=0 disables the planner process-wide (eager dispatch)
-PLAN_ENV = "TG_PLAN"
-
-_FALSY = ("", "0", "false", "False", "no")
-
+#: ``enable_planning(False)`` runs the eager per-stage path (the tests' and
+#: the benchmark's bit-equality reference); None or True plans
 _enabled_override: Optional[bool] = None
 
 #: plan LRU: (stage identity seq, schema fp, options) → TransformPlan | None
@@ -80,53 +77,33 @@ _PLAN_CACHE_MAX = int(os.environ.get(
 
 
 def plan_enabled() -> bool:
-    """True when the transform-plan compiler may be used (TG_PLAN, unless
-    overridden programmatically)."""
-    if _enabled_override is not None:
-        return _enabled_override
-    return os.environ.get(PLAN_ENV, "1") not in _FALSY
+    """True when the transform-plan compiler may be used: always, unless
+    ``enable_planning(False)`` turned it off."""
+    return _enabled_override is None or _enabled_override
 
 
 def enable_planning(on: Optional[bool]) -> None:
-    """Force planning on/off from code (tests, A/B benches); ``None`` hands
-    control back to the ``TG_PLAN`` environment switch."""
+    """Force planning on/off from code (tests, the benchmark's eager
+    reference); ``None`` restores the default, which is on."""
     global _enabled_override
     _enabled_override = None if on is None else bool(on)
 
 
 def planning_applicable() -> bool:
     """Planning is allowed only when per-stage fault semantics are not in
-    play: under ``TG_CHAOS`` or any armed non-``plan.*``/``serve.*``
-    injection site the eager per-stage path runs so PR 1 retry/quarantine
-    behavior is exactly preserved. Sites prefixed ``plan.`` target the
-    planner itself and keep it active — they exercise the runtime
-    fallback; sites prefixed ``serve.`` / ``drift.`` target the serving
-    runtime and its drift monitor *above* the planner
-    (serving/runtime.py, serving/drift.py), whose chaos tests must
-    exercise the real planned dispatch path, not an eager stand-in;
-    sites prefixed ``oom.`` inject resource exhaustion into the planned /
-    serve / stream / sweep dispatch paths themselves — disabling the
-    planner would disable exactly the path under test; sites prefixed
-    ``fleet.`` target the replica front door a further layer up
-    (serving/frontdoor.py) and keep the planner active for the same
-    reason as ``serve.*``; the ``aot.load`` site targets the AOT
-    program-store load path *inside* the planner's segment dispatch
-    (programstore/store.py) — disabling the planner would disable
-    exactly the fallback ladder under test; sites prefixed ``place.``
-    target the fleet's model-placement layer (serving/placement.py),
-    another floor above the planner, and keep it active like
-    ``fleet.*``."""
+    play: under ``TG_CHAOS``, or with any armed injection site that is not
+    registered as ``keeps_planner`` (robustness/faults.py ``SiteSpec``: the
+    sites that target the planner itself, the layers above it or its own
+    dispatch), the eager per-stage path runs, so the per-stage
+    retry/quarantine behavior is exactly preserved. An armed name the
+    registry does not hold counts as a per-stage site."""
     if not plan_enabled():
         return False
     from .robustness import faults
     if os.environ.get(faults.CHAOS_ENV):
         return False
-    armed = faults.active_sites()
-    if any(not s.startswith(("plan.", "serve.", "drift.", "oom.",
-                             "fleet.", "aot.", "place."))
-           for s in armed):
-        return False
-    return True
+    return all(s in faults.ALL_SITES and faults.ALL_SITES[s].keeps_planner
+               for s in faults.active_sites())
 
 
 def clear_plan_cache() -> None:

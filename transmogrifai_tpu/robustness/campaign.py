@@ -44,9 +44,9 @@ closes that gap *compositionally*:
   re-triggers the violation. A campaign failure is a repro, not a flaky
   soak.
 
-Entry points: ``python -m transmogrifai_tpu.cli campaign`` and
-``BENCH_MODE=campaign python bench.py`` (seeded fixed-budget soak
-asserting 100% site coverage, zero violations, full accounting).
+Entry point: ``python -m transmogrifai_tpu.cli campaign`` (seeded
+fixed-budget soak: coverage singletons for every registered site first;
+exits non-zero on any violation).
 
 Env knobs (docs/robustness.md "Chaos campaigns"): ``TG_CAMPAIGN_SCHEDULES``
 (default budget, 40), ``TG_CAMPAIGN_SEED`` (0),

@@ -141,8 +141,8 @@ site                         fires in
                              ``accept_fault`` shed with a
                              ``net_accept_refused`` FaultLog record —
                              nothing was submitted, nothing can be lost;
-                             ``net.*`` sites keep the planner active
-                             like ``serve.*``)
+                             ``net.*`` sites are not ``keeps_planner``:
+                             while one is armed scoring runs eager)
 ``net.read``                 per request, before the frame/body is read
                              off the socket (a raise models the read
                              path dying mid-request: the peer observes a
@@ -268,18 +268,26 @@ class SiteSpec:
     (the campaign's strongest oracle); False when recovery legitimately
     alters the result (e.g. a quarantined candidate changes selection) —
     such divergence must then be visible in fault accounting, never
-    silent."""
+    silent. ``keeps_planner``: True when arming the site leaves the
+    transform planner on (plan.planning_applicable): the site targets the
+    planner itself, its segment dispatch, or a layer above it, so the
+    eager stand-in would disable exactly the path under test; an armed
+    site without it runs the eager per-stage path, whose retry/quarantine
+    semantics it exercises."""
     name: str
     modes: Tuple[str, ...]
     module: str
     scenarios: Tuple[str, ...]
     recovery: str
     bit_equal: bool = True
+    keeps_planner: bool = False
 
 
-def _site(name, modes, module, scenarios, recovery, bit_equal=True):
+def _site(name, modes, module, scenarios, recovery, bit_equal=True,
+          keeps_planner=False):
     return SiteSpec(name, tuple(modes.split("|")), module,
-                    tuple(scenarios.split("|")), recovery, bit_equal)
+                    tuple(scenarios.split("|")), recovery, bit_equal,
+                    keeps_planner)
 
 
 #: the machine-readable site inventory (docs/robustness.md carries the
@@ -314,17 +322,22 @@ ALL_SITES: Dict[str, SiteSpec] = {s.name: s for s in (
           "host->device placement retried (transient); a fatal placement "
           "fault quarantines the consuming family", bit_equal=False),
     _site("plan.segment_execute", "raise", "plan.py", "train|serve",
-          "planned run falls back to eager per-stage dispatch, bit-equal"),
+          "planned run falls back to eager per-stage dispatch, bit-equal",
+          keeps_planner=True),
     _site("serve.enqueue", "raise", "serving/runtime.py", "serve",
-          "typed error to the one caller; the runtime stays up"),
+          "typed error to the one caller; the runtime stays up",
+          keeps_planner=True),
     _site("serve.flush", "raise", "serving/runtime.py", "serve",
-          "batch degrades to the eager per-row path, bit-equal"),
+          "batch degrades to the eager per-row path, bit-equal",
+          keeps_planner=True),
     _site("serve.dispatch", "raise", "serving/runtime.py", "serve",
-          "breaker counts the failure; batch degrades eager, bit-equal"),
+          "breaker counts the failure; batch degrades eager, bit-equal",
+          keeps_planner=True),
     _site("serve.complete", "raise", "serving/runtime.py", "serve",
           "pipelined completion-side failure: the breaker counts it "
           "against the dispatching flush; batch degrades eager, "
-          "bit-equal (fires only with TG_SERVE_PIPELINE > 1)"),
+          "bit-equal (fires only with TG_SERVE_PIPELINE > 1)",
+          keeps_planner=True),
     _site("stream.read", "raise|preempt", "streaming/feed.py", "stream",
           "error forwards through the queue; preemption resumes "
           "bit-exactly from the last committed chunk"),
@@ -336,40 +349,45 @@ ALL_SITES: Dict[str, SiteSpec] = {s.name: s for s in (
     _site("stream.fold", "raise|preempt", "streaming/trainer.py", "stream",
           "fold retried/resumed from the committed state, bit-exact"),
     _site("drift.fold", "raise", "serving/drift.py", "serve|serve_heal",
-          "contained by the runtime fence; zero request impact"),
+          "contained by the runtime fence; zero request impact",
+          keeps_planner=True),
     _site("drift.verdict", "raise", "serving/drift.py", "serve|serve_heal",
-          "contained in the monitor; fold state intact"),
+          "contained in the monitor; fold state intact", keeps_planner=True),
     _site("drift.refit", "raise", "serving/registry.py", "serve_heal",
-          "no swap; the old model keeps serving, breaker untouched"),
+          "no swap; the old model keeps serving, breaker untouched",
+          keeps_planner=True),
     _site("oom.plan", "oom", "plan.py", "train|serve",
-          "row batch bisects to smaller padding buckets, bit-equal"),
+          "row batch bisects to smaller padding buckets, bit-equal",
+          keeps_planner=True),
     _site("oom.serve", "oom", "serving/runtime.py", "serve|serve_heal",
           "flush splits down to singletons; zero failed requests, "
-          "bit-equal records"),
+          "bit-equal records", keeps_planner=True),
     _site("oom.stream", "oom", "streaming/feed.py", "stream",
           "chunk row budget halves from the committed-row prefix; prep "
           "folds bit-equal, tree edges within documented tolerance",
-          bit_equal=False),
+          bit_equal=False, keeps_planner=True),
     _site("oom.sweep", "oom", "impl/tuning/validators.py", "sweep|train",
           "packed grid splits and fold metrics merge (identical winner); "
           "exhaustion persisting to a single config quarantines the "
-          "family", bit_equal=False),
+          "family", bit_equal=False, keeps_planner=True),
     _site("fleet.route", "raise", "serving/frontdoor.py", "fleet|density",
           "request fails over to another replica (bounded budget); "
-          "typed shed when exhausted — never a lost future"),
+          "typed shed when exhausted — never a lost future",
+          keeps_planner=True),
     _site("fleet.replica_kill", "raise", "serving/frontdoor.py",
           "fleet|density",
           "replica killed mid-flight; queued requests fail over to "
           "survivors, replica_lost post-mortem dumped, zero lost — "
           "under placement, models whose only warm copy died page in "
-          "on a survivor"),
+          "on a survivor", keeps_planner=True),
     _site("fleet.probe", "raise", "serving/frontdoor.py", "fleet|density",
           "probe failure counted; consecutive failures eject the "
-          "replica, healthy probes readmit it — requests unaffected"),
+          "replica, healthy probes readmit it — requests unaffected",
+          keeps_planner=True),
     _site("aot.load", "raise", "programstore/store.py", "serve_heal",
           "bad AOT artifact falls back to the trace path bit-equally; "
           "typed aot_fallback recorded, ledger build classified "
-          "aot-miss — never a request error"),
+          "aot-miss — never a request error", keeps_planner=True),
     _site("net.accept", "raise", "serving/netedge.py", "net",
           "connection dropped at accept as a typed accept_fault shed; "
           "net_accept_refused recorded, nothing submitted, zero lost"),
@@ -381,14 +399,16 @@ ALL_SITES: Dict[str, SiteSpec] = {s.name: s for s in (
           "typed write_fault shed (net_write_shed), never a lost future"),
     _site("place.assign", "raise", "serving/placement.py", "density",
           "model left cold by the bin-pack (place_assign_failed); it "
-          "pages in on first demand — zero request impact"),
+          "pages in on first demand — zero request impact",
+          keeps_planner=True),
     _site("place.evict", "raise", "serving/placement.py", "density",
           "eviction skipped (capacity prediction is advisory) with a "
-          "typed place_evict_failed; the page-in proceeds anyway"),
+          "typed place_evict_failed; the page-in proceeds anyway",
+          keeps_planner=True),
     _site("place.pagein", "raise", "serving/placement.py", "density",
           "page-in fails typed (place_pagein_failed); the front door "
           "retries within the bounded failover budget — typed shed "
-          "when exhausted, never a lost future"),
+          "when exhausted, never a lost future", keeps_planner=True),
     _site("preempt.stage_fit", "preempt", "dag.py", "train|stream",
           "train(resume=True) restores verified stages, bit-exact"),
     _site("preempt.checkpoint_write", "preempt", "persistence.py",

@@ -290,7 +290,6 @@ class DriftMonitor:
         #: path only gathers request values into numpy blocks (cheap);
         #: the per-column sketch update + compaction amortizes over
         #: ``every_rows``-sized batches instead of running per flush
-        #: (the ≤5% serve-overhead budget, docs/benchmarks.md)
         self._pending: List[Any] = []
         self._pending_rows = 0
         self._text_counts = {

@@ -19,8 +19,8 @@ Two replica kinds behind one duck-typed surface (``submit`` / ``health``
   fails them over to a survivor). Used by the tier-1 tests and the
   chaos-campaign ``fleet`` scenario.
 * :class:`SubprocessReplica` — **out-of-process** (``TG_FLEET_SUBPROCESS=1``
-  / ``FleetConfig.subprocess``; the multi-process soak + bench scaling
-  arm): a ``python -m transmogrifai_tpu.serving.replica_worker`` child
+  / ``FleetConfig.subprocess``; the multi-process soak): a
+  ``python -m transmogrifai_tpu.serving.replica_worker`` child
   serving a saved model dir over a JSON-lines stdio protocol. A real
   process boundary — ``kill()`` is a SIGKILL, and the reader thread
   failing every pending future with :class:`ReplicaLostError` is
@@ -193,8 +193,7 @@ class Replica:
         for name, src in models.items():
             if isinstance(src, str):
                 # manifest-verified load + warm pre-trace by default: the
-                # replica's first flush must hit warm plan caches (the
-                # zero-retrace tripwire runs per replica in the bench)
+                # replica's first flush must hit warm plan caches
                 self.registry.load(name, src,
                                    warm=True if warm is None else warm)
             else:
@@ -251,14 +250,6 @@ class Replica:
     def resident(self) -> List[str]:
         """Models currently warm on this replica."""
         return self.registry.names()
-
-    def warm_reports(self) -> Dict[str, Any]:
-        """Per-model warm reports (the bench's per-replica zero-retrace
-        evidence)."""
-        out = {}
-        for name in self.registry.names():
-            out[name] = self.registry.runtime(name).warm_info
-        return out
 
     def kill(self) -> None:
         """Simulate a replica crash: no drain — every queued request's
@@ -450,17 +441,6 @@ class SubprocessReplica:
             raise ValueError("subprocess replicas swap saved-model paths")
         self._call({"op": "swap", "model": model,
                     "path": model_or_path}).result(timeout=180.0)
-
-    def warm_reports(self) -> Dict[str, Any]:
-        """Per-model warm reports read through the health protocol —
-        ``registry.health()`` carries each runtime's ``warm_info`` under
-        ``models.<name>.warm`` (the bench's per-replica zero-compile +
-        AOT-hit evidence crosses the process boundary here)."""
-        try:
-            models = self.health().get("models", {})
-            return {name: m.get("warm") for name, m in models.items()}
-        except Exception:
-            return {}
 
     def kill(self) -> None:
         self._dead = True

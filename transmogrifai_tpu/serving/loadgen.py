@@ -1,4 +1,4 @@
-"""Open-loop synthetic load generator (``BENCH_MODE=serve``, ``op serve``).
+"""Open-loop synthetic load generator (``op serve``, ``op fleet``).
 
 Open-loop means arrivals follow a fixed schedule regardless of how fast
 the server answers — the honest way to measure a serving tier, because a
@@ -110,8 +110,8 @@ def run_open_loop(runtime: ServingRuntime, rows: List[Dict[str, Any]],
     (deterministic under ``model_seed``) and submits with ``model=...``
     so routing/paging is exercised per request; the report grows a
     per-model ``models`` breakdown whose buckets sum to the totals —
-    the per-model accounting identity the density bench line and the
-    campaign ``density`` scenario assert."""
+    the per-model accounting identity the campaign ``density`` scenario
+    asserts."""
     if rps <= 0:
         raise ValueError(f"rps must be > 0, got {rps}")
     tenant_names: List[str] = []
@@ -201,7 +201,7 @@ def run_open_loop(runtime: ServingRuntime, rows: List[Dict[str, Any]],
     # drain: every accepted request must resolve (result or typed shed).
     # A future that never resolves inside the drain budget is LOST — the
     # one outcome a serving tier may never produce; the campaign engine
-    # and BENCH_MODE=campaign assert lost == 0
+    # asserts lost == 0
     completed = quarantined = shed_deadline = failed = lost = 0
     shed_noreplica = 0
     slowest: List[Dict[str, Any]] = []
@@ -247,7 +247,7 @@ def run_open_loop(runtime: ServingRuntime, rows: List[Dict[str, Any]],
     # an upper bound on the serve latency (the drain loop walks futures in
     # submit order), but the ids are exact — each links to its recorder
     # timeline (blackbox.slice_for) and to the runtime histogram's
-    # exemplars, so a bench/chaos soak can name its tail outliers
+    # exemplars, so a chaos soak can name its tail outliers
     slowest.sort(key=lambda d: -d["ms"])
     del slowest[SLOWEST_K:]
     wall = time.monotonic() - start
@@ -287,11 +287,10 @@ def run_open_loop(runtime: ServingRuntime, rows: List[Dict[str, Any]],
         "degradedRows": summary.get("degradedRows", 0.0),
         "breaker": summary.get("breaker", {}),
         # per-tenant accounting (same buckets as the totals; None
-        # without a tenant mix) — the per-tenant-budget tests and the
-        # BENCH_MODE=serve tenant line read this
+        # without a tenant mix) — the per-tenant-budget tests read this
         "tenants": per_tenant or None,
         # per-model accounting twin (None without a model mix) — buckets
-        # sum to the totals; the density bench line reads this
+        # sum to the totals; `op fleet` prints it as perModel
         "models": per_model or None,
     }
     # fleet targets: per-replica routing distribution + failover /

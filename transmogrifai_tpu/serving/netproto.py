@@ -41,7 +41,7 @@ using the same status codes as the HTTP mapping.
 Every malformed condition raises :class:`FrameError` — the edge maps it
 to a typed 400 shed, never an untyped escape. :class:`WireClient` is the
 shared synchronous client (tests, loadgen socket driver, campaign ``net``
-scenario, bench wire lines, ``op serve --listen``); a connection that
+scenario, ``op serve --listen``); a connection that
 dies mid-request raises :class:`WireDisconnect`, which callers count in
 the typed ``shedDisconnect`` bucket — never ``lost``.
 """

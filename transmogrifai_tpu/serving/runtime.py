@@ -563,7 +563,7 @@ class ServingRuntime:
     def _flush(self, batch: List[_Request]) -> None:
         # stage attribution twin of the pipelined histograms: one serial
         # flush is gather+dispatch+complete fused, recorded as
-        # stage="serial" so the bench A/B can compare like with like
+        # stage="serial" so the two loops compare like with like
         t0 = time.perf_counter()
         try:
             with _obs_span("serve.flush", cat="serve", model=self.name,

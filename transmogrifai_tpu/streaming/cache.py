@@ -10,8 +10,7 @@ read + upstream transform + pack for chunk ``i``, every later pass with
 the same upstream models can replay the exact bytes instead of redoing
 the work. The streaming GBT makes ``1 + trees x (depth + 1)`` passes over
 the identical transformed stream — this cache is what turns that
-amplification from "re-prepare everything" into "re-read host blocks"
-(docs/benchmarks.md round 20; the bench A/B's third arm).
+amplification from "re-prepare everything" into "re-read host blocks".
 
 Two bounded tiers:
 
@@ -133,7 +132,7 @@ class PackedChunk:
 
     def content_sha(self) -> str:
         """Digest of the packed payload bytes — the byte-equality probe
-        tests and the bench A/B compare cached vs recomputed chunks on."""
+        tests compare cached vs recomputed chunks on."""
         h = hashlib.sha256()
         h.update(json.dumps(self.header, sort_keys=True).encode())
         for dt in self.header["dtypes"]:

@@ -2,7 +2,7 @@
 
 While the consumer folds chunk N, a pool of ``TG_STREAM_WORKERS``
 producer threads (default min(4, cores); ``1`` reproduces the round-7
-serial feed thread-for-thread and is the bench A/B baseline) prepares
+serial feed thread-for-thread) prepares
 the chunks behind it. Each worker *claims* the next schedule index —
 gated on the same slot semaphore as always, so device residency stays
 O(prefetch + 1 chunks), never O(dataset) — then runs read (chaos site
@@ -24,7 +24,7 @@ consumes host numpy views, so a cache hit is byte-equal input with zero
 h2d traffic; chaos site ``stream.cache`` = corrupt/evicted entry, which
 falls back to a typed bit-equal recompute).
 
-Accounting (:class:`FeedStats`) is what the stream bench line reports:
+Accounting (:class:`FeedStats`) is what a stream run reports:
 uploaded bytes, per-stage seconds (read / transform / upload — also
 observed as ``tg_stream_stage_seconds{stage=...}``), cache hits/misses,
 peak concurrently-resident device bytes (the O(chunk) claim, asserted

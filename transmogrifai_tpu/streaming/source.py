@@ -221,8 +221,7 @@ class AvroChunkSource(ChunkSource):
 class SyntheticChunkSource(ChunkSource):
     """Deterministic synthetic generator: chunk ``i`` is a pure function of
     ``(seed, i)``, so any chunk regenerates independently — resume never
-    replays the prefix, and no pass ever materializes the dataset (the
-    10M×64 bench source, BENCH_MODE=stream).
+    replays the prefix, and no pass ever materializes the dataset.
 
     Emits ``x0..x{d-1}`` Real predictor columns (a deterministic ~3% of
     slots masked invalid) and a RealNN ``y`` response from a fixed hidden

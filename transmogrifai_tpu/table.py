@@ -132,9 +132,9 @@ def column_of_scalars(feature_type: Type[FeatureType],
     """Vectorized dual of ``Column.of_values`` for numeric scalar kinds:
     one ``np.asarray`` sweep instead of a python loop calling
     ``float()``/``int()`` per cell — the serve-time request→table hot path
-    (local/scoring.serve_table_builder; docs/benchmarks.md "Serving
-    runtime"). Returns None whenever the batch is not homogeneous numeric
-    (a None, a string, a FeatureType wrapper) — the caller falls back to
+    (local/scoring.serve_table_builder). Returns None whenever the batch
+    is not homogeneous numeric (a None, a string, a FeatureType wrapper) —
+    the caller falls back to
     ``of_values``, so semantics are byte-identical by construction:
     NaN = missing, invalid slots hold 0, binary truth-tests, integral
     truncation all match the per-cell path."""

@@ -692,8 +692,8 @@ class OpWorkflowModel(_WorkflowCore):
         """Batch scoring over the fitted transformer DAG. The pass runs on
         the fused substrate: the transform-plan compiler (``plan.py``)
         traces each device-fusable segment into one XLA program (eager
-        per-stage dispatch under ``TG_PLAN=0`` or active chaos — results
-        are bit-identical either way, docs/plan.md)."""
+        per-stage dispatch under ``plan.enable_planning(False)`` or active
+        chaos — results are bit-identical either way, docs/plan.md)."""
         if df is not None:
             table = dataframe_to_table(df, self.raw_features)
         if table is None:
