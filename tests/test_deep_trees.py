@@ -282,3 +282,133 @@ def test_chain_sibling_subtraction_parity(monkeypatch, mode, W, depth):
     names = ("feat_lv", "thr_lv", "bin_lv", "base_lv", "node_s")
     for nm, a, b in zip(names, base, sib):
         np.testing.assert_array_equal(a, b, err_msg=nm)
+
+
+# ---------------------------------------------------------------------------
+# Per-tree compact columns: the growers contract and search only the columns
+# each tree drew, and grow the same trees as over all d columns and a mask
+# ---------------------------------------------------------------------------
+
+def _subset_case(mode, k, Tb, seed=7, S=700, d=24, n_bins=16, p=0.3):
+    """One table, strict per-tree subsets (one tree drew nothing), as both
+    forms the growers take them in: (Tb, d) masks and the (Tb, d_sub)
+    ascending index table padded with the sentinel d."""
+    rng = np.random.RandomState(seed)
+    codes = jnp.asarray(rng.randint(0, n_bins, (S, d)), jnp.int32)
+    edges = jnp.asarray(np.sort(rng.randn(d, n_bins - 1), 1), jnp.float32)
+    fm = rng.rand(Tb, d) < p
+    fm[Tb // 2] = False
+    d_sub = -(-int(fm.sum(1).max()) // 4) * 4
+    assert d_sub < d
+    cols = np.sort(np.where(fm, np.arange(d), d), 1)[:, :d_sub]
+    # well-separated stats so split choices don't sit on numeric ties
+    sw = [jnp.asarray(rng.rand(S, Tb).astype(np.float32) + 0.1)
+          for _ in range(k)]
+    cfg = {"max_depth": jnp.full((Tb,), 5.0, jnp.float32),
+           "min_instances": jnp.full((Tb,), 1.0, jnp.float32),
+           "min_info_gain": jnp.full((Tb,), 1e-4, jnp.float32),
+           "lam": jnp.full((Tb,), 1e-6, jnp.float32),
+           "min_child_weight": jnp.zeros((Tb,), jnp.float32)}
+    return (codes, edges, sw, jnp.asarray(fm), cfg,
+            jnp.asarray(cols, jnp.int32), n_bins, fm)
+
+
+@pytest.mark.parametrize("sibling", [False, True])
+@pytest.mark.parametrize("mode,k", [("counts", 2), ("counts", 5), ("gh", 3)])
+def test_capped_grower_compact_columns_grow_the_same_trees(
+        monkeypatch, mode, k, sibling):
+    """Compact against full, slot-chain grower, sibling subtraction off (Tb
+    below the gate) and on (above it): the same split column and bin at
+    every level and slot, thresholds equal, the sample routed to the same
+    leaves; no split uses a column its tree did not draw."""
+    monkeypatch.setattr(T, "_CHAIN_SIBLING_MIN_TB", 8)
+    Tb = 12 if sibling else 6
+    codes, edges, sw, fmasks, cfg, cols, n_bins, fm = _subset_case(
+        mode, k, Tb)
+
+    def grow(**kw):
+        return [np.asarray(a) for a in T._grow_forest_capped(
+            codes, edges, sw, fmasks, cfg, depth=5, n_bins=n_bins,
+            mode=mode, n_slots=8, **kw)]
+
+    full, compact = grow(), grow(feat_idx=cols)
+    for nm, a, b in zip(("feat_lv", "thr_lv", "bin_lv", "base_lv", "node_s"),
+                        full, compact):
+        np.testing.assert_array_equal(a, b, err_msg=nm)
+    feat_lv, bin_lv = compact[0], compact[2]
+    split = bin_lv < n_bins
+    assert split.any()
+    t_of = np.broadcast_to(np.arange(Tb)[:, None, None], feat_lv.shape)
+    assert fm[t_of[split], feat_lv[split]].all()
+    assert not split[Tb // 2].any()       # the tree that drew nothing
+
+
+@pytest.mark.parametrize("B,n_trees,d,task", [
+    (1, 50, 28, "classification"), (54, 16, 76, "classification"),
+    (54, 16, 105, "classification"), (6, 5, 24, "regression"),
+    (18, 16, 300, "regression"), (3, 7, 6, "classification"),
+    (2, 3, 4, "regression")])
+def test_feature_table_width_is_exact(B, n_trees, d, task):
+    """d_sub is a bound, not an estimate: every tree's drawn set is its
+    ``feat_idx`` row (ascending, sentinel-padded), the widest tree fills the
+    row to within the rounding to 4, masks and index come from one table,
+    and the masks are the draws the fit's own keys give."""
+    p = T._rf_p_feat(d, task)
+    fmasks, feat_idx = T._rf_feature_table(B, n_trees, d, p)
+    assert fmasks.shape == (B, n_trees, d) and fmasks.dtype == bool
+    seeds = jnp.asarray(T._rf_seeds(B))
+    for b, t in ((0, 0), (B - 1, n_trees - 1)):
+        want = jax.random.bernoulli(T._rf_tree_keys(seeds[b], t)[1], p, (d,))
+        np.testing.assert_array_equal(fmasks[b, t], np.asarray(want))
+    widest = int(fmasks.sum(-1).max())
+    if feat_idx is None:
+        assert max(4, -(-widest // 4) * 4) >= d
+        return
+    d_sub = feat_idx.shape[-1]
+    assert feat_idx.shape == (B, n_trees, d_sub) and d_sub % 4 == 0
+    assert widest <= d_sub < min(d, widest + 4)
+    back = np.zeros_like(fmasks)
+    b_i, t_i, c_i = np.nonzero(feat_idx < d)
+    back[b_i, t_i, feat_idx[b_i, t_i, c_i]] = True
+    np.testing.assert_array_equal(back, fmasks)      # nothing dropped
+    assert (np.diff(feat_idx, axis=-1) >= 0).all()
+    real = feat_idx[..., 1:] < d
+    assert (np.diff(feat_idx, axis=-1)[real] > 0).all()
+    assert T._rf_feature_table(B, n_trees, d, p)[1] is feat_idx   # cached
+
+
+def _tree_batched_dots(jaxpr):
+    """dot_generals with two batch dimensions (row block x tree): the
+    compact path's contraction; the full-width one has the row block only."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            n += len(eqn.params["dimension_numbers"][1][0]) == 2
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _tree_batched_dots(sub)
+    return n
+
+
+@pytest.mark.parametrize("fam_name,d,compact", [
+    ("OpRandomForestClassifier", 30, True),
+    ("OpRandomForestRegressor", 30, True),
+    ("OpRandomForestClassifier", 6, False),
+    ("OpGBTClassifier", 30, False), ("OpXGBoostClassifier", 30, False),
+    ("OpDecisionTreeClassifier", 30, False)])
+def test_only_strict_subsets_trace_the_compact_path(fam_name, d, compact):
+    """The width of the subset is the one thing the growers adapt to: a
+    forest over a table wide enough for strict subsets traces the
+    tree-batched contraction; one whose d_sub >= d, and the callers that
+    pass no subset (boosted trees, XGBoost, single trees), trace none."""
+    fam = MODEL_REGISTRY[fam_name]
+    problem = "regression" if "Regressor" in fam_name else "binary"
+    stock = fam.default_grid(problem)
+    grid = [stock[0], stock[-1]]          # the shallowest and the deepest
+    garr = {k: np.asarray(v) for k, v in fam.grid_to_arrays(grid).items()}
+    n = 600
+    jaxpr = jax.make_jaxpr(
+        lambda X, y, W: fam.sweep_fit_batch(X, y, W, garr, 2))(
+        jax.ShapeDtypeStruct((n, d), jnp.float32),
+        jax.ShapeDtypeStruct((n,), jnp.float32),
+        jax.ShapeDtypeStruct((len(grid), n), jnp.float32))
+    assert (_tree_batched_dots(jaxpr.jaxpr) > 0) == compact

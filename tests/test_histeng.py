@@ -103,15 +103,19 @@ def test_pinned_kernel_bit_exact_under_mesh_sharding(monkeypatch):
     np.testing.assert_array_equal(sharded, plain)
 
 
-def test_build_node_hist_under_jit_with_engine_mesh(monkeypatch):
+@pytest.mark.parametrize("per_tree", [False, True])
+def test_build_node_hist_under_jit_with_engine_mesh(monkeypatch, per_tree):
     """The node-resolved entry point, jitted and traced under
-    `engine_mesh`, shards its row blocks and gives the single-device bits.
+    `engine_mesh`, shards its row blocks and gives the single-device bits:
+    with codes shared by the trees, and with per-tree codes (the forest's
+    compact columns: the batched contraction keeps the pinned form).
     `engine_mesh` enters ``jax.set_mesh``, so it wraps the jitted call from
     outside — entering it inside a trace is refused by jax."""
     monkeypatch.setenv("TG_TREE_PALLAS", "0")
     rng = np.random.RandomState(5)
     S, d, nb, T, Wl = 257, 5, 8, 3, 4
-    codes = jnp.asarray(rng.randint(0, nb, (S, d)).astype(np.int32))
+    codes = jnp.asarray(rng.randint(
+        0, nb, (S, T, d) if per_tree else (S, d)).astype(np.int32))
     node = jnp.asarray(rng.randint(0, Wl, (S, T)).astype(np.int32))
     sw = jnp.asarray(rng.randn(S, T).astype(np.float32))
     plain = np.asarray(histeng.build_node_hist(codes, node, [sw], nb,

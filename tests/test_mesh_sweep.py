@@ -117,15 +117,21 @@ GBT_GRID = [{"maxDepth": 3, "maxIter": 4, "stepSize": 0.3},
 
 
 @pytest.mark.hist
-@pytest.mark.parametrize("n", [400, 333, 257])
-def test_fused_mesh_tree_families_bit_exact(force_mesh, n):
+@pytest.mark.parametrize("n,d", [(400, 8), (333, 8), (257, 8), (333, 24),
+                                 (257, 40)])
+def test_fused_mesh_tree_families_bit_exact(force_mesh, n, d):
     """Tree families under the mesh are BIT-identical to single-device —
     the histogram engine's pinned K-blocked reduction (histeng.kernels)
     replaces the order-unspecified psum that used to leave mesh trees only
     'within noise' of the plain sweep. Odd row counts (333, 257) do not
     divide the 'data' axis: bucket padding plus the engine's sentinel row
-    blocks must keep the pinned combine identical anyway."""
-    X, y = _synth(n=n)
+    blocks must keep the pinned combine identical anyway. At 24 and 40
+    columns the forest's per-tree subsets are strict, so its growers run
+    the compact per-tree contraction (at 8 they run full width)."""
+    X, y = _synth(n=n, d=d)
+    rf_attrs = MODEL_REGISTRY["OpRandomForestClassifier"].fit_span_attrs(
+        n, d, RF_GRID * 3, 2, True)
+    assert (0 < rf_attrs["featSubset"] < d) == (d > 8)
     models = _models(("OpRandomForestClassifier", RF_GRID),
                      ("OpGBTClassifier", GBT_GRID))
     plain = OpCrossValidation(num_folds=3, seed=3).validate(

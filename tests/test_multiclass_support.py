@@ -204,6 +204,13 @@ def test_span_attributes_come_from_the_solvers_own_rules(monkeypatch):
         return real_map(f, xs, **kw)
 
     monkeypatch.setattr(jax.lax, "map", spy)
+    # ... and the compact column width against what the growers are handed
+    widths = set()
+    for name in ("_grow_forest", "_grow_forest_capped"):
+        def grower(*a, _real=getattr(trees, name), feat_idx=None, **kw):
+            widths.add(0 if feat_idx is None else feat_idx.shape[1])
+            return _real(*a, feat_idx=feat_idx, **kw)
+        monkeypatch.setattr(trees, name, grower)
     n, d, C = 20000, 30, 23
     garr = {k: np.asarray(v) for k, v in rf.grid_to_arrays(grid).items()}
     jax.eval_shape(
@@ -211,8 +218,12 @@ def test_span_attributes_come_from_the_solvers_own_rules(monkeypatch):
         jax.ShapeDtypeStruct((n, d), jnp.float32),
         jax.ShapeDtypeStruct((n,), jnp.float32),
         jax.ShapeDtypeStruct((len(grid), n), jnp.float32))
-    assert rf.fit_span_attrs(n, d, grid, C, True) == {
-        "configChunks": sum(seen)}
+    attrs = rf.fit_span_attrs(n, d, grid, C, True)
+    assert attrs == {"configChunks": sum(seen), "featSubset": attrs[
+        "featSubset"]}
+    assert widths == {attrs["featSubset"]} and 0 < attrs["featSubset"] < d
+    # a table too narrow for a strict subset runs full width: 0
+    assert rf.fit_span_attrs(n, 6, grid, C, True)["featSubset"] == 0
     # at train-kddcup99's shape: three depth groups of 18 lanes, the deep
     # one (64 sweep slots x 23 planes) the most chunked; at the refit's 256
     # slots a configuration would be a chunk of its own

@@ -38,3 +38,40 @@ def test_node_hist_matches_acat(T, Wl, stride):
     ref = _reference(codes, node, sw, Wl, nb, stride, k)
     assert out.shape == ref.shape == (k * Wl * T, d * nb)
     np.testing.assert_allclose(out, ref, rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("T,Wl,stride,S", [(5, 1, 1, 512), (54, 7, 1, 512),
+                                           (20, 32, 2, 512), (7, 4, 1, 333),
+                                           (3, 2, 2, 5)])
+def test_node_hist_per_tree_columns_match_shared(T, Wl, stride, S):
+    """Per-tree codes (S, T, d_sub): every tree's block of the batched
+    contraction equals the shared-codes histogram read at that tree's own
+    columns, and a sentinel column (code n_bins) is all zeros. Integer-ish
+    stats keep the bf16 sums exact, so the two contractions agree to the
+    bit whatever order the rows are summed in (S=333 pads a row block,
+    S=5 < K runs unblocked)."""
+    import jax.numpy as jnp
+    from transmogrifai_tpu.histeng import build_node_hist
+    rng = np.random.RandomState(T)
+    d, nb, k, d_sub = 9, 8, 3, 4
+    codes = rng.randint(0, nb, size=(S, d)).astype(np.int32)
+    node = rng.randint(0, max(stride * Wl, 1), size=(S, T)).astype(np.int32)
+    sw = [jnp.asarray(rng.randint(-3, 4, (S, T)).astype(np.float32))
+          for _ in range(k)]
+    cols = np.stack([np.sort(rng.choice(d, d_sub, replace=False))
+                     for _ in range(T)])
+    cols[0, -1] = d                                   # one short tree
+    per_tree = np.where(cols[None] < d,
+                        codes[:, np.minimum(cols, d - 1)], nb)   # (S, T, 4)
+    full = np.asarray(build_node_hist(
+        jnp.asarray(codes), jnp.asarray(node), sw, nb, n_nodes=Wl,
+        stride=stride))                               # (k, Wl, T, d, nb)
+    got = np.asarray(build_node_hist(
+        jnp.asarray(per_tree), jnp.asarray(node), sw, nb, n_nodes=Wl,
+        stride=stride))
+    assert got.shape == (k, Wl, T, d_sub, nb)
+    for t in range(T):
+        for c in range(d_sub):
+            want = (full[:, :, t, cols[t, c]] if cols[t, c] < d
+                    else np.zeros((k, Wl, nb), np.float32))
+            np.testing.assert_array_equal(got[:, :, t, c], want)

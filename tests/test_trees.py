@@ -222,3 +222,28 @@ def test_grow_forest_leaf_stats_match_segment_sums():
         mode="gh", return_leaf_stats=True)
     want = np.stack([np.asarray(s).sum(0) for s in sw], -1)[:, None, :]
     np.testing.assert_allclose(np.asarray(lst0), want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("mode,k", [("counts", 2), ("counts", 5), ("gh", 3)])
+def test_grow_forest_compact_columns_grow_the_same_trees(mode, k):
+    """Compact against full, complete-heap grower (sibling subtraction at
+    every level): the same split column and bin at every heap node, equal
+    thresholds, the same routing, and the final level's leaf statistics
+    within float32 rounding — also for the tree that drew no column, whose
+    node totals are read off a column that is no candidate."""
+    from test_deep_trees import _subset_case
+    from transmogrifai_tpu.models.trees import _grow_forest
+    codes, edges, sw, fmasks, cfg, cols, n_bins, fm = _subset_case(
+        mode, k, Tb=6, seed=3)
+
+    def grow(**kw):
+        return [np.asarray(a) for a in _grow_forest(
+            codes, edges, sw, fmasks, cfg, depth=4, n_bins=n_bins,
+            mode=mode, return_leaf_stats=True, **kw)]
+
+    full, compact = grow(), grow(feat_idx=cols)
+    for nm, a, b in zip(("feat", "thresh", "bins", "node_s"), full, compact):
+        np.testing.assert_array_equal(a, b, err_msg=nm)
+    np.testing.assert_allclose(compact[4], full[4], rtol=1e-6, atol=1e-4)
+    assert (compact[2] < n_bins).any() and compact[4][3].sum() > 0
+    assert not (compact[2][3] < n_bins).any()

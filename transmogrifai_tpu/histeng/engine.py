@@ -98,7 +98,10 @@ def build_node_hist(codes, node, stats: Sequence, n_bins: int, *,
     codes, ``node`` (S, T) int32 current slot per tree (values < 0 never
     match), ``stats``: k arrays (S, T) of per-tree row statistics,
     ``stride``: slot-id multiplier (2 = heap left-children). Returns
-    (k, n_nodes, T, d, n_bins) f32 on device.
+    (k, n_nodes, T, d, n_bins) f32 on device. ``codes`` (S, T, d_sub) gives
+    every tree its own columns (a forest's drawn feature subsets, sentinel
+    code ``n_bins`` where a tree has fewer): the same pinned contraction
+    batched over trees, d_sub wide; returns (k, n_nodes, T, d_sub, n_bins).
 
     Host backend (numpy inputs): ``codes`` (d, n)
     int64 feature-major from `bin_codes_host` (feature-major on purpose —
@@ -121,7 +124,7 @@ def build_node_hist(codes, node, stats: Sequence, n_bins: int, *,
                             stride=stride)
     k = len(stats)
     T = node.shape[1]
-    d = codes.shape[1]
+    d = codes.shape[-1]
     return flat.reshape(k, n_nodes, T, d, n_bins)
 
 

@@ -376,6 +376,42 @@ def _node_hist_xla(codes, node, sws, Wl_eff, n_bins, stride, k, exact=False):
     return _hist_xla_pinned(codes, A, n_bins, exact)
 
 
+def _node_hist_xla_per_tree(codes, node, sw_list, Wl, n_bins, stride):
+    """The node histogram where every tree has its OWN columns: codes
+    (S, T, d_sub), node (S, T), k stats (S, T). One contraction batched over
+    trees, (T; S, k·Wl) x (T; S, d_sub·nb), in the pinned form of
+    `_hist_xla_pinned`: the same K row blocks (sentinel-padded), the same
+    'data'-axis constraints under an engine mesh, the same `_tree_combine`.
+    Returns (k·Wl·T, d_sub·nb), lane = (k·Wl + j)·T + t."""
+    S, T, dw = codes.shape
+    k = len(sw_list)
+    K = _hist_shards()
+    if K <= 1 or S < K:
+        K = 1
+    j = stride * jnp.arange(Wl, dtype=jnp.int32)
+    n_oh = (node[:, :, None] == j).astype(jnp.float32)        # (S, T, Wl)
+    A = jnp.concatenate(
+        [n_oh * sw.astype(jnp.float32)[:, :, None] for sw in sw_list],
+        axis=2)                                               # (S, T, k·Wl)
+    Sp = _pad_to(S, K)
+    cb = jnp.pad(codes.astype(jnp.int32), ((0, Sp - S), (0, 0), (0, 0)),
+                 constant_values=n_bins).reshape(K, Sp // K, T, dw)
+    ab = jnp.pad(A, ((0, Sp - S), (0, 0), (0, 0))
+                 ).reshape(K, Sp // K, T, k * Wl)
+    mesh = current_engine_mesh()
+    if mesh is not None:
+        cb = jax.lax.with_sharding_constraint(cb, _data_spec(mesh, 4))
+        ab = jax.lax.with_sharding_constraint(ab, _data_spec(mesh, 4))
+    oh = (cb[..., None] == jnp.arange(n_bins, dtype=jnp.int32)
+          ).astype(jnp.bfloat16).reshape(K, Sp // K, T, dw * n_bins)
+    oh = jax.lax.optimization_barrier(oh)
+    parts = jnp.einsum("rsta,rstf->rtaf", ab.astype(jnp.bfloat16), oh,
+                       preferred_element_type=jnp.float32)
+    if mesh is not None:
+        parts = jax.lax.with_sharding_constraint(parts, _data_spec(mesh, 4))
+    out = _tree_combine(parts)                                # (T, k·Wl, ·)
+    return out.transpose(1, 0, 2).reshape(k * Wl * T, dw * n_bins)
+
 
 @jax.named_scope("hist.build")
 def node_hist_matmul(codes: jnp.ndarray, node: jnp.ndarray,
@@ -387,13 +423,19 @@ def node_hist_matmul(codes: jnp.ndarray, node: jnp.ndarray,
     a pallas kernel that expanded it tile-by-tile in VMEM measured SLOWER
     at every production shape, sweep and refit alike, and was retired).
 
-    codes: (S, d) int32 bin codes; node: (S, T) int32 current slot per tree
-    (values < 0 never match); sw_list: k arrays (S, T) of per-tree stats;
-    ``stride``: slot-id multiplier (2 = heap left-children, 1 = chain slots).
+    codes: (S, d) int32 bin codes shared by every tree, or (S, T, d_sub)
+    where each tree brings its own columns (a forest's drawn subsets: the
+    contraction is then batched over trees and d_sub wide, not d); node:
+    (S, T) int32 current slot per tree (values < 0 never match); sw_list: k
+    arrays (S, T) of per-tree stats; ``stride``: slot-id multiplier (2 =
+    heap left-children, 1 = chain slots).
     Returns (k·Wl·T, d·n_bins) f32, lane = (k·Wl + j)·T + t — identical
     layout to ``hist_matmul(codes, A_cat, n_bins)`` with A_cat built k-major
     then j-major.
     """
+    if codes.ndim == 3:
+        return _node_hist_xla_per_tree(codes, node, sw_list, Wl, n_bins,
+                                       stride)
     S, d = codes.shape
     T = node.shape[1]
     k = len(sw_list)

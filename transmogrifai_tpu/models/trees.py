@@ -47,7 +47,7 @@ from __future__ import annotations
 
 import logging
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -318,8 +318,42 @@ def _grow_tree(codes_s, edges, stats_s, w_s, feat_mask, cfg, *,
     return feat_heap, thr_heap, bin_heap, node
 
 
+def _tree_columns(codes_s, fmasks, feat_idx, n_bins: int):
+    """The columns a forest grower builds histograms of and searches.
+
+    Without ``feat_idx`` every tree sees all d columns of the shared codes
+    and ``fmasks`` (Tb, d) marks its candidates. With ``feat_idx`` (Tb,
+    d_sub), each tree's drawn columns in ascending order (so ``argmax`` ties
+    break as over all d) and padded with the sentinel d, each tree gets its
+    own COMPACT codes (S, Tb, d_sub), made once for all levels by a one-hot
+    (d+1, Tb·d_sub) matmul (one nonzero term a sum: exact in bfloat16 for
+    codes <= n_bins <= 256); the sentinel selects an appended column of
+    ``n_bins``, the code that matches no histogram lane. A tree's FIRST
+    compact column always holds a real one (column 0, not a candidate,
+    where the tree drew nothing): the growers read node totals off it.
+    Returns (codes for `build_node_hist`, (Tb, width) bool candidates,
+    (Tb, width) true column of each compact one or None)."""
+    if feat_idx is None:
+        return codes_s, fmasks, None
+    S, d = codes_s.shape
+    Tb, d_sub = feat_idx.shape
+    drawn = feat_idx < d
+    col_of = jnp.where(drawn, feat_idx, 0)
+    src = feat_idx.at[:, 0].set(col_of[:, 0])
+    codes_aug = jnp.concatenate(
+        [codes_s.astype(jnp.bfloat16),
+         jnp.full((S, 1), n_bins, jnp.bfloat16)], axis=1)        # (S, d+1)
+    pick = (src.reshape(1, Tb * d_sub)
+            == jnp.arange(d + 1, dtype=jnp.int32)[:, None]
+            ).astype(jnp.bfloat16)                               # (d+1, ·)
+    codes_c = jnp.dot(codes_aug, pick, preferred_element_type=jnp.float32
+                      ).astype(jnp.int32).reshape(S, Tb, d_sub)
+    return codes_c, drawn, col_of
+
+
 def _grow_forest(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
-                 n_bins: int, mode: str, return_leaf_stats: bool = False):
+                 n_bins: int, mode: str, return_leaf_stats: bool = False,
+                 feat_idx=None):
     """Grow Tb complete-heap trees AT ONCE on the split-search sample.
 
     The tree batch (configs × trees) lives flattened in the lane axis from
@@ -331,7 +365,10 @@ def _grow_forest(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
 
     codes_s: (S, d) shared int32 bin codes; sw_list: k arrays (S, Tb) — the
     per-tree stat·rowweight products, one array per stat so no tiny-minor
-    array ever exists; fmasks: (Tb, d) feature subsets; cfg: dict of (Tb,)
+    array ever exists; fmasks: (Tb, d) feature subsets; ``feat_idx``: the
+    same subsets as a (Tb, d_sub) index table where they are strict (see
+    `_tree_columns`: histograms and split search then run over each tree's
+    d_sub columns only); cfg: dict of (Tb,)
     per-tree scalars. Returns (feat (Tb,H), thresh (Tb,H), bins (Tb,H),
     node_s (S, Tb)); with ``return_leaf_stats`` also a (Tb, 2^depth, k)
     per-leaf stat-sum tensor read off the FINAL level's histogram — the
@@ -342,6 +379,8 @@ def _grow_forest(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
     Tb = sw_list[0].shape[1]
     k = len(sw_list)
     codes_f = codes_s.astype(jnp.bfloat16)
+    codes_h, col_ok, col_of = _tree_columns(codes_s, fmasks, feat_idx, n_bins)
+    dw = col_ok.shape[1]                 # columns searched per tree
     H = 2 ** depth - 1
     feat_heap = jnp.zeros((Tb, H), jnp.int32)
     thr_heap = jnp.full((Tb, H), jnp.inf, jnp.float32)
@@ -366,22 +405,22 @@ def _grow_forest(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
         # histogram matmul FLOPs and the A_cat HBM traffic at every level.
         if level == 0:
             # root: node == 0 everywhere, the one-hot is all-ones
-            hist = build_node_hist(codes_s, node, sw_list, n_bins, n_nodes=1)
+            hist = build_node_hist(codes_h, node, sw_list, n_bins, n_nodes=1)
             hist = hist[:, 0].transpose(1, 2, 3, 0)
         else:
             h = m // 2
             # left children only (heap slot 2j), fused in VMEM
             # (node_hist_matmul stride=2); right = parent − left below
-            hist_l = build_node_hist(codes_s, node, sw_list, n_bins,
+            hist_l = build_node_hist(codes_h, node, sw_list, n_bins,
                                      n_nodes=h, stride=2)
-            hist_l = hist_l.reshape(k, h * Tb, d, n_bins
+            hist_l = hist_l.reshape(k, h * Tb, dw, n_bins
                                     ).transpose(1, 2, 3, 0)          # (h·Tb,…)
             hist_r = hist_prev - hist_l
             # interleave children j-major: row (2j'+parity)·Tb + t
             hist = jnp.stack(
-                [hist_l.reshape(h, Tb, d, n_bins, k),
-                 hist_r.reshape(h, Tb, d, n_bins, k)],
-                axis=1).reshape(M, d, n_bins, k)
+                [hist_l.reshape(h, Tb, dw, n_bins, k),
+                 hist_r.reshape(h, Tb, dw, n_bins, k)],
+                axis=1).reshape(M, dw, n_bins, k)
         hist_prev = hist
         cum = jnp.cumsum(hist, axis=2)
         total = cum[:, 0, -1, :]                       # (M, k) node totals
@@ -389,11 +428,14 @@ def _grow_forest(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
         SR = total[:, None, None, :] - SL
         cfg_m = {key: jnp.tile(v, m) for key, v in cfg.items()}
         gain, valid = _split_gain(SL, SR, total, cfg_m, mode)
-        valid = valid & jnp.tile(fmasks, (m, 1))[:, :, None]
+        valid = valid & jnp.tile(col_ok, (m, 1))[:, :, None]
         gain = jnp.where(valid, gain, -jnp.inf)
-        gflat = gain.reshape(M, d * (n_bins - 1))
+        gflat = gain.reshape(M, dw * (n_bins - 1))
         best = jnp.argmax(gflat, axis=1)
         bf = (best // (n_bins - 1)).astype(jnp.int32)
+        if col_of is not None:           # compact column -> the tree's own
+            bf = jnp.take_along_axis(jnp.tile(col_of, (m, 1)), bf[:, None],
+                                     axis=1)[:, 0]
         bb = (best % (n_bins - 1)).astype(jnp.int32)
         bgain = jnp.take_along_axis(gflat, best[:, None], axis=1)[:, 0]
         active = jnp.asarray(level, jnp.float32) < jnp.tile(
@@ -426,7 +468,7 @@ def _grow_forest(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
             # leaf stats off this level's histogram: left child = chosen
             # split's left cumsum (node total when stopped), right = rest
             k_st = hist.shape[-1]
-            SL_flat = SL.reshape(M, d * (n_bins - 1), k_st)
+            SL_flat = SL.reshape(M, dw * (n_bins - 1), k_st)
             left = jnp.take_along_axis(
                 SL_flat, best[:, None, None], axis=1)[:, 0]       # (M, k)
             left = jnp.where(do_split[:, None], left, total)
@@ -441,7 +483,7 @@ def _grow_forest(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
 
 
 def _grow_forest_capped(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
-                        n_bins: int, mode: str, n_slots: int):
+                        n_bins: int, mode: str, n_slots: int, feat_idx=None):
     """Grow Tb slot-chain ("leaf budget") trees at once — arbitrary depth at
     a bounded per-level width.
 
@@ -460,7 +502,8 @@ def _grow_forest_capped(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
     Emits per-level tables (Tb, depth, W): split feature, bin threshold
     (sentinel ``n_bins`` ⇒ route left), raw threshold, and the child base
     pointer — routing is ``slot' = base[slot] + go`` (ops/forest.py chain
-    kernels). Returns (feat_lv, thr_lv, bin_lv, base_lv, node_s) with
+    kernels). ``feat_idx``: as in `_grow_forest`. Returns (feat_lv, thr_lv,
+    bin_lv, base_lv, node_s) with
     node_s (S, Tb) the final sample leaf slot in [0, min(2^depth, W))."""
     from ..ops.forest import _chain_widths, _check_slots
     _check_slots(n_slots)
@@ -469,6 +512,8 @@ def _grow_forest_capped(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
     k = len(sw_list)
     W = n_slots
     codes_f = codes_s.astype(jnp.bfloat16)
+    codes_h, col_ok, col_of = _tree_columns(codes_s, fmasks, feat_idx, n_bins)
+    dw = col_ok.shape[1]                 # columns searched per tree
     feat_lv = jnp.zeros((Tb, depth, W), jnp.int32)
     thr_lv = jnp.full((Tb, depth, W), jnp.inf, jnp.float32)
     bin_lv = jnp.full((Tb, depth, W), n_bins, jnp.int32)
@@ -493,7 +538,7 @@ def _grow_forest_capped(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
     # narrow per-step ops are latency-bound; the reconstruction's extra
     # gathers/stacks cost more than the saved FLOPs), hence the width gate.
     sibling = Tb >= _CHAIN_SIBLING_MIN_TB
-    hist5_prev = None                 # (Wl_prev, Tb, d, nb, k) f32
+    hist5_prev = None                 # (Wl_prev, Tb, dw, nb, k) f32
     odd_map_prev = None               # (j_src (Wh_o, Tb), is_rchild)
     for level in range(depth):
         Wl = widths[level]
@@ -504,38 +549,41 @@ def _grow_forest_capped(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
         # the operand in VMEM measured slower at every production shape and
         # was retired
         if level == 0 or Wl % 2 or not sibling:
-            hist5 = build_node_hist(codes_s, node, sw_list, n_bins,
+            hist5 = build_node_hist(codes_h, node, sw_list, n_bins,
                                     n_nodes=Wl).transpose(1, 2, 3, 4, 0)
         else:
             Wh = Wl // 2
-            he5 = build_node_hist(codes_s, node, sw_list, n_bins,
+            he5 = build_node_hist(codes_h, node, sw_list, n_bins,
                                   n_nodes=Wh, stride=2
                                   ).transpose(1, 2, 3, 4, 0)   # slot 2j'
             j_src, is_rch = odd_map_prev
             prev_flat = hist5_prev.reshape(
-                hist5_prev.shape[0], Tb, d * n_bins * k)
+                hist5_prev.shape[0], Tb, dw * n_bins * k)
             src = jnp.take_along_axis(
                 prev_flat.transpose(1, 0, 2),             # (Tb, Wl_prev, ·)
                 j_src.T[:, :, None].astype(jnp.int32), axis=1
-            ).transpose(1, 0, 2).reshape(Wh, Tb, d, n_bins, k)
+            ).transpose(1, 0, 2).reshape(Wh, Tb, dw, n_bins, k)
             odd5 = src - jnp.where(
                 is_rch[:, :, None, None, None], he5,
                 jnp.zeros_like(he5))
             hist5 = jnp.stack([he5, odd5], axis=1).reshape(
-                Wl, Tb, d, n_bins, k)
+                Wl, Tb, dw, n_bins, k)
         hist5_prev = hist5
-        hist = hist5.reshape(M, d, n_bins, k)
+        hist = hist5.reshape(M, dw, n_bins, k)
         cum = jnp.cumsum(hist, axis=2)
         total = cum[:, 0, -1, :]                       # (M, k) node totals
         SL = cum[:, :, :-1, :]
         SR = total[:, None, None, :] - SL
         cfg_m = {key: jnp.tile(v, Wl) for key, v in cfg.items()}
         gain, valid = _split_gain(SL, SR, total, cfg_m, mode)
-        valid = valid & jnp.tile(fmasks, (Wl, 1))[:, :, None]
+        valid = valid & jnp.tile(col_ok, (Wl, 1))[:, :, None]
         gain = jnp.where(valid, gain, -jnp.inf)
-        gflat = gain.reshape(M, d * (n_bins - 1))
+        gflat = gain.reshape(M, dw * (n_bins - 1))
         best = jnp.argmax(gflat, axis=1)
         bf = (best // (n_bins - 1)).astype(jnp.int32)
+        if col_of is not None:           # compact column -> the tree's own
+            bf = jnp.take_along_axis(jnp.tile(col_of, (Wl, 1)), bf[:, None],
+                                     axis=1)[:, 0]
         bb = (best % (n_bins - 1)).astype(jnp.int32)
         bgain = jnp.take_along_axis(gflat, best[:, None], axis=1)[:, 0]
         active = jnp.asarray(level, jnp.float32) < jnp.tile(
@@ -787,10 +835,59 @@ def _fit_dt_batch(X, y, weights, max_depth, min_inst, min_gain, *,
             "edges": edges}
 
 
+def _rf_tree_keys(seed, t):
+    """(bootstrap key, feature-subset key) of tree ``t`` of the forest
+    configuration seeded ``seed``."""
+    base = jax.random.PRNGKey(seed.astype(jnp.uint32))
+    return jax.random.split(jax.random.fold_in(base, t))
+
+
+def _rf_p_feat(d: int, task: str) -> float:
+    """Share of the columns a tree draws (Spark featureSubsetStrategy auto:
+    sqrt for classification, 1/3 for regression)."""
+    return (float(np.ceil(np.sqrt(d)) / d) if task == "classification"
+            else max(1.0 / 3.0, 1.0 / d))
+
+
+def _rf_seeds(B: int) -> np.ndarray:
+    """The forest family's per-lane seeds: host constants, like maxDepth."""
+    return np.arange(B, dtype=np.float32) + 7.0
+
+
+@lru_cache(maxsize=64)
+def _rf_feature_table(B: int, n_trees: int, d: int, p_feat: float):
+    """Every tree's drawn columns for a forest fit of B lanes x n_trees:
+    (fmasks (B, n_trees, d) bool, feat_idx (B, n_trees, d_sub) int32 or
+    None), numpy, drawn ONCE on the host before any trace.
+
+    The subsets are a pure function of (seed, tree, d, p_feat) and the
+    seeds are `_rf_seeds`, so the width of the widest subset is known
+    before the growers are traced: d_sub is that width rounded up to a
+    multiple of 4 (d_sub·32 bin lanes a multiple of 128), an exact bound
+    and not a tail estimate; no tree's column is ever dropped. ``feat_idx``
+    lists each tree's columns ascending, padded with the sentinel d; it is
+    None where d_sub >= d, and the growers then run full width. Masks and
+    index come from this one table: there is no second draw to disagree."""
+    with jax.ensure_compile_time_eval():
+        draw = jax.vmap(lambda seed: jax.vmap(
+            lambda t: jax.random.bernoulli(
+                _rf_tree_keys(seed, t)[1], p_feat, (d,))
+        )(jnp.arange(n_trees)))
+        fmasks = np.asarray(draw(jnp.asarray(_rf_seeds(B))))
+    d_sub = max(4, -(-int(fmasks.sum(-1).max()) // 4) * 4)
+    if d_sub >= d:
+        return fmasks, None
+    cols = np.where(fmasks, np.arange(d, dtype=np.int32), np.int32(d))
+    return fmasks, np.sort(cols, axis=-1)[..., :d_sub]
+
+
 def _rf_config_chunk(B: int, S: int, n_trees: int, depth: int, n_slots: int,
-                     k: int, d: int, n_bins: int) -> int:
-    """Configurations per chunk of ``_fit_rf_batch``'s ``lax.map``."""
+                     k: int, d: int, n_bins: int, d_sub: int = 0) -> int:
+    """Configurations per chunk of ``_fit_rf_batch``'s ``lax.map``.
+    ``d_sub``: the per-tree column width where the growers run compact
+    (`_rf_feature_table`), 0 where they run all d columns."""
     deep = n_slots > 0
+    dw = d_sub or d                      # columns a tree's histogram holds
     # chunk budget covers BOTH the grower's bf16 (S, Tb·nodes) transients
     # and the sweep leaf-stat path's f32 (S, k+1, Tb) A_cols tensor (f32
     # counts double in the bf16-element budget); the capped grower's level
@@ -807,7 +904,12 @@ def _rf_config_chunk(B: int, S: int, n_trees: int, depth: int, n_slots: int,
     # alive across the cumsum/gain chain)
     nodes_w = min(2 ** depth, n_slots) if deep else 2 ** (depth - 1)
     cb = max(1, min(cb, _LEVEL_HIST_ELEMS
-                    // (n_trees * nodes_w * d * n_bins * k)))
+                    // (n_trees * nodes_w * dw * n_bins * k)))
+    if d_sub:
+        # ...AND the compact path's bin one-hot, which every tree has of
+        # its own: (S, trees, d_sub·n_bins) bf16
+        cb = max(1, min(cb, _CFG_CHUNK_ELEMS
+                        // (S * n_trees * d_sub * n_bins)))
     if k > 2:
         # ...AND, with more than two statistic planes (a many-class label),
         # the flat histogram's row-blocked contraction: past the Pallas
@@ -817,40 +919,43 @@ def _rf_config_chunk(B: int, S: int, n_trees: int, depth: int, n_slots: int,
         # for 19.2 GB (6.8 GB of it this one tensor) and the sweep fell down
         # the exhaustion ladder (PR 26). Two planes never bind here.
         cb = max(1, min(cb, _HIST_PARTIAL_ELEMS
-                        // (_hist_shards() * n_trees * nodes_w * d * n_bins
+                        // (_hist_shards() * n_trees * nodes_w * dw * n_bins
                             * k)))
-    return cb
+    # of the sizes the budgets allow (down to half the largest), the one
+    # that grows the fewest padded configurations, the larger on a tie:
+    # 18 lanes in chunks of 5 would grow 20, in chunks of 3 they grow 18
+    return min(range(cb, cb // 2, -1), key=lambda c: -(-B // c) * c)
 
 
 @partial(jax.jit, static_argnames=("depth", "n_bins", "num_classes", "task",
                                    "n_trees", "sweep", "n_slots"))
 def _fit_rf_batch(X, y, weights, max_depth, min_inst, min_gain, num_trees,
-                  subsample, seeds, *, depth, n_bins, num_classes, task,
-                  n_trees, sweep=False, n_slots=0):
+                  subsample, seeds, fmasks, feat_idx=None, *, depth, n_bins,
+                  num_classes, task, n_trees, sweep=False, n_slots=0):
+    """``fmasks`` (B, n_trees, d) and ``feat_idx`` (B, n_trees, d_sub) or
+    None: the per-tree feature subsets of `_rf_feature_table`, one draw per
+    tree for all of its levels."""
     n, d = X.shape
     samp, edges, binned, binned_s, stats, mode, w_scale = \
         _prep_tree_inputs(X, y, n_bins, num_classes, task,
                           full_bin=not sweep, sweep=sweep)
-    # per-tree feature subset (Spark featureSubsetStrategy auto:
-    # sqrt for classification, 1/3 for regression)
-    p_feat = float(np.ceil(np.sqrt(d)) / d) if task == "classification" \
-        else max(1.0 / 3.0, 1.0 / d)
     S = binned_s.shape[0]
     k = stats.shape[1]
     stats_s = stats[samp]
     deep = n_slots > 0
     L = min(2 ** depth, n_slots) if deep else 2 ** depth
     B = weights.shape[0]
-    cb = _rf_config_chunk(B, S, n_trees, depth, n_slots, k, d, n_bins)
+    cb = _rf_config_chunk(B, S, n_trees, depth, n_slots, k, d, n_bins,
+                          0 if feat_idx is None else feat_idx.shape[-1])
 
-    def one_chunk(w_c, md, mi, mg, ss, seed):
+    def one_chunk(w_c, md, mi, mg, ss, seed, fmask_c, fidx_c):
         """Grow a chunk of cb configs — cb·n_trees trees — in one
         tree-batched forest call. Leading axes here are (cb,)."""
         Tb = cb * n_trees
         w_s = w_c[:, samp] * w_scale                        # (cb, S)
+        fidx = None if fidx_c is None else fidx_c.reshape(Tb, -1)
 
         def boots_one(seed_c, ss_c):
-            base = jax.random.PRNGKey(seed_c.astype(jnp.uint32))
             # Poisson(ss) bootstrap weights by inverse-CDF over uniforms,
             # truncated at 7 (P[X>7 | lam<=1] < 1e-6) — 3x cheaper than
             # jax.random.poisson's rejection sampling at these volumes
@@ -861,15 +966,12 @@ def _fit_rf_batch(X, y, weights, max_depth, min_inst, min_gain, num_trees,
             cdf = jnp.cumsum(jnp.exp(log_pmf))
 
             def per_tree(t):
-                k1, k2 = jax.random.split(jax.random.fold_in(base, t))
-                u = jax.random.uniform(k1, (S,))
-                boot = (u[:, None] > cdf[None, :]).sum(-1).astype(X.dtype)
-                fmask = jax.random.bernoulli(k2, p_feat, (d,))
-                return boot, fmask
+                u = jax.random.uniform(_rf_tree_keys(seed_c, t)[0], (S,))
+                return (u[:, None] > cdf[None, :]).sum(-1).astype(X.dtype)
 
             return jax.vmap(per_tree)(jnp.arange(n_trees))
 
-        boots, fmasks = jax.vmap(boots_one)(seed, ss)   # (cb,T,S) (cb,T,d)
+        boots = jax.vmap(boots_one)(seed, ss)               # (cb, T, S)
         # per-tree row weight = config fold weight x bootstrap; flatten the
         # (config, tree) axes into the lane dim: t-major lane = c*T + t
         w_ts = (w_s[:, None, :] * boots).reshape(Tb, S).T   # (S, Tb)
@@ -881,12 +983,13 @@ def _fit_rf_batch(X, y, weights, max_depth, min_inst, min_gain, num_trees,
                "min_child_weight": jnp.zeros((Tb,), jnp.float32)}
         if deep:
             fs, ths, bhs, abs_, node_s = _grow_forest_capped(
-                binned_s, edges, sw_list, fmasks.reshape(Tb, d), cfg,
-                depth=depth, n_bins=n_bins, mode=mode, n_slots=n_slots)
+                binned_s, edges, sw_list, fmask_c.reshape(Tb, d), cfg,
+                depth=depth, n_bins=n_bins, mode=mode, n_slots=n_slots,
+                feat_idx=fidx)
         else:
             fs, ths, bhs, node_s = _grow_forest(
-                binned_s, edges, sw_list, fmasks.reshape(Tb, d), cfg,
-                depth=depth, n_bins=n_bins, mode=mode)
+                binned_s, edges, sw_list, fmask_c.reshape(Tb, d), cfg,
+                depth=depth, n_bins=n_bins, mode=mode, feat_idx=fidx)
             abs_ = jnp.zeros((Tb, 0), jnp.int32)
 
         if sweep:
@@ -918,7 +1021,8 @@ def _fit_rf_batch(X, y, weights, max_depth, min_inst, min_gain, num_trees,
 
     n_chunks = -(-B // cb)
     B_pad = n_chunks * cb
-    args = (weights, max_depth, min_inst, min_gain, subsample, seeds)
+    args = (weights, max_depth, min_inst, min_gain, subsample, seeds,
+            fmasks, feat_idx)
     if B_pad != B:
         idx = jnp.arange(B_pad) % B
         args = jax.tree_util.tree_map(lambda a: a[idx], args)
@@ -1574,8 +1678,6 @@ class RandomForestFamilyBase(_TreeFamilyBase):
         tree_vals = np.asarray(_g(grid, "numTrees", 20.0))
         n_trees = int(tree_vals.max())
         B = weights.shape[0]
-        seeds = jnp.arange(B, dtype=jnp.float32) + 7.0
-        grid = dict(grid, _seeds=seeds)
         if sweep:
             # rank with a capped forest; the winner refits at full numTrees
             # (proportional per-config scaling when the grid sweeps
@@ -1586,13 +1688,22 @@ class RandomForestFamilyBase(_TreeFamilyBase):
                 n_trees = int(capped.max())
                 grid = dict(grid, numTrees=jnp.asarray(capped, jnp.float32))
         n_slots = _SWEEP_SLOTS if sweep else _REFIT_SLOTS
+        # seeds and the per-tree feature subsets they fix: host constants
+        # per lane, grouped by depth with the rest of the grid
+        d = X.shape[1]
+        fmasks, feat_idx = _rf_feature_table(B, n_trees, d,
+                                             _rf_p_feat(d, task))
+        grid = dict(grid, _seeds=jnp.asarray(_rf_seeds(B)), _fmasks=fmasks)
+        if feat_idx is not None:
+            grid["_featIdx"] = feat_idx
 
         def fit_group(g, w, depth, slots=0):
             return _fit_rf_batch(
                 X, y, w, g["maxDepth"],
                 _g(g, "minInstancesPerNode", 1.0), _g(g, "minInfoGain", 0.0),
                 _g(g, "numTrees", 20.0), _g(g, "subsamplingRate", 1.0),
-                g["_seeds"], depth=depth, n_bins=N_BINS,
+                g["_seeds"], g["_fmasks"], g.get("_featIdx"),
+                depth=depth, n_bins=N_BINS,
                 num_classes=max(num_classes, 2), task=task, n_trees=n_trees,
                 sweep=sweep, n_slots=slots)
 
@@ -1604,12 +1715,18 @@ class RandomForestFamilyBase(_TreeFamilyBase):
         """``configChunks``: how many chunks of configurations the fit's
         ``lax.map``s run over its depth groups, by the rules of
         ``fit_batch``, ``_fit_depth_grouped`` and ``_rf_config_chunk``
-        (tests/test_multiclass_support.py holds them to the traced count)."""
+        (tests/test_multiclass_support.py holds them to the traced count);
+        ``featSubset``: the per-tree column width the growers run compact
+        at (`_rf_feature_table`), 0 where they run all ``features``."""
         if any("maxDepth" not in g for g in grid):
             return {}
         n_trees = int(max(g.get("numTrees", 20.0) for g in grid))
         if sweep:
             n_trees = min(n_trees, _SWEEP_RF_TREES)
+        feat_idx = _rf_feature_table(
+            len(grid), n_trees, features,
+            _rf_p_feat(features, self._task(num_classes)))[1]
+        d_sub = 0 if feat_idx is None else feat_idx.shape[-1]
         S = min(rows, _SWEEP_HIST_SAMPLE if sweep else _HIST_SAMPLE)
         k = (max(num_classes, 2)
              if self._task(num_classes) == "classification" else 3)
@@ -1624,9 +1741,9 @@ class RandomForestFamilyBase(_TreeFamilyBase):
             B = depths.count(u)
             cb = _rf_config_chunk(B, S, n_trees, u,
                                   slots if u > _MAX_HEAP_DEPTH else 0, k,
-                                  features, N_BINS)
+                                  features, N_BINS, d_sub)
             chunks += -(-B // cb)
-        return {"configChunks": chunks}
+        return {"configChunks": chunks, "featSubset": d_sub}
 
     def predict_batch(self, params, X, num_classes):
         edges = self._edges_of(params)
