@@ -81,3 +81,24 @@ def test_the_softmax_refit_compiles_for_the_chip_at_the_cells_size(one_chip):
             shape((1,), jnp.float32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
     assert "while" in compiled.as_text()       # the schedule stays a loop
+
+
+@pytest.mark.parametrize("fit,lanes", [("linear", 18), ("glm", 24)])
+def test_the_moment_based_sweep_fits_compile_for_the_chip_at_the_cells_size(
+        one_chip, fit, lanes):
+    """``train-nyctaxi``'s linear and generalised-linear sweeps: 18 and 24
+    lanes over the selector's 3 600 000 rows (bucket 4 194 304) x 30
+    columns. Before PR 30 every lane held its own standardised copy of the
+    matrix (8 GB a temporary); from moments the chip's compiler is asked for
+    a block's outer products and little else."""
+    from transmogrifai_tpu.models import glm, linear
+    n, d = 4194304, 30
+
+    def shape(dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+    fn = linear._fit_linreg_batch if fit == "linear" else glm._fit_glm_batch
+    grid = [shape((lanes,))] * (2 if fit == "linear" else 3)
+    compiled = fn.lower(shape((n, d)), shape((n,)), shape((lanes, n)),
+                        *grid).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert "while" in compiled.as_text()       # the row blocks stay a loop

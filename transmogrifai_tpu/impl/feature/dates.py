@@ -27,6 +27,7 @@ from .vectorizers import _VectorModelBase
 #: period → (extractor over epoch-ms int64 array, cardinality, offset)
 #: matches the reference's TimePeriod enum (joda semantics: Monday=1)
 _DAY_MS = 86_400_000
+_HOUR_MS = 3_600_000
 
 
 def _dt_parts(ms: np.ndarray) -> Dict[str, np.ndarray]:
@@ -37,7 +38,7 @@ def _dt_parts(ms: np.ndarray) -> Dict[str, np.ndarray]:
     day_of_month = (days - months.astype("datetime64[D]")).astype(np.int64) + 1
     day_of_year = (days - years.astype("datetime64[D]")).astype(np.int64) + 1
     return {
-        "HourOfDay": (ms // 3_600_000) % 24,
+        "HourOfDay": (ms // _HOUR_MS) % 24,
         "DayOfWeek": ((days.astype(np.int64) + 3) % 7) + 1,  # 1970-01-01 = Thu
         "DayOfMonth": day_of_month,
         "DayOfYear": day_of_year,
@@ -69,6 +70,29 @@ def unit_circle(values: np.ndarray, period: str) -> np.ndarray:
     spec = TIME_PERIODS[period]
     radians = 2.0 * np.pi * (values - spec["offset"]) / spec["period"]
     return np.stack([np.sin(radians), np.cos(radians)], axis=1).astype(np.float32)
+
+
+def unit_circle_block(ms: np.ndarray, periods: Sequence[str]) -> np.ndarray:
+    """(n, 2 * len(periods)) float32: ``[sin, cos]`` of every period of the
+    epoch-ms column, side by side. Every period is a function of the hour a
+    timestamp falls in, so where the column's hours span far fewer values
+    than it has rows (a month of trips: 744 hours under millions of rows)
+    the encodings are computed once an hour and the rows look theirs up:
+    one integer division and one gather a column, the same numbers to the
+    bit."""
+    def direct(ms):
+        return np.concatenate([unit_circle(time_period_values(ms, p), p)
+                               for p in periods], axis=1)
+    n = len(ms)
+    if n == 0 or not periods:
+        return np.zeros((n, 2 * len(periods)), dtype=np.float32)
+    hours = ms // _HOUR_MS
+    lo, hi = int(hours.min()), int(hours.max())
+    if hi - lo >= n // 4:
+        return direct(ms)
+    table = direct(np.arange(lo, hi + 1, dtype=np.int64) * _HOUR_MS)
+    hours -= lo
+    return np.take(table, hours, axis=0)
 
 
 class TimePeriodTransformer(UnaryTransformer):
@@ -195,10 +219,10 @@ class DateToUnitCircleTransformer(SequenceTransformer):
             col = table[f.name]
             ms = np.asarray(col.values, dtype=np.int64)
             m = col.valid_mask()
+            block = unit_circle_block(ms, self.periods)
+            block[~m] = 0.0
+            blocks.append(block)
             for period in self.periods:
-                block = unit_circle(time_period_values(ms, period), period)
-                block[~m] = 0.0
-                blocks.append(block)
                 meta.extend([
                     VectorColumnMetadata(f.name, f.type_name, f.name, None,
                                          descriptor_value=f"{period}_sin"),

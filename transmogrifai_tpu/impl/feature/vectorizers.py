@@ -14,6 +14,7 @@ reference: mean/mode fill + a tracked null-indicator column per feature.
 """
 from __future__ import annotations
 
+import weakref
 import zlib
 from collections import Counter
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -389,14 +390,43 @@ def _top_levels(counts: Dict[str, int], min_support: int, top_k: int
     return sorted(top, key=lambda v: (-counts[v], v))[:top_k]
 
 
+#: the codes a fit's count pass made of a column, until the transform of the
+#: same array takes them: ``id(values) -> (weakref to the values, mask,
+#: codes, levels, path)``. ``train()`` fits a pivot and then transforms the
+#: table it was fitted on, so the column is hashed once and not twice. Every
+#: fit overwrites its column's entry and the first transform of that array
+#: removes it, so nothing is carried from one train to the next or to a
+#: score; an entry whose array has died goes with it.
+_FIT_CODES: Dict[int, Tuple[Any, np.ndarray, np.ndarray, List[str], str]] = {}
+_FIT_CODES_MAX = 64
+
+
+def _keep_fit_codes(vals: np.ndarray, m: np.ndarray, codes: np.ndarray,
+                    levels: List[str], path: str) -> None:
+    key = id(vals)
+    try:
+        ref = weakref.ref(vals, lambda _: _FIT_CODES.pop(key, None))
+    except TypeError:
+        return
+    while len(_FIT_CODES) >= _FIT_CODES_MAX:
+        _FIT_CODES.pop(next(iter(_FIT_CODES)), None)
+    _FIT_CODES[key] = (ref, m, codes, levels, path)
+
+
 def _encode_valid(vals: np.ndarray, m: np.ndarray, index: Dict[str, int]
                   ) -> Tuple[np.ndarray, str]:
     """Each row's vocabulary index under ``index`` (-1 for a level not in
     it, -2 where ``m`` is false) and the path ``_factorize_valid`` took:
-    the dictionary is asked once per level, not once per row."""
-    codes, counts, path = _factorize_valid(vals, m)
+    the dictionary is asked once per level, not once per row. The codes are
+    the fit's own where this is the array (and the mask) it counted."""
+    kept = _FIT_CODES.pop(id(vals), None)
+    if kept is not None and kept[0]() is vals and np.array_equal(kept[1], m):
+        _, _, codes, levels, path = kept
+    else:
+        codes, counts, path = _factorize_valid(vals, m)
+        levels = list(counts)
     # the last entry is the one the null rows' -1 reaches
-    lut = np.array([index.get(v, -1) for v in counts] + [-2], dtype=np.int64)
+    lut = np.array([index.get(v, -1) for v in levels] + [-2], dtype=np.int64)
     return lut[codes], path
 
 
@@ -427,7 +457,8 @@ class OneHotVectorizer(Estimator):
                     cnt = Counter(v for vs, ok in zip(vals, m) if ok
                                   for v in (vs or ()))
                 else:
-                    _, cnt, path = _factorize_valid(vals, m)
+                    codes, cnt, path = _factorize_valid(vals, m)
+                    _keep_fit_codes(vals, m, codes, list(cnt), path)
                     count_span.set_attr(path=path)
                 vocabs.append(_top_levels(cnt, self.min_support, self.top_k))
                 count_span.set_attr(levels=len(cnt))

@@ -4,10 +4,11 @@ linear SVC, naive Bayes.
 TPU-native replacements for the reference's SparkML wrappers
 (reference: core/.../impl/classification/OpLogisticRegression.scala,
 OpLinearSVC.scala, OpNaiveBayes.scala, impl/regression/OpLinearRegression.scala).
-Each family fits its whole hyperparameter × fold batch in ONE jitted, vmapped
-XLA program: the inner loop is prox-Newton / closed-form solves built from
-(n,d)ᵀ(n,d) MXU matmuls, and per-configuration 0/1 row-weight vectors express
-CV folds without reshaping data.
+Each family fits its whole hyperparameter × fold batch in ONE jitted XLA
+program over the ONE shared feature matrix: the inner loop is prox-Newton /
+moment-based least-squares solves built from (n,d)ᵀ(n,d) MXU matmuls, lanes
+appear only in (n, lanes) or (lanes, d, d) operands, and per-configuration
+0/1 row-weight vectors express CV folds without reshaping data.
 
 Conventions (matching Spark ML so reference grids transfer):
 * objective = mean loss + regParam * (α·‖w‖₁ + (1-α)/2·‖w‖₂²), bias unpenalized
@@ -26,35 +27,6 @@ import numpy as np
 from .api import FittedParams, ModelFamily, register_family
 
 _PREC = jax.lax.Precision.HIGHEST
-
-
-def _standardize(X: jnp.ndarray, w: jnp.ndarray):
-    """Weighted feature standardization; returns (Xs, mean, scale).
-
-    Columns constant within the weighted rows get a huge scale (Xs ≈ 0,
-    coefficient pinned at 0) instead of 1/sqrt(noise) — same dead-column
-    guard as _BatchStd, or the unscale step amplifies rounding noise 1e6x."""
-    cnt = jnp.maximum(w.sum(), 1.0)
-    mean = (X * w[:, None]).sum(0) / cnt
-    var = ((X - mean) ** 2 * w[:, None]).sum(0) / cnt
-    # dead = EXACTLY constant within the weighted rows (weighted range 0) —
-    # matches Spark zeroing only zero-variance columns. An informative column
-    # whose natural scale is tiny (std 1e-4 → var 1e-8) or whose offset is
-    # huge (epoch-millis: var/ex2 ~ 1e-10) must NOT be pinned to 0, so no
-    # variance threshold can be used here; the range test is exact
-    active = w[:, None] > 0
-    hi = jnp.where(active, X, -jnp.inf).max(0)
-    lo = jnp.where(active, X, jnp.inf).min(0)
-    dead = hi <= lo
-    scale = jnp.where(dead, 1e30, jnp.sqrt(jnp.maximum(var, 1e-30)))
-    return (X - mean) / scale, mean, scale
-
-
-def _unscale(coef_s: jnp.ndarray, bias_s: jnp.ndarray, mean: jnp.ndarray,
-             scale: jnp.ndarray):
-    coef = coef_s / scale
-    bias = bias_s - (coef * mean).sum()
-    return coef, bias
 
 
 # ---------------------------------------------------------------------------
@@ -546,42 +518,249 @@ def _fit_softmax_batch(X, y_idx, W_rows, reg, elastic_net, num_classes,
 
 
 # ---------------------------------------------------------------------------
-# Linear / ridge regression — closed form + ISTA refinement for L1
+# Linear / ridge / elastic-net regression: weighted least squares from
+# moments (the shape of Spark's WeightedLeastSquares)
+#
+# A least-squares fit needs its rows once: every lane's weighted second
+# moments of [x, 1, y] come from row-block passes over the ONE shared matrix
+# (no lane holds a copy of a row), standardisation is algebra on them, and
+# every point of the grid is then solved on its lane's (d, d) system. Lanes
+# appear only as the (lanes, rows-of-a-block) weights of one contraction.
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("l1_iters",))
-def _fit_linreg(X, y, w, reg, elastic_net, l1_iters=60):
+#: elements of one row block's (rows, width^2) outer-product temporary in a
+#: moment pass: 128 MiB of float32, whatever the lanes and the row count
+_GRAM_BLOCK_ELEMS = 2 ** 25
+
+#: proximal-gradient steps of one solve of an elastic-net point on its
+#: (d, d) system, power-iteration steps for the Lipschitz constant of its
+#: gradient, and refinement passes (each solves every point once more). From
+#: this sandbox's CPU against the float64 optimum on 200 000 rows of the
+#: taxi table, whose one-hot groups make the Gram matrix singular so that
+#: the ridge part (regParam / 2) alone bounds the curvature from below
+#: (PR 30, standardised coefficients, regParam 0.001 / 0.01 / 0.1 at
+#: elasticNetParam 0.5): 30 steps 4e-1 / 1e-1 / 2e-4, 100 steps 1e-1 /
+#: 2e-4 / 4e-7, 300 steps 2e-4 / 2e-5 / 3e-6 and no better at 1 000, 3 000
+#: or 10 000 (float32's floor: 1e-4 to 1e-3 at the smallest penalty). Twice
+#: the 300 is the schedule. Without a refinement pass the ridge point
+#: (0.001, 0) reads 2e-3, with one 2e-4, with two 3e-5, with three 2e-4
+_ENET_STEPS = 600
+_POWER_STEPS = 64
+_REFINE_PASSES = 2
+
+
+def gram_block_rows(n: int, width: int) -> int:
+    """Rows of one block of a moment pass over ``n`` rows whose augmented
+    row is ``width`` wide: the largest power of two whose (rows, width^2)
+    outer-product temporary stays under ``_GRAM_BLOCK_ELEMS`` (at least 8),
+    or all ``n`` rows where they fit in one."""
+    r = max(_GRAM_BLOCK_ELEMS // (width * width), 8)
+    return min(1 << (r.bit_length() - 1), n)
+
+
+def for_row_blocks(n: int, rows: int, body, init):
+    """``body(carry, start, live)`` folded over the blocks of ``rows`` rows
+    that cover ``0..n``: ``start`` is where the block begins and ``live``
+    (rows,) marks the rows it owns. The last block is moved back to end at
+    ``n`` (its rows that an earlier block owned are not live), so nothing is
+    padded or copied."""
+    def step(i, carry):
+        start = jnp.minimum(i * rows, n - rows)
+        live = start + jnp.arange(rows) >= i * rows
+        return body(carry, start, live)
+    return jax.lax.fori_loop(0, -(-n // rows), step, init)
+
+
+def global_affine(X):
+    """(mean, scale) of every column over all rows: the one affine map the
+    moment passes read the shared matrix through, so that float32 sums of
+    squares do not cancel (a longitude of -73.97 +- 0.04). Lanes
+    standardise on their own rows by algebra on the moments of the mapped
+    columns; a column that is constant maps to 0."""
+    return X.mean(axis=0), jnp.sqrt(jnp.maximum(X.var(axis=0), 1e-12))
+
+
+def two_lanes(*lane_arrays):
+    """A single lane, fitted twice side by side. With a lane axis of one the
+    moment contractions take the backend's matrix-vector path, whose float32
+    accumulation over a block's rows is sequential on the CPU (5e-4 of a
+    second moment at 200 000 rows, against 3e-6 from the blocked
+    matrix-matrix path)."""
+    return tuple(jnp.concatenate([a, a]) for a in lane_arrays)
+
+
+def _lane_moments(X, y, W, g_mean, g_scale, y_mean):
+    """(B, d+2, d+2) weighted second moments ``sum_rows w z z'`` of
+    ``z = [xg, 1, y - y_mean]`` for every lane's weights ``W`` (B, n), in
+    one pass over the rows: float32 accumulation at HIGHEST, the lanes as
+    one operand of the contraction."""
     n, d = X.shape
-    Xs, mean, scale = _standardize(X, w)
-    cnt = jnp.maximum(w.sum(), 1.0)
+    D = d + 2
+    rows = gram_block_rows(n, D)
+    slice_rows = partial(jax.lax.dynamic_slice_in_dim, slice_size=rows)
+
+    def body(M, start, live):
+        xb = (slice_rows(X, start) - g_mean) / g_scale
+        z = jnp.concatenate(
+            [xb, jnp.ones((rows, 1), X.dtype),
+             (slice_rows(y, start) - y_mean)[:, None]], axis=1)
+        outer = (z[:, :, None] * z[:, None, :]).reshape(rows, D * D)
+        wb = slice_rows(W, start, axis=1) * live
+        return M + jnp.dot(wb, outer, precision=_PREC)
+
+    M = for_row_blocks(n, rows, body,
+                       jnp.zeros((W.shape[0], D * D), X.dtype))
+    return M.reshape(W.shape[0], D, D)
+
+
+def _moment_std(M):
+    """Per-lane standardisation from the moments of ``_lane_moments``:
+    ``(cnt (B,), mean (B, d), scale (B, d), y_bar (B,))``. A column that is
+    constant within a lane's weighted rows gets a huge scale (coefficient
+    pinned at 0, Spark's zero-variance rule) by ``_BatchStd``'s test:
+    relative to its second moment, because one-pass cancellation noise is
+    eps times that."""
+    d = M.shape[1] - 2
+    cnt = jnp.maximum(M[:, d, d], 1.0)
+    mean = M[:, :d, d] / cnt[:, None]
+    ex2 = jnp.diagonal(M[:, :d, :d], axis1=1, axis2=2) / cnt[:, None]
+    var_raw = ex2 - mean ** 2
+    dead = var_raw < jnp.maximum(1e-6 * ex2, 1e-10)
+    scale = jnp.where(dead, 1e30, jnp.sqrt(jnp.maximum(var_raw, 1e-12)))
+    return cnt, mean, scale, M[:, d + 1, d] / cnt
+
+
+def _residual_moments(X, y, W, g_mean, g_scale, y_mean, coef_g, bias_g):
+    """(B, d+1) ``sum_rows w r [xg, 1]`` of the residuals
+    ``r = y - y_mean - xg'coef_g - bias_g`` of every lane's fit, in one
+    pass over the rows. Sums of residuals do not carry the label's and the
+    coefficients' size as the second moments do, so their float32 rounding
+    is that much smaller: the fit is corrected with them."""
+    n, d = X.shape
+    rows = gram_block_rows(n, d + 2)
+    slice_rows = partial(jax.lax.dynamic_slice_in_dim, slice_size=rows)
+
+    def body(S, start, live):
+        xb = (slice_rows(X, start) - g_mean) / g_scale
+        r = ((slice_rows(y, start) - y_mean)[None, :] - bias_g[:, None]
+             - jnp.dot(coef_g, xb.T, precision=_PREC))            # (B, rows)
+        wr = slice_rows(W, start, axis=1) * live * r
+        xa = jnp.concatenate([xb, jnp.ones((rows, 1), X.dtype)], axis=1)
+        return S + jnp.dot(wr, xa, precision=_PREC)
+
+    return for_row_blocks(n, rows, body,
+                          jnp.zeros((W.shape[0], d + 1), X.dtype))
+
+
+def _largest_eigenvalue(C):
+    """(B,) largest eigenvalue of every lane's positive semi-definite C
+    (B, d, d) by ``_POWER_STEPS`` power-iteration steps, with a twentieth
+    of room: the Lipschitz constant of the quadratic's gradient."""
+    d = C.shape[-1]
+
+    def power(v, _):
+        v = jnp.einsum("bij,bj->bi", C, v, precision=_PREC)
+        return v / jnp.maximum(jnp.linalg.norm(v, axis=1, keepdims=True),
+                               1e-30), None
+
+    v0 = jnp.broadcast_to(1.0 + jnp.arange(d, dtype=C.dtype) / d,
+                          C.shape[:2])
+    v, _ = jax.lax.scan(power, v0 / jnp.linalg.norm(v0[0]), None,
+                        length=_POWER_STEPS)
+    return 1.05 * (v * jnp.einsum("bij,bj->bi", C, v,
+                                  precision=_PREC)).sum(axis=1)
+
+
+def _solve_points(C, c, l1, l2, top, a0=None):
+    """Every lane's minimiser of ``a'Ca/2 - c'a + l2/2 |a|^2 + l1 |a|_1``
+    (C (B, d, d) positive semi-definite with largest eigenvalue ``top``,
+    the rest (B, d) or (B,)). Without an L1 term it is the (d, d) solve.
+    With one: ``_ENET_STEPS`` accelerated proximal-gradient steps from
+    ``a0`` (else from that solve) at the step 1 / (top + l2)."""
+    d = C.shape[-1]
+    ridge = jnp.linalg.solve(
+        C + (l2 + 1e-8)[:, None, None] * jnp.eye(d, dtype=C.dtype),
+        c[:, :, None])[:, :, 0]
+    mv = lambda v: jnp.einsum("bij,bj->bi", C, v, precision=_PREC)
+    lips = top + l2 + 1e-8
+    step = (1.0 / lips)[:, None]
+    thresh = step * l1[:, None]
+
+    # the ridge part makes the objective strongly convex (modulus l2), so
+    # the momentum is the constant that gives the rate 1 - sqrt(l2 / L) a
+    # step and needs no restart test (in float32 a restart test fires on
+    # rounding and stalls the weak directions); a pure L1 point (l2 = 0)
+    # runs at the momentum of modulus L / 10 000
+    q = jnp.sqrt(jnp.maximum(l2 / lips, 1e-4))
+    mom = ((1.0 - q) / (1.0 + q))[:, None]
+
+    def fista(carry, _):
+        a, u = carry
+        w = u - step * (mv(u) + l2[:, None] * u - c)
+        a_new = jnp.sign(w) * jnp.maximum(jnp.abs(w) - thresh, 0.0)
+        return (a_new, a_new + mom * (a_new - a)), None
+
+    start = ridge if a0 is None else a0
+    (a, _), _ = jax.lax.scan(fista, (start, start), None,
+                             length=_ENET_STEPS)
+    return jnp.where(l1[:, None] > 0, a, ridge)
+
+
+@jax.jit
+def _fit_linreg_batch(X, y, W, reg, elastic_net):
+    """Fit B linear regressions at once. W: (B, n) per-lane row weights;
+    reg / elastic_net: (B,). Returns (coef (B, d), bias (B,)) in the
+    features' own scale.
+
+    Objective (Spark ML's, features standardised on the lane's weighted
+    rows, label as it is, intercept free and unpenalised):
+    ``mean_w (y - x_s'a - b)^2 / 2 + reg (alpha |a|_1 + (1 - alpha)/2
+    |a|^2)``. Two passes over the rows for the one global affine map, ONE
+    for every lane's moments; a ridge point is then a (d, d) solve, a point
+    with an L1 term runs proximal gradient on the same system to its
+    optimum. ``_REFINE_PASSES`` more passes take the fits' residual moments
+    and every point is solved again with them in the cross moments' place
+    (iterative refinement: what float32 lost in the second moments, along
+    the directions that one-hot groups leave to the penalty alone, comes
+    back)."""
+    if W.shape[0] == 1:
+        coef, bias = _fit_linreg_batch(X, y, *two_lanes(W, reg, elastic_net))
+        return coef[:1], bias[:1]
+    g_mean, g_scale = global_affine(X)
+    y_mean = y.mean()
+    M = _lane_moments(X, y, W, g_mean, g_scale, y_mean)
+    d = X.shape[1]
+    cnt, mean, scale, y_bar = _moment_std(M)
+    C = ((M[:, :d, :d] / cnt[:, None, None]
+          - mean[:, :, None] * mean[:, None, :])
+         / (scale[:, :, None] * scale[:, None, :]))
+    c = (M[:, :d, d + 1] / cnt[:, None] - mean * y_bar[:, None]) / scale
     l2 = reg * (1.0 - elastic_net)
     l1 = reg * elastic_net
-    Xa = jnp.concatenate([Xs, jnp.ones((n, 1), X.dtype)], axis=1)
-    A = jnp.einsum("ni,nj->ij", Xa * w[:, None], Xa, precision=_PREC) / cnt
-    A = A + jnp.diag(jnp.concatenate([jnp.full((d,), l2), jnp.zeros((1,))])) \
-        + 1e-8 * jnp.eye(d + 1, dtype=X.dtype)
-    rhs = (Xa * (w * y)[:, None]).sum(0) / cnt
-    theta = jnp.linalg.solve(A, rhs)
-
-    # ISTA refinement handles the L1 part (no-op when l1 == 0)
-    lips = jnp.trace(A)  # cheap Lipschitz upper bound for the quadratic part
-    step_sz = 1.0 / jnp.maximum(lips, 1e-6)
-
-    def ista(theta, _):
-        grad = A @ theta - rhs
-        t = theta - step_sz * grad
-        coef = jnp.sign(t[:d]) * jnp.maximum(jnp.abs(t[:d]) - step_sz * l1, 0.0)
-        return jnp.concatenate([coef, t[d:]]), None
-
-    theta = jax.lax.cond(
-        l1 > 0,
-        lambda th: jax.lax.scan(ista, th, None, length=l1_iters)[0],
-        lambda th: th, theta)
-    coef, bias = _unscale(theta[:d], theta[d], mean, scale)
+    top = _largest_eigenvalue(C)
+    a = _solve_points(C, c, l1, l2, top)
+    r_bar = jnp.zeros_like(y_bar)
+    for _ in range(_REFINE_PASSES):
+        bias_g = y_bar + r_bar - (a / scale * mean).sum(axis=1)
+        S = _residual_moments(X, y, W, g_mean, g_scale, y_mean, a / scale,
+                              bias_g)
+        r_bar = r_bar + S[:, d] / cnt
+        c_fix = (jnp.einsum("bij,bj->bi", C, a, precision=_PREC)
+                 + (S[:, :d] - mean * S[:, d:]) / (cnt[:, None] * scale))
+        a = _solve_points(C, c_fix, l1, l2, top, a)
+    coef_g = a / scale
+    coef = coef_g / g_scale
+    bias = (y_bar + r_bar + y_mean - (coef_g * mean).sum(axis=1)
+            - (coef * g_mean).sum(axis=1))
     return coef, bias
 
 
-_fit_linreg_batch = jax.jit(jax.vmap(_fit_linreg, in_axes=(None, None, 0, 0, 0)))
+def _fit_linreg(X, y, w, reg, elastic_net):
+    """Single-config fit: the B=1 slice of the batched solver."""
+    coef, bias = _fit_linreg_batch(
+        X, y, w[None, :], jnp.asarray([reg], X.dtype),
+        jnp.asarray([elastic_net], X.dtype))
+    return coef[0], bias[0]
 
 
 class LinearRegressionFamily(ModelFamily):
@@ -603,12 +782,25 @@ class LinearRegressionFamily(ModelFamily):
             X, y, weights, grid["regParam"], grid["elasticNetParam"])
         return {"coef": coef, "bias": bias}
 
+    def fit_span_attrs(self, rows, features, grid, num_classes, sweep):
+        # the global affine map reads the rows twice (mean, deviation), the
+        # moments once, the residual moments once; the small solver runs,
+        # before and after the correction, only where a lane has an L1 term
+        l1 = any(g.get("regParam", 0) * g.get("elasticNetParam", 0) > 0
+                 for g in grid)
+        return {"gramPasses": 3 + _REFINE_PASSES,
+                "solveSteps": (1 + _REFINE_PASSES) * _ENET_STEPS if l1 else 0}
+
     def predict_batch(self, params, X, num_classes):
         return jnp.einsum("bd,nd->bn", params["coef"], X, precision=_PREC) \
             + params["bias"][:, None]
 
     def predict_parts(self, fitted: FittedParams, X):
-        pred = X @ jnp.asarray(fitted.params["coef"]) + fitted.params["bias"]
+        # a real (n,d)@(d,) product: at the chip's default precision its
+        # bfloat16 pass moves a fare by cents (PR 26 found the same for the
+        # multiclass probabilities)
+        pred = jnp.dot(X, jnp.asarray(fitted.params["coef"]),
+                       precision=_PREC) + fitted.params["bias"]
         return {"prediction": pred}
 
     def predict_one(self, fitted: FittedParams, X) -> Dict[str, np.ndarray]:

@@ -46,6 +46,7 @@ docs/observability.md "Compile & memory ledger").
 """
 from __future__ import annotations
 
+import hashlib
 import logging
 import os
 import time
@@ -214,6 +215,36 @@ class _DeviceSegment:
             return tuple(env[nm] for nm in outs)
 
         self.chain = chain
+
+
+#: executables of train-time segments by the text of their lowered program
+_TRAIN_SEGMENT_PROGRAMS: "OrderedDict[str, Any]" = OrderedDict()
+_TRAIN_SEGMENT_PROGRAMS_MAX = 64
+
+
+def _train_segment_program(chain, vals, masks) -> Tuple[Any, bool]:
+    """The executable of a train-time segment's ``chain`` at these
+    arguments, and whether an earlier train had already built it.
+
+    A train-time layer's stages are new objects at every ``train()``, so
+    their plan, its segments and the jitted ``chain`` are new as well: jit's
+    own cache cannot find last train's executable, and the same program was
+    compiled again inside every train of the same table (PR 30 found it
+    where a Real and an Integral vectorizer share a layer). The lowered
+    program's text says all that the executable depends on (the stages'
+    classes, their fitted fills, the shapes and shardings; no uid), so the
+    executable is kept under its hash, at most
+    ``_TRAIN_SEGMENT_PROGRAMS_MAX`` of them."""
+    lowered = chain.lower(vals, masks)
+    key = hashlib.sha256(lowered.as_text().encode()).hexdigest()
+    prog = _TRAIN_SEGMENT_PROGRAMS.get(key)
+    if prog is not None:
+        _TRAIN_SEGMENT_PROGRAMS.move_to_end(key)
+        return prog, True
+    prog = _TRAIN_SEGMENT_PROGRAMS[key] = lowered.compile()
+    while len(_TRAIN_SEGMENT_PROGRAMS) > _TRAIN_SEGMENT_PROGRAMS_MAX:
+        _TRAIN_SEGMENT_PROGRAMS.popitem(last=False)
+    return prog, False
 
 
 class TransformPlan:
@@ -403,12 +434,17 @@ class TransformPlan:
                 seg.aot_progs[n_pad] = aot_fn
         pre_stats = _devicemem.memory_stats()
         t_disp = time.perf_counter()
+        reused = False
         with _obs_span("plan.segment", cat=self.cat,
                        stages=len(seg.stages), rows=n,
                        inputs=len(seg.in_names), outputs=len(seg.out_names),
                        aot=aot_fn is not None):
-            outs = (aot_fn or seg.chain)(tuple(vals_list),
-                                         tuple(mask_list))
+            run = aot_fn or seg.chain
+            if aot_fn is None and first_bucket and self.cat == "train":
+                run, reused = _train_segment_program(
+                    seg.chain, tuple(vals_list), tuple(mask_list))
+                seg.aot_progs[n_pad] = run
+            outs = run(tuple(vals_list), tuple(mask_list))
         disp_secs = time.perf_counter() - t_disp
         post_stats = _devicemem.sample_measured(subsystem)
         # cost bytes: measured allocation delta where the backend reports
@@ -422,8 +458,9 @@ class TransformPlan:
         if first_bucket:
             seg_ident = f"{self.ident}/seg{seg_idx}"
             seg.seen_buckets.add(n_pad)
-            if aot_fn is not None:
-                # AOT hit: nothing was traced — no ledger build. The
+            if aot_fn is not None or reused:
+                # AOT hit (or an earlier train's executable of the same
+                # program): nothing was compiled — no ledger build. The
                 # dispatch still lands a cost row (execute side) so the
                 # admission table stays warm.
                 _devicemem.record_cost(seg_fp, n_pad, cost_bytes,
