@@ -17,14 +17,18 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -102,3 +106,98 @@ def test_the_moment_based_sweep_fits_compile_for_the_chip_at_the_cells_size(
                         *grid).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
     assert "while" in compiled.as_text()       # the row blocks stay a loop
+
+
+# The histogram engine's pinned contraction at one production shape each:
+# a boosted-trees level of ``train-airline`` (8 192 sampled rows, 105
+# columns x 32 bins, k.Wl.T_pad = 3 072 stat columns) through
+# `_hist_xla_pinned`, and a forest chunk's level of the same cell (48 trees
+# with 24 drawn columns each, 2 class planes x 16 slots) through
+# `_node_hist_xla_per_tree`.
+_S, _NB = 8192, 32
+
+
+def _pinned_hist(shape):
+    from transmogrifai_tpu.histeng import kernels
+    d, B = 105, 3072
+    args = (shape((_S, d), jnp.int32), shape((_S, B), jnp.float32))
+    sizes = {"partials": 8 * B * d * _NB * 4, "one_hot": _S * d * _NB * 2,
+             "stats": _S * B * 2}
+    return (lambda c, a: kernels._hist_xla_pinned(c, a, _NB)), args, sizes
+
+
+def _per_tree_hist(shape):
+    from transmogrifai_tpu.histeng import kernels
+    T, dw, k, Wl = 48, 24, 2, 16
+    args = (shape((_S, T, dw), jnp.int32), shape((_S, T), jnp.int32),
+            *[shape((_S, T), jnp.float32)] * k)
+    # the tree-batched contraction wants the one-hot in another layout:
+    # the compiler keeps three buffers of its size (strided form the same)
+    sizes = {"partials": 8 * T * k * Wl * dw * _NB * 4,
+             "one_hot": 3 * _S * T * dw * _NB * 2,
+             "stats": _S * T * k * Wl * 2}
+    return (lambda c, n, *sw: kernels._node_hist_xla_per_tree(
+        c, n, list(sw), Wl, _NB, 1)), args, sizes
+
+
+@pytest.mark.parametrize("piece", [_pinned_hist, _per_tree_hist])
+def test_the_pinned_combine_is_one_fused_pass_on_the_chip(one_chip, piece):
+    """On one device `_tree_combine` takes static slices of the K partials
+    and the chip's compiler makes one elementwise pass of it: no ``gather``
+    and no ``dynamic-update-slice`` (the strided slices it replaced in PR 31
+    lowered to four and to 212 update loops at the first shape), and no
+    temporary beyond the partials and the contraction's two bfloat16
+    operands, the bin one-hot and the masked statistics."""
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    fn, args, sizes = piece(shape)
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert " gather(" not in text
+    assert " dynamic-update-slice(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= sum(
+        sizes.values())
+
+
+def _all_reduce_group_sizes(text):
+    """The replica-group size of every ``all-reduce`` of an optimized
+    module, in either spelling: ``{{0,1},{2,3}}`` or ``[2,2]<=[4]``."""
+    sizes = []
+    for line in text.splitlines():
+        if not re.search(r" all-reduce(-start)?\(", line):
+            continue
+        listed = re.search(r"replica_groups=\{(\{[\d,]+\}(?:,\{[\d,]+\})*)\}",
+                           line)
+        iota = re.search(r"replica_groups=\[(\d+),(\d+)\]<=", line)
+        if listed:
+            sizes += [g.count(",") + 1
+                      for g in re.findall(r"\{([\d,]+)\}", listed.group(1))]
+        elif iota:
+            sizes.append(int(iota.group(2)))
+        else:       # empty groups: every device of the program
+            sizes.append(-1)
+    return sizes
+
+
+@pytest.mark.parametrize("piece", [_pinned_hist, _per_tree_hist])
+def test_the_mesh_combine_sums_two_operands_a_step(topo, piece):
+    """Under an engine mesh (rows on 'data' over the topology's four chips)
+    `_tree_combine` halves the array itself, so every cross-device round is
+    an all-reduce over PAIRS of devices: a sum of two terms, whose result
+    no reduction order can change. The fused spelling would be local sums
+    and one all-reduce over all four there, in an order the hardware picks."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from transmogrifai_tpu import histeng
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+
+    def shape(dims, dtype):
+        spec = PartitionSpec("data", *[None] * (len(dims) - 1))
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    fn, args, _ = piece(shape)
+    with histeng.engine_mesh(mesh):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    groups = _all_reduce_group_sizes(text)
+    assert groups, "rows are sharded: the combine must cross devices"
+    assert all(0 < g <= 2 for g in groups), groups

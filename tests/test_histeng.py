@@ -84,6 +84,94 @@ def test_tree_combine_is_fixed_order():
     np.testing.assert_array_equal(got, want)
 
 
+def _combine_strided(parts):
+    """`_tree_combine` as it stood until PR 31 (strided slices)."""
+    while parts.shape[0] > 1:
+        h = parts.shape[0] // 2
+        s = parts[0:2 * h:2] + parts[1:2 * h:2]
+        if parts.shape[0] % 2:
+            s = jnp.concatenate([s, parts[2 * h:]], axis=0)
+        parts = s
+    return parts[0]
+
+
+#: the pinned association written out: neighbours added, an odd leftover
+#: carried to the next round
+_PINNED = {
+    1: lambda p: p[0],
+    2: lambda p: p[0] + p[1],
+    3: lambda p: (p[0] + p[1]) + p[2],
+    5: lambda p: ((p[0] + p[1]) + (p[2] + p[3])) + p[4],
+    7: lambda p: ((p[0] + p[1]) + (p[2] + p[3])) + ((p[4] + p[5]) + p[6]),
+    8: lambda p: (((p[0] + p[1]) + (p[2] + p[3]))
+                  + ((p[4] + p[5]) + (p[6] + p[7]))),
+}
+
+
+@pytest.mark.parametrize("under_mesh", [False, True],
+                         ids=["one_device", "engine_mesh"])
+@pytest.mark.parametrize("tail", [(6,), (3, 5), (2, 3, 4)],
+                         ids=["2d", "3d", "4d"])
+@pytest.mark.parametrize("K", sorted(_PINNED))
+def test_tree_combine_spellings_give_the_pinned_bits(K, tail, under_mesh):
+    """Both spellings of the combine (static slices on one device, the
+    reshape-halving one traced under an engine mesh) equal the written-out
+    pinned expression AND the strided-slice form they replaced, bit for bit
+    (uint32 view), for every K, power of two or not."""
+    rng = np.random.RandomState(100 * K + len(tail))
+    # magnitudes spread over six decades: another association would show
+    p = jnp.asarray((rng.randn(K, *tail)
+                     * 10.0 ** rng.randint(-3, 4, (K, *tail))
+                     ).astype(np.float32))
+    want = np.asarray(_PINNED[K](p)).view(np.uint32)
+    old = np.asarray(jax.jit(_combine_strided)(p)).view(np.uint32)
+    np.testing.assert_array_equal(old, want)
+    fn = jax.jit(hk._tree_combine)
+
+    def traced():
+        return {e.primitive.name
+                for e in jax.make_jaxpr(hk._tree_combine)(p).jaxpr.eqns}
+    if under_mesh:
+        with histeng.engine_mesh(make_mesh(MeshSpec(data=4, model=2))):
+            assert hk._combine_form() == "halving"
+            prims = traced()
+            got = np.asarray(fn(p))
+        # the halving spelling reshapes the array; the fused one only
+        # takes static slices of it
+        assert ("reshape" in prims) == (K > 1)
+    else:
+        assert hk._combine_form() == "fused"
+        assert "reshape" not in traced()
+        got = np.asarray(fn(p))
+    assert got.shape == tail
+    np.testing.assert_array_equal(got.view(np.uint32), want)
+
+
+@pytest.mark.parametrize("family", [
+    "OpDecisionTreeClassifier", "OpRandomForestClassifier",
+    "OpGBTClassifier", "OpXGBoostClassifier", "OpRandomForestRegressor",
+    "OpGBTRegressor"])
+def test_tree_families_put_the_combine_on_their_spans(family, monkeypatch):
+    """``histShards`` (K) and ``combine`` (the spelling `_tree_combine`
+    traces, by its own test of the engine mesh) are what every tree family
+    adds to ``sweep.family`` / ``selector.refit``; the forest keeps its
+    chunk count and column width on top; other families add neither."""
+    fam = MODEL_REGISTRY[family]
+    grid = [{"maxDepth": 3}]
+    own = fam.fit_span_attrs(4000, 12, grid, 2, True)
+    assert (own["histShards"], own["combine"]) == (8, "fused")
+    assert (set(own) - {"histShards", "combine"}
+            == ({"configChunks", "featSubset"} if "Forest" in family
+                else set()))
+    with histeng.engine_mesh(make_mesh(MeshSpec(data=4, model=2))):
+        assert fam.fit_span_attrs(4000, 12, grid, 2, True)[
+            "combine"] == "halving"
+    monkeypatch.setenv("TG_HIST_SHARDS", "5")
+    assert fam.fit_span_attrs(4000, 12, grid, 2, False)["histShards"] == 5
+    assert "combine" not in MODEL_REGISTRY[
+        "OpLogisticRegression"].fit_span_attrs(4000, 12, LR_GRID, 2, True)
+
+
 def test_pinned_kernel_bit_exact_under_mesh_sharding(monkeypatch):
     """The determinism contract at kernel level: tracing the contraction
     under an engine mesh context (row blocks constrained to 'data') yields

@@ -148,6 +148,28 @@ def test_fused_mesh_tree_families_bit_exact(force_mesh, n, d):
         np.testing.assert_array_equal(rs.mean_metrics, rp.mean_metrics)
 
 
+def test_the_sweep_span_says_which_combine_the_program_traced(force_mesh):
+    """``combine`` on a tree family's ``sweep.family`` span is read under
+    the context its program is traced in: ``"fused"`` for the plain sweep,
+    ``"halving"`` for the mesh one (two-operand cross-device steps)."""
+    from transmogrifai_tpu.observability import trace as obs
+    X, y = _synth(n=400, d=8)
+    models = _models(("OpGBTClassifier", GBT_GRID))
+    seen = []
+    obs.enable_tracing(True)
+    try:
+        for mesh in (None, make_mesh(MeshSpec(data=4, model=2))):
+            obs.tracer().clear()
+            OpCrossValidation(num_folds=3, seed=3, mesh=mesh).validate(
+                models, X, y, "binary", "AuROC", True, 2)
+            seen += [(s.attrs["histShards"], s.attrs["combine"])
+                     for s in obs.tracer().finished()
+                     if s.name == "sweep.family"]
+    finally:
+        obs.enable_tracing(False)
+    assert seen == [(8, "fused"), (8, "halving")]
+
+
 # ---------------------------------------------------------------------------
 # on-device fold masks == the eager (F, n) tensors they replaced
 # ---------------------------------------------------------------------------

@@ -220,7 +220,7 @@ def test_span_attributes_come_from_the_solvers_own_rules(monkeypatch):
         jax.ShapeDtypeStruct((len(grid), n), jnp.float32))
     attrs = rf.fit_span_attrs(n, d, grid, C, True)
     assert attrs == {"configChunks": sum(seen), "featSubset": attrs[
-        "featSubset"]}
+        "featSubset"], "histShards": 8, "combine": "fused"}
     assert widths == {attrs["featSubset"]} and 0 < attrs["featSubset"] < d
     # a table too narrow for a strict subset runs full width: 0
     assert rf.fit_span_attrs(n, 6, grid, C, True)["featSubset"] == 0

@@ -36,6 +36,10 @@ Pinned reduction (mesh determinism)
   cross-block combine is a pinned expression rather than an
   order-unspecified `psum`, so mesh tree sweeps are bit-identical to
   single-device ones (docs/trees.md, "Determinism").
+- The combine is spelt twice, one association: on one device over static
+  slices of the partials (one fused elementwise pass on the chip), under
+  an engine mesh by halving the array, so that a cross-device step stays
+  a sum of two operands (`_tree_combine`, `_combine_form`).
 - When an engine mesh context is active (``engine.engine_mesh``), the
   blocked operands and partials carry ``with_sharding_constraint`` over the
   'data' axis so the per-block GEMMs stay shard-local.
@@ -133,16 +137,46 @@ def _data_spec(mesh, ndim: int):
     return NamedSharding(mesh, PartitionSpec("data", *([None] * (ndim - 1))))
 
 
+def _combine_form() -> str:
+    """The spelling `_tree_combine` traces (its docstring says why there
+    are two): ``"fused"`` on one device, ``"halving"`` under an engine
+    mesh. Read at TRACE time, like `_use_pallas()`."""
+    return "fused" if current_engine_mesh() is None else "halving"
+
+
 def _tree_combine(parts: jnp.ndarray) -> jnp.ndarray:
     """Fixed-order pairwise tree reduction over axis 0, exact f32 adds.
 
     The combine is an explicit expression — (p0+p1)+(p2+p3) … — so its
     floating-point result is pinned by construction: the same bits on one
     device and on a mesh, unlike `psum`/plain `.sum(0)` whose grouping the
-    compiler may re-associate across topologies."""
+    compiler may re-associate across topologies. Each round adds neighbours
+    and carries an odd leftover to the next; the two spellings below build
+    that one association and differ only in what the chip's compiler makes
+    of them (`_combine_form`):
+
+    - on one device, over the static slices ``parts[i]``: the whole tree is
+      ONE elementwise fusion that reads the K partials once and writes the
+      result once (strided slices, ``parts[0::2] + parts[1::2]``, lowered
+      to update loops: 64 % of the boosted-trees sweep fit, PERF.md PR 31);
+    - under an engine mesh, by halving the array itself (reshape to
+      ``(h, 2, …)``, add the two static columns): a round whose pairs lie
+      on different devices compiles to an all-reduce over PAIRS of devices,
+      a sum of two terms that no order can change. The closed form over
+      slices becomes local sums and ONE all-reduce over the whole 'data'
+      axis there, whose order the hardware chooses: the pinned property
+      would be gone (tests/test_device_names_tpu.py holds both)."""
+    if _combine_form() == "fused":
+        terms = [parts[i] for i in range(parts.shape[0])]
+        while len(terms) > 1:
+            h = len(terms) // 2
+            terms = ([terms[2 * i] + terms[2 * i + 1] for i in range(h)]
+                     + terms[2 * h:])
+        return terms[0]
     while parts.shape[0] > 1:
         h = parts.shape[0] // 2
-        s = parts[0:2 * h:2] + parts[1:2 * h:2]
+        pairs = parts[:2 * h].reshape(h, 2, *parts.shape[1:])
+        s = pairs[:, 0] + pairs[:, 1]
         if parts.shape[0] % 2:
             s = jnp.concatenate([s, parts[2 * h:]], axis=0)
         parts = s

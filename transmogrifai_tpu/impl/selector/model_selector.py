@@ -435,14 +435,15 @@ class ModelSelector(AllowLabelAsInput, Estimator):
                     refit_quarantine.append(rec)
                     FaultLog.record(FaultReport(site="selector.refit",
                                                 kind="quarantine", detail=rec))
+            with engine_mesh(self.mesh):      # as the fit was traced
+                own = MODEL_REGISTRY[best_used[0]].fit_span_attrs(
+                    n_pad, int(Xf.shape[1]), [best_used[1]], num_classes,
+                    False)
             refit_span.set_attr(family=best_used[0],
                                 attempts=(len(refit_quarantine)
                                           + (fitted is not None)),
                                 classes=num_classes, lanes=1, rows=n_fit,
-                                features=int(Xf.shape[1]),
-                                **MODEL_REGISTRY[best_used[0]].fit_span_attrs(
-                                    n_pad, int(Xf.shape[1]), [best_used[1]],
-                                    num_classes, False))
+                                features=int(Xf.shape[1]), **own)
         if fitted is None:
             raise AllCandidatesFailedError(
                 list(best.quarantined) + refit_quarantine)

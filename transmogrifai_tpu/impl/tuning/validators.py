@@ -849,14 +849,16 @@ class OpValidator:
                            order=dispatched) as sweep_span:
                 if tracing_enabled():
                     # the fit's shape, and what the family's own schedule
-                    # fixes about it (contractions, chunks of lanes)
-                    sweep_span.set_attr(
-                        classes=num_classes, lanes=F * len(grid), rows=n,
-                        features=int(X.shape[-1]),
-                        **family.fit_span_attrs(
+                    # fixes about it (contractions, chunks of lanes), read
+                    # under the context the program is traced in
+                    with _hist_mesh_ctx(family, mesh):
+                        own = family.fit_span_attrs(
                             int(X.shape[0]), int(X.shape[-1]),
                             list(grid) * F, num_classes,
-                            not self.exact_sweep_fits))
+                            not self.exact_sweep_fits)
+                    sweep_span.set_attr(
+                        classes=num_classes, lanes=F * len(grid), rows=n,
+                        features=int(X.shape[-1]), **own)
                 # flight-recorder: each family dispatch, stamped with the
                 # owning run's correlation id (workflow.train) — a sweep
                 # post-mortem shows which family the incident interrupted

@@ -61,7 +61,7 @@ from ..ops.forest import (
     forest_predict_chain,
 )
 from ..histeng import build_hist, build_node_hist, pinned_row_sum
-from ..histeng.kernels import _hist_shards
+from ..histeng.kernels import _combine_form, _hist_shards
 from .api import FittedParams, ModelFamily, register_family
 
 N_BINS = 32  # Spark maxBins default (reference DefaultSelectorParams.MaxBin)
@@ -1405,6 +1405,14 @@ class _TreeFamilyBase(ModelFamily):
             return "regression"
         return "classification"
 
+    def fit_span_attrs(self, rows, features, grid, num_classes, sweep):
+        """``histShards``: K, the pinned row blocks of the histogram
+        engine's contraction; ``combine``: the spelling `_tree_combine`
+        traces for their partials (``"fused"`` on one device, ``"halving"``
+        under an engine mesh: call this under the context the fit is
+        traced in)."""
+        return {"histShards": _hist_shards(), "combine": _combine_form()}
+
     def select_params(self, batched, idx):
         """Per-config slice, except the bin-edge table, which is shared by
         every configuration of a fit and stored once."""
@@ -1718,8 +1726,10 @@ class RandomForestFamilyBase(_TreeFamilyBase):
         (tests/test_multiclass_support.py holds them to the traced count);
         ``featSubset``: the per-tree column width the growers run compact
         at (`_rf_feature_table`), 0 where they run all ``features``."""
+        attrs = super().fit_span_attrs(rows, features, grid, num_classes,
+                                       sweep)
         if any("maxDepth" not in g for g in grid):
-            return {}
+            return attrs
         n_trees = int(max(g.get("numTrees", 20.0) for g in grid))
         if sweep:
             n_trees = min(n_trees, _SWEEP_RF_TREES)
@@ -1743,7 +1753,7 @@ class RandomForestFamilyBase(_TreeFamilyBase):
                                   slots if u > _MAX_HEAP_DEPTH else 0, k,
                                   features, N_BINS, d_sub)
             chunks += -(-B // cb)
-        return {"configChunks": chunks, "featSubset": d_sub}
+        return dict(attrs, configChunks=chunks, featSubset=d_sub)
 
     def predict_batch(self, params, X, num_classes):
         edges = self._edges_of(params)
