@@ -160,7 +160,10 @@ def test_tree_families_put_the_combine_on_their_spans(family, monkeypatch):
     grid = [{"maxDepth": 3}]
     own = fam.fit_span_attrs(4000, 12, grid, 2, True)
     assert (own["histShards"], own["combine"]) == (8, "fused")
-    assert (set(own) - {"histShards", "combine"}
+    # the growers' sample: every row of a table under the sweep's cap
+    assert own["sampleRows"] == 4000
+    assert fam.fit_span_attrs(10 ** 7, 12, grid, 2, True)["sampleRows"] == 8192
+    assert (set(own) - {"histShards", "combine", "sampleRows"}
             == ({"configChunks", "featSubset"} if "Forest" in family
                 else set()))
     with histeng.engine_mesh(make_mesh(MeshSpec(data=4, model=2))):

@@ -521,3 +521,248 @@ def test_no_mesh_program_leak_fixture_probe():
     registers a mesh-keyed cache entry; the fixture clears it after each
     test, so entry here must be clean."""
     assert not mesh_program_keys()
+
+
+# ---------------------------------------------------------------------------
+# the whole train under with_mesh(data=4): equal to one device, and no
+# array of row size whole on a device (PR 34)
+# ---------------------------------------------------------------------------
+
+FAMILY_GRIDS = {
+    "OpLogisticRegression": LR_GRID[:2],
+    "OpLinearSVC": SVC_GRID,
+    "OpRandomForestClassifier": [{"maxDepth": 3, "numTrees": 4}],
+    "OpGBTClassifier": [{"maxDepth": 3, "maxIter": 4}],
+}
+
+
+def _mesh_table(n=4000, seed=5):
+    rng = np.random.RandomState(seed)
+    x1, x2, x3 = rng.randn(n), rng.randn(n), rng.rand(n)
+    shop = rng.choice([f"s{i}" for i in range(6)], n)
+    z = x1 - 0.5 * x2 + 0.8 * (shop == "s1") + 0.3 * rng.randn(n)
+    return pd.DataFrame({"x1": x1, "x2": x2, "x3": x3, "shop": shop,
+                         "y": (z > 0).astype(float)})
+
+
+def _train(df, family, mesh=None):
+    import transmogrifai_tpu as tg
+    from transmogrifai_tpu import FeatureBuilder
+    from transmogrifai_tpu.features import reset_uids
+    from transmogrifai_tpu.impl.selector.factories import (
+        BinaryClassificationModelSelector)
+    from transmogrifai_tpu.impl.selector.model_selector import SelectedModel
+    from transmogrifai_tpu.workflow import OpWorkflow
+    reset_uids()
+    label = FeatureBuilder.RealNN("y").extract_field().as_response()
+    feats = [FeatureBuilder.Real(c).extract_field().as_predictor()
+             for c in ("x1", "x2", "x3")]
+    feats.append(FeatureBuilder.PickList("shop").extract_field()
+                 .as_predictor())
+    checked = tg.transmogrify(feats).sanity_check(label)
+    pred = (BinaryClassificationModelSelector.with_cross_validation(
+        models=[(family, FAMILY_GRIDS[family])])
+        .set_input(label, checked).get_output())
+    wf = OpWorkflow().set_input_dataset(df).set_result_features(pred)
+    if mesh is not None:
+        wf = wf.with_mesh(mesh)
+    model = wf.train()
+    return next(s for s in model.stages if isinstance(s, SelectedModel))
+
+
+def _whole_on_a_device(arr, n_data=4):
+    """Why ``arr`` is not shared evenly over the data axis, or None."""
+    if not isinstance(arr, jax.Array):
+        return f"a host array {type(arr).__name__}"
+    rows = arr.shape[-2] if arr.ndim > 2 else arr.shape[0]
+    axis = arr.ndim - 2 if arr.ndim > 2 else 0
+    got = sorted({s.data.shape[axis] for s in arr.addressable_shards})
+    if len(arr.sharding.device_set) != n_data or got != [rows // n_data]:
+        return (f"{arr.shape} lies in shards of {got} rows on "
+                f"{len(arr.sharding.device_set)} device(s)")
+    return None
+
+
+@pytest.mark.parametrize("family", sorted(FAMILY_GRIDS))
+def test_with_mesh_train_equals_one_device_and_shares_every_row_array(
+        force_mesh, monkeypatch, family):
+    """The train a user runs, ``OpWorkflow.with_mesh(data=4).train()``, for
+    each binary family alone: the winner, every fold metric and the refit's
+    parameters are the one-device train's (a tree family's to the bit: the
+    pinned combine; a linear family's to float32 rounding, since its row
+    sums are taken a chip at a time: docs/parallel.md), and no array with
+    the table's rows is committed whole to one device: the combiner's
+    output, the refit's ``Xf`` and ``W``, the evaluation's ``X``."""
+    from transmogrifai_tpu.impl.feature.vectorizers import VectorsCombiner
+    fam = MODEL_REGISTRY[family]
+    df = _mesh_table()
+    plain = _train(df, family)
+
+    seen = {}
+    real_fit, real_parts = type(fam).fit_batch, type(fam).predict_parts
+    real_combine = VectorsCombiner.transform_column
+
+    def fit_batch(self, X, y, W, *a, **kw):
+        if not isinstance(X, jax.core.Tracer):      # the refit, not a trace
+            seen["refit Xf"], seen["refit W"] = X, W[0]
+        return real_fit(self, X, y, W, *a, **kw)
+
+    def predict_parts(self, fitted, X):
+        if not isinstance(X, jax.core.Tracer) and X.shape[0] > 1000:
+            seen.setdefault("evaluation X", X)
+        return real_parts(self, fitted, X)
+
+    def transform_column(self, table):
+        col = real_combine(self, table)
+        if table.num_rows == len(df):
+            seen["combiner output"] = col.values
+        return col
+
+    monkeypatch.setattr(type(fam), "fit_batch", fit_batch)
+    monkeypatch.setattr(type(fam), "predict_parts", predict_parts)
+    monkeypatch.setattr(VectorsCombiner, "transform_column", transform_column)
+    mesh = make_mesh(MeshSpec(data=4, model=1), devices=jax.devices()[:4])
+    sharded = _train(df, family, mesh)
+
+    assert set(seen) == {"combiner output", "refit Xf", "refit W",
+                         "evaluation X"}
+    for what, arr in seen.items():
+        assert _whole_on_a_device(arr) is None, (
+            what, _whole_on_a_device(arr))
+
+    sp, ss = plain.summary, sharded.summary
+    assert (ss.best_model_type, ss.best_hyper) == (sp.best_model_type,
+                                                   sp.best_hyper)
+    trees = getattr(fam, "uses_hist_engine", False)
+    tol = dict(rtol=0, atol=0) if trees else dict(rtol=2e-4, atol=2e-5)
+    for rp, rs in zip(sp.validation_results, ss.validation_results):
+        np.testing.assert_allclose(rs.fold_metrics, rp.fold_metrics,
+                                   err_msg=rp.family, **tol)
+    assert set(sharded.fitted.params) == set(plain.fitted.params)
+    for k, v in plain.fitted.params.items():
+        np.testing.assert_allclose(
+            np.asarray(sharded.fitted.params[k]), np.asarray(v),
+            err_msg=k, **(tol if trees else dict(rtol=1e-3, atol=1e-4)))
+    for k, v in sp.holdout_evaluation.items():
+        assert ss.holdout_evaluation[k] == pytest.approx(
+            v, abs=1e-4, nan_ok=True), k
+
+
+def test_take_rows_is_the_rows_own_bits_shard_to_shard():
+    """``parallel.sharded.take_rows``: ``X[idx]`` of a row-sharded table,
+    the result's rows sharded alike; -1 gives a row of zeros; a last step
+    that overlaps the one before; a table whose rows do not divide."""
+    from transmogrifai_tpu.parallel import sharded as S
+    mesh = make_mesh(MeshSpec(data=4, model=1), devices=jax.devices()[:4])
+    rng = np.random.RandomState(0)
+    X = rng.randn(1003, 7).astype(np.float32)
+    X[5, 2] = -0.0
+    Xs = S.place_rows(X[:1000], mesh)
+    assert _whole_on_a_device(Xs) is None
+    for m, block in ((360, 64), (360, 65536), (4, 3), (2000, 77)):
+        idx = rng.randint(0, 1000, size=m)
+        idx[::9] = -1
+        out = S.take_rows(Xs, idx, mesh, block=block)
+        assert out.sharding == S.row_sharding(mesh, 2)
+        want = np.where((idx >= 0)[:, None], X[np.maximum(idx, 0)], 0.0)
+        np.testing.assert_array_equal(np.asarray(out), want)
+    odd = S.take_rows(jnp.asarray(X), np.arange(1003, 1003 - 8, -1) - 1,
+                      mesh)
+    np.testing.assert_array_equal(np.asarray(odd), X[::-1][:8])
+    # a vector, and a mesh with a 'model' axis beside 'data'
+    both = make_mesh(MeshSpec(data=4, model=2))
+    vec = S.take_rows(S.place_rows(X[:1000, 0].copy(), both),
+                      np.arange(8), both)
+    np.testing.assert_array_equal(np.asarray(vec), X[:8, 0])
+    padded = S.pad_rows_sharded(Xs, 1024, mesh)
+    assert _whole_on_a_device(padded) is None
+    np.testing.assert_array_equal(np.asarray(padded)[:1000], X[:1000])
+    assert not np.asarray(padded)[1000:].any()
+    with pytest.raises(ValueError):
+        S.take_rows(Xs, np.arange(6), mesh)
+
+
+@pytest.mark.parametrize("rows, fault", [
+    (None, None), ("bucket", None), ("bucket", "more than X has"),
+    ("bucket", "fewer than y"), ("odd", "not the data axis's")])
+def test_validate_takes_a_table_already_at_its_bucket_only_when_told(
+        force_mesh, rows, fault):
+    """``padded_rows`` says that ``X`` came padded (zeros past ``len(y)``):
+    the sweep then reads it as it stands, equal to the sweep that pads by
+    itself; a size that is not ``X``'s, under ``len(y)`` or not a multiple
+    of the data axis is refused, never guessed from the shapes."""
+    from transmogrifai_tpu.parallel import sharded as S
+    X, y = _synth(n=333)
+    models = _models(("OpLogisticRegression", LR_GRID[:2]))
+    mesh = make_mesh(MeshSpec(data=4, model=1), devices=jax.devices()[:4])
+    cv = OpCrossValidation(num_folds=3, seed=7, mesh=mesh)
+    plain = cv.validate(models, X, y, "binary", "AuPR", True, 2)
+    if rows is None:         # told nothing, the sweep pads to its bucket itself
+        assert sum(shape[0] for _, shape in cv.last_sweep_shards) \
+            == bucket_for(333, 4)
+        return
+    n_b = bucket_for(333, 4) if rows == "bucket" else 335
+    Xp = S.pad_rows_sharded(X, n_b, mesh) if rows == "bucket" \
+        else jnp.pad(X, ((0, 2), (0, 0)))
+    if fault is None:
+        got = cv.validate(models, Xp, y, "binary", "AuPR", True, 2,
+                          padded_rows=n_b)
+        assert got.hyper == plain.hyper
+        for rp, rg in zip(plain.results, got.results):
+            np.testing.assert_array_equal(rg.fold_metrics, rp.fold_metrics)
+        assert cv.last_sweep_shards and sum(
+            shape[0] for _, shape in cv.last_sweep_shards) == n_b
+        return
+    say = {"more than X has": 2 * n_b, "fewer than y": 332,
+           "not the data axis's": 335}[fault]
+    with pytest.raises(ValueError, match="padded_rows"):
+        cv.validate(models, Xp if fault != "fewer than y" else X[:332],
+                    y, "binary", "AuPR", True, 2, padded_rows=say)
+
+
+def test_the_spans_say_what_the_mesh_did(force_mesh):
+    """``mesh.place`` around each sharded upload; ``sweep.family``,
+    ``selector.refit`` and ``selector.evaluate`` carry the mesh, whether it
+    engaged and the rows a chip reads; ``workflow.train`` the chips."""
+    from transmogrifai_tpu.observability import trace as obs_trace
+    df = _mesh_table(n=2000)
+    mesh = make_mesh(MeshSpec(data=4, model=1), devices=jax.devices()[:4])
+    obs_trace.enable_tracing(True)
+    _train(df, "OpRandomForestClassifier", mesh)
+    spans = {}
+    for s in obs_trace.tracer().finished():
+        spans.setdefault(s.name, []).append(s)
+    places = spans["mesh.place"]
+    assert {p.attrs["path"] for p in places} == {"host_shards"}
+    assert all(p.attrs["shards"] == 4 and p.attrs["bytes"] > 0
+               for p in places)
+    assert max(p.attrs["bytes"] for p in places) >= 2000 * 4 * 4
+    (fam,) = spans["sweep.family"]
+    assert (fam.attrs["meshData"], fam.attrs["meshModel"],
+            fam.attrs["engaged"]) == (4, 1, True)
+    # a tree family's sweep fit reads its sample, not the table
+    assert fam.attrs["rowsPerChip"] == fam.attrs["sampleRows"] // 4
+    (refit,) = spans["selector.refit"]
+    assert refit.attrs["engaged"] is True and refit.attrs["meshData"] == 4
+    assert refit.attrs["rowsPerChip"] == bucket_for(1800, 4) // 4
+    (ev,) = spans["selector.evaluate"]
+    assert ev.attrs["rowsPerChip"] == 2000 // 4
+    assert spans["workflow.train"][0].attrs["chips"] == 4
+    # every shard-to-shard gather says what a chip receives, and where
+    takes = {t.attrs["site"]: t.attrs for t in spans["mesh.take_rows"]}
+    assert set(takes) == {"selector.prepare", "selector.evaluate"}
+    for a in takes.values():
+        assert a["shards"] == 4
+        assert a["rowBytes"] == 4 * refit.attrs["features"]
+        assert a["rowsPerChip"] * 4 == a["rows"]
+        assert a["steps"] == 1
+    assert takes["selector.prepare"]["rows"] == bucket_for(1800, 4)
+    obs_trace.tracer().clear()
+    _train(df, "OpLogisticRegression")
+    by = {s.name: s for s in obs_trace.tracer().finished()}
+    assert "mesh.place" not in by and "mesh.take_rows" not in by
+    assert (by["sweep.family"].attrs["meshData"],
+            by["sweep.family"].attrs["engaged"]) == (1, False)
+    assert by["sweep.family"].attrs["matrixPasses"] == 5 + 8 * (4 + 2 * 6)
+    assert by["selector.refit"].attrs["matrixPasses"] == 5 + 10 * (4 + 16)
+    assert by["workflow.train"].attrs["chips"] == 1

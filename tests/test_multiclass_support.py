@@ -191,7 +191,16 @@ def test_span_attributes_come_from_the_solvers_own_rules(monkeypatch):
         linear._SOFTMAX_STEPS)), "laneChunks": 2}
     assert lr.fit_span_attrs(1048576, 76, grid[:1], 23, False) == {
         "contractions": linear.softmax_contractions(False), "laneChunks": 1}
-    assert lr.fit_span_attrs(1048576, 76, grid, 2, True) == {}
+    # the binary fit gives its schedule's reads of the matrix instead
+    assert lr.fit_span_attrs(1048576, 76, grid, 2, True) == {
+        "matrixPasses": 5 + 8 * (4 + 2 * 6)}
+    assert lr.fit_span_attrs(1048576, 76, grid, 2, False) == {
+        "matrixPasses": linear.logreg_matrix_passes(False)}
+    # and so does the linear SVC: a standardisation and two products a step
+    svc = MODEL_REGISTRY["OpLinearSVC"]
+    for sweep in (True, False):
+        assert svc.fit_span_attrs(1048576, 76, grid, 2, sweep) == {
+            "matrixPasses": 5 + 2 * 100}
 
     # the forest's chunk count against what its growers' lax.map really get
     rf = MODEL_REGISTRY["OpRandomForestClassifier"]
@@ -220,7 +229,8 @@ def test_span_attributes_come_from_the_solvers_own_rules(monkeypatch):
         jax.ShapeDtypeStruct((len(grid), n), jnp.float32))
     attrs = rf.fit_span_attrs(n, d, grid, C, True)
     assert attrs == {"configChunks": sum(seen), "featSubset": attrs[
-        "featSubset"], "histShards": 8, "combine": "fused"}
+        "featSubset"], "histShards": 8, "combine": "fused",
+        "sampleRows": min(n, 8192)}
     assert widths == {attrs["featSubset"]} and 0 < attrs["featSubset"] < d
     # a table too narrow for a strict subset runs full width: 0
     assert rf.fit_span_attrs(n, 6, grid, C, True)["featSubset"] == 0

@@ -1081,14 +1081,14 @@ class VectorsCombiner(SequenceTransformer):
         if mesh is not None and mat.shape[0] % mesh.shape["data"] == 0:
             # row-sharded upload (only when rows split evenly — padding here
             # would change the table's row count; consumers that need exact
-            # shards re-pad internally with masked rows, see shard_rows)
-            import jax
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            arr = jax.device_put(jnp.asarray(mat),
-                                 NamedSharding(mesh, P("data", None)))
+            # shards re-pad internally with masked rows, see shard_rows).
+            # Straight from the host array: each chip is sent its own rows
+            # (place_rows counts the bytes)
+            from ...parallel.sharded import place_rows
+            arr = place_rows(mat, mesh, site="combiner.upload")
         else:
             arr = jnp.asarray(mat)
-        _count_transfer_bytes(arr, "h2d")
+            _count_transfer_bytes(arr, "h2d")
         return Column(OPVector, arr, None, {"vector_meta": vm})
 
     def transform_row(self, row: Dict[str, Any]) -> Any:

@@ -27,6 +27,7 @@ from ...stages.base import (AllowLabelAsInput, Estimator, PendingFit,
                             Transformer)
 from ...table import Column, FeatureTable
 from ...types import OPVector, RealNN
+from ...utils.padding import pad_rows, padded_valid_mask
 from ...vector_metadata import VectorColumnMetadata, VectorMetadata
 from .sanity_checker_metadata import (
     CategoricalGroupStats, ColumnStatistics, SanityCheckerSummary,
@@ -186,19 +187,33 @@ class SanityChecker(AllowLabelAsInput, Estimator):
         max_frac = max(0.0, self.sample_upper_limit / max(n, 1))
         frac = max(min(self.check_sample, max_frac), min_frac)
         target = min(int(round(n * frac)), n)
-        if target < n:
-            rng = np.random.RandomState(self.seed)
-            idx = rng.choice(n, size=target, replace=False)
-            Xd, ys = Xd_all[jnp.asarray(idx)], y[idx]
-        else:
-            Xd, ys = Xd_all, y
-        yd = jnp.asarray(ys)
         mesh = getattr(self, "mesh", None)
+        n_data = mesh.shape["data"] if mesh is not None else 1
         row_mask = None
+        idx = None
+        if target < n:
+            idx = np.random.RandomState(self.seed).choice(
+                n, size=target, replace=False)
+        ys = y if idx is None else y[idx]
+        if idx is not None and mesh is not None and n % n_data == 0:
+            # the sample of a table sharded over the mesh: gathered shard
+            # to shard (no chip holds the sample whole), padded to the data
+            # axis with row 0 under a False mask
+            from ...parallel.sharded import place_rows, take_rows
+            n_s = -(-target // n_data) * n_data
+            Xd = take_rows(Xd_all, pad_rows(idx, n_s), mesh,
+                           site="sanity.sample")
+            yd = place_rows(pad_rows(ys, n_s), mesh, site="checker.upload")
+            row_mask = place_rows(padded_valid_mask(None, target, n_s), mesh,
+                                  site="checker.upload")
+        else:
+            Xd = Xd_all if idx is None else Xd_all[jnp.asarray(idx)]
+            yd = jnp.asarray(ys)
+            if mesh is not None:
+                from ...parallel.sharded import shard_rows
+                Xd, row_mask, _ = shard_rows(Xd, None, mesh)
+                yd, _, _ = shard_rows(yd, None, mesh)
         if mesh is not None:
-            from ...parallel.sharded import shard_rows
-            Xd, row_mask, _ = shard_rows(Xd, None, mesh)
-            yd, _, _ = shard_rows(yd, None, mesh)
             self._stats_input_sharding = str(Xd.sharding)
         stats = col_stats(Xd, row_mask)
         if self.correlation_type_spearman:

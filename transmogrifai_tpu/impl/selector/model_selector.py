@@ -350,11 +350,23 @@ class ModelSelector(AllowLabelAsInput, Estimator):
                 num_classes = 2
 
             metric_name, larger_better = self.validation_metric
-            sel_d, yd = jnp.asarray(sel), jnp.asarray(y)
-            _count_transfer_bytes(sel_d, "h2d")
+            yd = jnp.asarray(y)
             _count_transfer_bytes(yd, "h2d")
-            Xd = Xd_all[sel_d]
-            del sel_d        # an index vector, not to outlive its gather
+            if self.mesh is not None:
+                # shard to shard, straight into the row bucket the sweep
+                # and the refit share (rows past len(y) are zeros): no chip
+                # holds the rows kept whole, and neither pads a copy
+                from ...parallel.sharded import take_rows
+                n_b = bucket_for(len(sel),
+                                 multiple_of=self.mesh.shape["data"])
+                Xd = take_rows(Xd_all, np.pad(sel, (0, n_b - len(sel)),
+                                              constant_values=-1), self.mesh,
+                               site="selector.prepare")
+            else:
+                sel_d = jnp.asarray(sel)
+                _count_transfer_bytes(sel_d, "h2d")
+                Xd = Xd_all[sel_d]
+                del sel_d    # an index vector, not to outlive its gather
             if "labelsKept" in prep.summary:     # what DataCutter kept
                 prepare_span.set_attr(
                     labelsKept=len(prep.summary["labelsKept"]),
@@ -369,7 +381,9 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         else:
             best = self.validator.validate(
                 self.models, Xd, yd, self.problem, metric_name, larger_better,
-                num_classes)
+                num_classes,
+                padded_rows=(int(Xd.shape[0]) if self.mesh is not None
+                             else None))
 
         # deterministic preemption point: the sweep completed (and, under a
         # checkpoint dir, persisted) but the winner never refit — a resume
@@ -382,22 +396,28 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         n_data = self.mesh.shape["data"] if self.mesh is not None else 1
         n_pad = bucket_for(n_fit, multiple_of=n_data)
         Xf, yf = Xd, yd
-        if n_pad != n_fit:
-            Xf = jnp.pad(Xd, ((0, n_pad - n_fit), (0, 0)))
-            yf = jnp.pad(yd, (0, n_pad - n_fit))
-        W = jnp.zeros((1, n_pad), jnp.float32).at[:, :n_fit].set(1.0)
         if self.mesh is not None:
-            # the winner refit is a full-data fit — shard its rows over
-            # 'data' like the sweep (round-3 left it unsharded: the most
-            # expensive single fit of the train path ran on one chip);
-            # placements retry transient link errors (robustness/policy.py)
+            # the winner refit is a full-data fit — its rows are sharded
+            # over 'data' like the sweep's: Xd came from the gather already
+            # at this bucket, the label and the weight row are built on the
+            # host and sent shard by shard; placements retry transient link
+            # errors (robustness/policy.py)
             from jax.sharding import NamedSharding, PartitionSpec as P
             from ...parallel.distributed import retrying_device_put
-            Xf = retrying_device_put(Xf, NamedSharding(self.mesh,
-                                                       P("data", None)))
-            yf = retrying_device_put(yf, NamedSharding(self.mesh, P("data")))
-            W = retrying_device_put(W, NamedSharding(self.mesh,
-                                                     P(None, "data")))
+            from ...parallel.sharded import place_rows
+            assert Xf.shape[0] == n_pad, (Xf.shape, n_pad)
+            yf = place_rows(pad_rows(y, n_pad), self.mesh,
+                            site="refit.upload")
+            W = retrying_device_put(
+                padded_valid_mask(None, n_fit, n_pad).astype(
+                    np.float32)[None, :],
+                NamedSharding(self.mesh, P(None, "data")),
+                site="refit.upload")
+        else:
+            if n_pad != n_fit:
+                Xf = jnp.pad(Xd, ((0, n_pad - n_fit), (0, 0)))
+                yf = jnp.pad(yd, (0, n_pad - n_fit))
+            W = jnp.zeros((1, n_pad), jnp.float32).at[:, :n_fit].set(1.0)
         # refit with a non-finite guard and fallback: a winner that diverges
         # on the full prepared train (the sweep fit at a sample/cap; the
         # refit is the exact program) is quarantined and the next-ranked
@@ -443,7 +463,8 @@ class ModelSelector(AllowLabelAsInput, Estimator):
                                 attempts=(len(refit_quarantine)
                                           + (fitted is not None)),
                                 classes=num_classes, lanes=1, rows=n_fit,
-                                features=int(Xf.shape[1]), **own)
+                                features=int(Xf.shape[1]),
+                                **self._mesh_attrs(n_pad), **own)
         if fitted is None:
             raise AllCandidatesFailedError(
                 list(best.quarantined) + refit_quarantine)
@@ -505,9 +526,16 @@ class ModelSelector(AllowLabelAsInput, Estimator):
             eval_span.set_attr(
                 labelMap="none" if labels is None else "lookup",
                 evalPath="device" if on_device else "table",
-                hostBytes=host_bytes)
+                hostBytes=host_bytes,
+                **self._mesh_attrs(len(train_idx) + len(test_idx)))
         model.summary_metadata = summary.to_json()
         return model
+
+    def _mesh_attrs(self, rows: int) -> Dict[str, Any]:
+        """The mesh a refit or an evaluation of ``rows`` rows runs on, for
+        its span: unlike the sweep, neither asks the cost model."""
+        from ...parallel.mesh import mesh_span_attrs
+        return mesh_span_attrs(self.mesh, self.mesh is not None, rows)
 
     def _ranked_candidates(self, best, larger_better: bool):
         """Winner first, then every other finite-metric candidate ordered by
@@ -536,14 +564,14 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         n_pad = bucket_for(n, multiple_of=n_data)
         idx_pad = pad_rows(idx, n_pad)
         label = pad_rows(y_dense[idx], n_pad)
-        idx_d = jnp.asarray(idx_pad)
-        _count_transfer_bytes(idx_d, "h2d")
-        X = Xd_all[idx_d]
         if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            from ...parallel.distributed import retrying_device_put
-            X = retrying_device_put(X, NamedSharding(self.mesh,
-                                                     P("data", None)))
+            from ...parallel.sharded import take_rows
+            X = take_rows(Xd_all, idx_pad, self.mesh,
+                          site="selector.evaluate")
+        else:
+            idx_d = jnp.asarray(idx_pad)
+            _count_transfer_bytes(idx_d, "h2d")
+            X = Xd_all[idx_d]
         return X, label, padded_valid_mask(None, n, n_pad)
 
     def _default_evaluator(self):
@@ -659,11 +687,12 @@ class SelectedModel(AllowLabelAsInput, Transformer):
         mesh = getattr(self, "mesh", None)
         n_data = mesh.shape["data"] if mesh is not None else 1
         n_pad = bucket_for(n, multiple_of=n_data)
-        if n_pad != n:  # bucket rows so the predict program is reused
-            X = jnp.pad(X, ((0, n_pad - n), (0, 0)))
         if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            X = jax.device_put(X, NamedSharding(mesh, P("data", None)))
+            # padded and sharded by one program: no whole copy on a chip
+            from ...parallel.sharded import pad_rows_sharded
+            X = pad_rows_sharded(X, n_pad, mesh)
+        elif n_pad != n:  # bucket rows so the predict program is reused
+            X = jnp.pad(X, ((0, n_pad - n), (0, 0)))
         family = MODEL_REGISTRY[self.fitted.family]
         with engine_mesh(mesh):
             parts = family.predict_one(self.fitted, X)

@@ -402,7 +402,8 @@ class OpValidator:
                  metric_name: str, larger_better: bool, num_classes: int,
                  val_masks: Optional[np.ndarray] = None,
                  fold_sliced: Optional[bool] = None,
-                 resolve: bool = True):
+                 resolve: bool = True,
+                 padded_rows: Optional[int] = None):
         """Run the full |families| × |grid| × |folds| sweep. Each family is one
         vmapped fit_batch + predict_batch + batched-metric program.
 
@@ -410,7 +411,12 @@ class OpValidator:
         boolean validation masks — used by the workflow-level CV path, which
         must evaluate one externally-prepared fold at a time. ``fold_sliced``
         forces the per-fold row-gather scoring path on/off (default: on —
-        under a mesh the gathered fold tensors are re-sharded over 'data')."""
+        under a mesh the gathered fold tensors are re-sharded over 'data').
+
+        ``padded_rows``: ``X`` already has that many rows, zeros past
+        ``len(y)``, and it is the sweep's padded size as it stands (the
+        selector under a mesh gathers its rows straight into the bucket);
+        None: ``X`` has ``len(y)`` rows and is padded here."""
         if val_masks is None:
             val_masks = self.make_splits(np.asarray(y))  # (F, n)
         F, n = val_masks.shape
@@ -468,8 +474,21 @@ class OpValidator:
         # carry zero weight and False val masks — results are unchanged.
         n_data = mesh.shape["data"] if mesh is not None else 1
         n_pad = bucket_for(n, multiple_of=n_data)
-        if n_pad != n:
+        if padded_rows is not None:
+            # the caller gathered X straight into its row bucket (zero rows
+            # past len(y)): no second copy here
+            if int(X.shape[0]) != int(padded_rows) or padded_rows < n \
+                    or padded_rows % n_data:
+                raise ValueError(
+                    f"padded_rows={padded_rows}: X has {X.shape[0]} rows, "
+                    f"y {n}, the data axis {n_data}")
+            n_pad = int(padded_rows)
+        elif n_pad != n and mesh is not None:
+            from ...parallel.sharded import pad_rows_sharded
+            X = pad_rows_sharded(X, n_pad, mesh)
+        elif n_pad != n:
             X = jnp.pad(X, ((0, n_pad - n),) + ((0, 0),) * (X.ndim - 1))
+        if n_pad != n:
             y = jnp.pad(y, (0, n_pad - n))
         # ship ONE byte per row and expand masks on device: each row sits in
         # at most one validation fold (TVS leaves train-only rows at id=F),
@@ -856,9 +875,16 @@ class OpValidator:
                             int(X.shape[0]), int(X.shape[-1]),
                             list(grid) * F, num_classes,
                             not self.exact_sweep_fits)
+                    # the mesh asked for, whether the cost model engaged
+                    # it, and the rows of the table this program reads on a
+                    # chip (a tree family's sweep fit reads its sample)
+                    from ...parallel.mesh import mesh_span_attrs
                     sweep_span.set_attr(
                         classes=num_classes, lanes=F * len(grid), rows=n,
-                        features=int(X.shape[-1]), **own)
+                        features=int(X.shape[-1]),
+                        **mesh_span_attrs(self.mesh, mesh is not None,
+                                          own.get("sampleRows", n_pad)),
+                        **own)
                 # flight-recorder: each family dispatch, stamped with the
                 # owning run's correlation id (workflow.train) — a sweep
                 # post-mortem shows which family the incident interrupted

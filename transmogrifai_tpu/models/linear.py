@@ -203,6 +203,20 @@ def _fit_logreg_batch(X, y, W, reg, elastic_net, newton_iters=10, cg_iters=8,
     return std.unscale(A, b)
 
 
+#: (Newton steps, CG steps a Newton step) of the binary fit: a sweep
+#: candidate's shorter schedule, the refit's full one
+_LOGREG_STEPS = {True: (8, 6), False: (10, 8)}
+
+
+def logreg_matrix_passes(sweep: bool) -> int:
+    """Reads of the (rows, features) matrix that `_fit_logreg_batch`'s
+    schedule makes: five to standardise (`_BatchStd`), then a Newton step's
+    margin, gradient and two diagonal-curvature products and two products
+    a CG step."""
+    newton, cg = _LOGREG_STEPS[bool(sweep)]
+    return 5 + newton * (4 + 2 * cg)
+
+
 def _fit_logreg(X, y, w, reg, elastic_net):
     """Single-config fit: the B=1 slice of the batched solver."""
     coef, bias = _fit_logreg_batch(
@@ -227,8 +241,10 @@ class LogisticRegressionFamily(ModelFamily):
 
     def fit_batch(self, X, y, weights, grid, num_classes):
         if num_classes <= 2:
+            newton, cg = _LOGREG_STEPS[False]
             coef, bias = _fit_logreg_batch(
-                X, y, weights, grid["regParam"], grid["elasticNetParam"])
+                X, y, weights, grid["regParam"], grid["elasticNetParam"],
+                newton_iters=newton, cg_iters=cg)
             return {"coef": coef, "bias": bias}
         return self._fit_softmax(X, y, weights, grid, num_classes, False)
 
@@ -244,15 +260,16 @@ class LogisticRegressionFamily(ModelFamily):
         # — metric-ranking accuracy only; the winner refits through
         # fit_batch (exact f32 temps, full 10x8 schedule)
         if num_classes <= 2:
+            newton, cg = _LOGREG_STEPS[True]
             coef, bias = _fit_logreg_batch(
                 X, y, weights, grid["regParam"], grid["elasticNetParam"],
-                newton_iters=8, cg_iters=6, sweep=True)
+                newton_iters=newton, cg_iters=cg, sweep=True)
             return {"coef": coef, "bias": bias}
         return self._fit_softmax(X, y, weights, grid, num_classes, True)
 
     def fit_span_attrs(self, rows, features, grid, num_classes, sweep):
         if num_classes <= 2:
-            return {}
+            return {"matrixPasses": logreg_matrix_passes(sweep)}
         chunk = softmax_lane_chunk(rows, len(grid), num_classes)
         return {"contractions": softmax_contractions(sweep),
                 "laneChunks": -(-len(grid) // chunk)}
@@ -813,8 +830,19 @@ class LinearRegressionFamily(ModelFamily):
 # configs via the same shared-matmul standardization algebra as logistic.
 # ---------------------------------------------------------------------------
 
+#: gradient steps of the SVC fit, a sweep candidate's and the refit's alike
+_SVC_STEPS = 100
+
+
+def svc_matrix_passes() -> int:
+    """Reads of the (rows, features) matrix that `_fit_svc_batch` makes:
+    five to standardise (`_BatchStd`), then a step's margin and gradient
+    products."""
+    return 5 + 2 * _SVC_STEPS
+
+
 @partial(jax.jit, static_argnames=("iters", "sweep"))
-def _fit_svc_batch(X, y, W, reg, iters=100, sweep=False):
+def _fit_svc_batch(X, y, W, reg, iters=_SVC_STEPS, sweep=False):
     """Fit B linear SVCs at once. W: (B, n) row weights; reg: (B,).
     Each GD step is two shared (n,d)@(d,B) matmuls. ``sweep``: bf16 (n, B)
     margin/gradient temps (f32 reduction accumulates) — see
@@ -855,7 +883,7 @@ def _fit_svc_batch(X, y, W, reg, iters=100, sweep=False):
     return std.unscale(A, b)
 
 
-def _fit_svc(X, y, w, reg, iters=100):
+def _fit_svc(X, y, w, reg, iters=_SVC_STEPS):
     """Single-config fit: the B=1 slice of the batched solver."""
     coef, bias = _fit_svc_batch(X, y, w[None, :], jnp.asarray([reg], X.dtype),
                                 iters=iters)
@@ -882,6 +910,9 @@ class LinearSVCFamily(ModelFamily):
         coef, bias = _fit_svc_batch(X, y, weights, grid["regParam"],
                                     sweep=True)
         return {"coef": coef, "bias": bias}
+
+    def fit_span_attrs(self, rows, features, grid, num_classes, sweep):
+        return {"matrixPasses": svc_matrix_passes()}
 
     def predict_batch(self, params, X, num_classes):
         # squash margins so threshold-style validation metrics (which cut at

@@ -1410,8 +1410,11 @@ class _TreeFamilyBase(ModelFamily):
         engine's contraction; ``combine``: the spelling `_tree_combine`
         traces for their partials (``"fused"`` on one device, ``"halving"``
         under an engine mesh: call this under the context the fit is
-        traced in)."""
-        return {"histShards": _hist_shards(), "combine": _combine_form()}
+        traced in); ``sampleRows``: the rows the growers' histograms are
+        built from (`_sample_rows`), all a sweep fit reads of the table."""
+        return {"histShards": _hist_shards(), "combine": _combine_form(),
+                "sampleRows": min(rows, _SWEEP_HIST_SAMPLE if sweep
+                                  else _HIST_SAMPLE)}
 
     def select_params(self, batched, idx):
         """Per-config slice, except the bin-edge table, which is shared by

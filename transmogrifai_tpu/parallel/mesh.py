@@ -28,15 +28,20 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 #: model"). Engaging the mesh prices in per-program collectives (psums over
 #: every cross-row reduce of the fit), cross-device layout moves around the
 #: config axis, and the GSPMD partitioner's fixed per-program overhead —
-#: none of which shrink with the problem. Measured on the 8-virtual-device
-#: CPU host (shared cores, so the ratio isolates overhead from parallel
-#: win): at 8192 rows/chip the sharded sweep executes ~2.5x the
-#: single-device fused wall; the overhead first falls inside run-to-run
-#: noise above ~16k rows per chip and a handful of configs per model shard
-#: (CPU, rounds 6-20; never re-measured on the chip: ROADMAP S8). Below
-#: the thresholds the sweep transparently downgrades to the single-device
-#: fused path — bit-identical results, observable via
-#: tg_mesh_downgrade_total + span event.
+#: none of which shrink with the problem. The thresholds' origin: timed on
+#: eight VIRTUAL CPU devices of one host (shared cores, so the ratio
+#: isolates overhead from parallel win; rounds 6-20): at 8192 rows/chip the
+#: sharded sweep executed ~2.5x the single-device fused wall there; the
+#: overhead first fell inside run-to-run noise above ~16k rows per chip and
+#: a handful of configs per model shard. The TPU has not confirmed them:
+#: the one four-chip cell (`train-airline-10m-mesh`, PR 34: the sweep's
+#: 1 M-row table, 250 000 rows a chip, 135 configs) lies far above both and
+#: engages; it says nothing about where the line is, and a tree family's
+#: sweep fit reads 8192 sampled rows (2048 a chip) whatever the table, a
+#: case the model does not price (PERF.md section 7; ROADMAP S8). Below the
+#: thresholds the sweep
+#: transparently downgrades to the single-device fused path — bit-identical
+#: results, observable via tg_mesh_downgrade_total + span event.
 MESH_MIN_ROWS_PER_CHIP_ENV = "TG_MESH_MIN_ROWS_PER_CHIP"
 MESH_MIN_CONFIGS_PER_CHIP_ENV = "TG_MESH_MIN_CONFIGS_PER_CHIP"
 MESH_FORCE_ENV = "TG_MESH_FORCE"
@@ -67,6 +72,19 @@ def sweep_mesh_decision(mesh: Mesh, n_rows: int,
     }
     engage = rows_per_chip >= min_rows and cfg_per_chip >= min_cfg
     return engage, detail
+
+
+def mesh_span_attrs(mesh: Optional[Mesh], engaged: bool,
+                    rows: int) -> Dict[str, object]:
+    """What a span says of the mesh its program was given: the axes asked
+    for (1 and 1 without a mesh), whether it ``engaged`` (the sweep's cost
+    model may say no), and ``rowsPerChip``: ``rows``, the rows of the table
+    the program reads, over the data axis where it engaged."""
+    shape = mesh.shape if mesh is not None else {}
+    n_data = int(shape.get("data", 1))
+    return {"meshData": n_data, "meshModel": int(shape.get("model", 1)),
+            "engaged": bool(engaged),
+            "rowsPerChip": int(rows) // (n_data if engaged else 1)}
 
 
 @dataclass(frozen=True)

@@ -7,12 +7,16 @@ pjit/XLA insert the psum collectives the reference got from Spark shuffles.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from .collectives import reduce_scatter
+from .mesh import data_parallel_sharding as row_sharding
 
 
 def _pad_to(n: int, mult: int) -> int:
@@ -101,6 +105,106 @@ def shard_table(table, mesh: Mesh):
     if key is not None and pad:
         key = np.concatenate([key, np.full(pad, None, dtype=object)])
     return FeatureTable(cols, num_rows=n_pad, key=key)
+
+
+#: rows of the result each chip receives per step of ``take_rows``: a step's
+#: temporary is ``data x TAKE_BLOCK`` rows on every chip
+TAKE_BLOCK = 65536
+
+
+def place_rows(host, mesh: Mesh, site: str = "mesh.place"):
+    """Upload a HOST array with its rows sharded over 'data': every chip
+    is sent its own rows and no chip ever holds the whole array (a
+    ``jnp.asarray`` first would land it whole on the first device). The
+    row count must divide by the data axis. Spanned as ``mesh.place``."""
+    from ..observability.trace import span
+    from .distributed import retrying_device_put
+    host = np.asarray(host)
+    with span("mesh.place", cat="train", bytes=int(host.nbytes),
+              shards=int(mesh.shape["data"]), path="host_shards",
+              site=site):
+        return retrying_device_put(host, row_sharding(mesh, host.ndim),
+                                   site=site)
+
+
+@partial(jax.jit, static_argnames=("n_pad", "mesh"))
+def pad_rows_sharded(X, n_pad: int, mesh: Mesh):
+    """``jnp.pad`` of the row axis with zeros to ``n_pad`` (a multiple of
+    the data axis) with the result's rows sharded over 'data', whatever
+    ``X``'s placement: one program, no whole copy on any chip."""
+    out = jnp.pad(X, ((0, n_pad - X.shape[0]),) + ((0, 0),) * (X.ndim - 1))
+    return jax.lax.with_sharding_constraint(out, row_sharding(mesh, X.ndim))
+
+
+@partial(jax.jit, static_argnames=("block", "mesh"))
+def _take_rows(X, idx2, block: int, mesh: Mesh):
+    n_data = mesh.shape["data"]
+    n_local = X.shape[0] // n_data
+    m_local = idx2.shape[1]
+    steps = -(-m_local // block)
+    tail = (1,) * (X.ndim - 1)
+
+    def local(x, idx):                # x: this chip's rows; idx: (D, m_l)
+        lo = jax.lax.axis_index("data") * n_local
+
+        def step(j, out):
+            # the last step starts early and does some rows again, so that
+            # every step is ``block`` rows and ``out`` is never sliced
+            at = jnp.minimum(j * block, m_local - block)
+            want = jax.lax.dynamic_slice_in_dim(
+                idx, at, block, axis=1).reshape(-1) - lo
+            mine = (want >= 0) & (want < n_local)
+            rows = jnp.where(mine.reshape((-1,) + tail),
+                             x[jnp.clip(want, 0, n_local - 1)],
+                             jnp.zeros((), x.dtype))
+            # every row is held by exactly one chip: the sum is that row
+            got = reduce_scatter(rows, "data")
+            return jax.lax.dynamic_update_slice_in_dim(out, got, at, axis=0)
+
+        out = jnp.zeros((m_local,) + x.shape[1:], x.dtype)
+        return jax.lax.fori_loop(0, steps, step, out)
+
+    spec = P("data", *([None] * (X.ndim - 1)))
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, P()),
+                         out_specs=spec, check_vma=False)(X, idx2)
+
+
+def take_rows(X, idx, mesh: Mesh, block: int = TAKE_BLOCK,
+              site: str = "mesh.take_rows"):
+    """``X[idx]`` for ``X`` with rows sharded over 'data', the result's
+    rows sharded alike (chip k holds ``idx``'s k-th quarter, in order): the
+    row gather of a table no chip holds whole. ``len(idx)`` must divide by
+    the data axis: callers pad it, with row 0 under a False mask or with
+    -1, which no chip holds and so comes back as a row of zeros.
+
+    Every chip looks up, among ``data x block`` wanted rows at a time, those
+    it holds (zeros elsewhere) and a reduce-scatter hands each chip its
+    ``block`` of the sum: the values are the rows' own bits, no chip holds
+    more than its shard of the result plus one step's temporary, and the
+    rows cross the chip-to-chip links once. An eager ``X[idx]`` is
+    partitioned as a masked gather and an all-reduce: the whole result on
+    every chip."""
+    n_data = mesh.shape["data"]
+    idx = np.asarray(idx)
+    m = int(idx.shape[0])
+    if m % n_data:
+        raise ValueError(f"take_rows: {m} rows do not divide by the data "
+                         f"axis ({n_data})")
+    if X.shape[0] % n_data:          # a table that could not be sharded
+        X = pad_rows_sharded(X, _pad_to(X.shape[0], n_data), mesh)
+    idx2 = idx.astype(np.int32).reshape(n_data, m // n_data)
+    if not isinstance(X, jax.Array) or X.sharding != row_sharding(mesh,
+                                                                  X.ndim):
+        X = jax.device_put(X, row_sharding(mesh, X.ndim))
+    from ..observability.trace import span
+    block = max(min(int(block), m // n_data), 1)
+    # the launch, not the gather: a span adds no sync
+    with span("mesh.take_rows", cat="train", rows=m, rowsPerChip=m // n_data,
+              rowBytes=int(np.prod(X.shape[1:], dtype=np.int64))
+              * X.dtype.itemsize,
+              shards=int(n_data), steps=-(-(m // n_data) // block),
+              site=site):
+        return _take_rows(X, idx2, block=block, mesh=mesh)
 
 
 def sharded_fit_batch(family, X, y, weights, grid: Dict[str, jnp.ndarray],

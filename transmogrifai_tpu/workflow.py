@@ -310,7 +310,9 @@ class OpWorkflow(_WorkflowCore):
                 if _blackbox.blackbox_enabled() else None)
         with fault_log.activate(), _blackbox.correlated(corr), \
                 _root_span("workflow.train", self.profiler, cat="train",
-                           resume=resume, stream=stream is not None):
+                           resume=resume, stream=stream is not None,
+                           chips=(int(self._mesh.devices.size) if getattr(
+                               self, "_mesh", None) is not None else 1)):
             _blackbox.record("workflow.train", resume=resume,
                              stream=stream is not None)
             if stream is not None:
