@@ -766,3 +766,130 @@ def test_the_spans_say_what_the_mesh_did(force_mesh):
     assert by["sweep.family"].attrs["matrixPasses"] == 5 + 8 * (4 + 2 * 6)
     assert by["selector.refit"].attrs["matrixPasses"] == 5 + 10 * (4 + 16)
     assert by["workflow.train"].attrs["chips"] == 1
+
+
+# ---------------------------------------------------------------------------
+# the pivot's block and the combined matrix are born sharded (PR 35)
+# ---------------------------------------------------------------------------
+
+def _pivot_and_combine(table, mesh, reals_to=None):
+    from transmogrifai_tpu import FeatureBuilder, FeatureTable
+    from transmogrifai_tpu.table import Column
+    from transmogrifai_tpu.impl.feature.vectorizers import (
+        OneHotVectorizer, VectorsCombiner)
+    picks = [FeatureBuilder.PickList(c).extract_field().as_predictor()
+             for c in ("shop", "day")]
+    reals = FeatureBuilder.OPVector("reals").extract_field().as_predictor()
+    pivot = OneHotVectorizer(min_support=1)
+    pivot.set_input(*picks)
+    comb = VectorsCombiner()
+    comb.set_input(pivot.get_output(), reals)
+    if mesh is not None:
+        pivot.set_mesh(mesh)
+        comb.set_mesh(mesh)
+    model = pivot.fit(table)
+    block = model.transform_column(table)
+    reals = table["reals"]
+    if reals_to is not None:            # already on a device
+        reals = Column(reals.feature_type,
+                       jax.device_put(reals.values, reals_to), None)
+    out = comb.transform_column(FeatureTable(
+        {pivot.get_output().name: block, "reals": reals}, table.num_rows))
+    return block, out
+
+
+def _pick_table(n, seed=9):
+    from transmogrifai_tpu import FeatureTable
+    from transmogrifai_tpu.table import Column
+    from transmogrifai_tpu.types import OPVector, PickList
+    rng = np.random.RandomState(seed)
+    shop = np.array([f"s{i}" for i in range(6)] + [None], dtype=object)[
+        rng.randint(0, 7, n)]
+    day = np.array(list("mtwTf"), dtype=object)[rng.randint(0, 5, n)]
+    return FeatureTable({
+        "shop": Column(PickList, shop, np.array([v is not None
+                                                 for v in shop])),
+        "day": Column(PickList, day, np.ones(n, bool)),
+        "reals": Column(OPVector, rng.rand(n, 3).astype(np.float32), None),
+    }, n)
+
+
+@pytest.mark.parametrize("n", [4000, 4001], ids=["divides", "odd"])
+def test_the_pivot_block_and_the_combined_matrix_are_born_sharded(
+        monkeypatch, n):
+    """Under ``data=4`` the positions go up as shards, the two programs
+    write shards and no device holds a whole copy of the positions, the
+    block or the matrix; the bits are the one-device host path's. A row
+    count the data axis does not divide stays on one device and still
+    gives the right matrix."""
+    from transmogrifai_tpu.impl.feature import vectorizers
+    from transmogrifai_tpu.parallel.sharded import row_sharding
+    table = _pick_table(n)
+    monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS", 10 ** 12)
+    want_block, want = _pivot_and_combine(table, None)
+    assert isinstance(want_block.values, np.ndarray)
+    monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS", 1000)
+    mesh = make_mesh(MeshSpec(data=4, model=1), devices=jax.devices()[:4])
+    before = {id(a) for a in jax.live_arrays()}
+    went_up = []            # held, so that the uploads are alive below
+    real = vectorizers._upload
+
+    def upload(host, mesh, site):
+        went_up.append(real(host, mesh, site))
+        return went_up[-1]
+    monkeypatch.setattr(vectorizers, "_upload", upload)
+    block, out = _pivot_and_combine(table, mesh)
+    mine = [a for a in jax.live_arrays()
+            if id(a) not in before and a.ndim and a.shape[0] >= n // 2]
+    # two columns' positions, the reals, the block, the matrix
+    assert sorted(a.shape for a in mine) == [
+        (n,), (n,), (n, 3), (n, 8 + 7), (n, 8 + 7 + 3)]
+    if n % 4 == 0:
+        for a in (block.values, out.values):
+            assert a.sharding.is_equivalent_to(row_sharding(mesh, 2), 2)
+        for a in mine:
+            assert _whole_on_a_device(a) is None, _whole_on_a_device(a)
+    else:
+        assert len(out.values.sharding.device_set) == 1
+    np.testing.assert_array_equal(np.asarray(block.values),
+                                  want_block.values)
+    np.testing.assert_array_equal(np.asarray(out.values),
+                                  np.asarray(want.values))
+    assert (out.metadata["vector_meta"].column_names()
+            == want.metadata["vector_meta"].column_names())
+    if n % 4 == 0:
+        # an input that lies whole on one chip is re-placed, chip to chips
+        _, again = _pivot_and_combine(table, mesh, jax.devices()[0])
+        assert _whole_on_a_device(again.values) is None
+        np.testing.assert_array_equal(np.asarray(again.values),
+                                      np.asarray(want.values))
+
+
+def test_a_second_train_under_the_mesh_builds_no_program(force_mesh,
+                                                         monkeypatch):
+    """The pivot's and the combiner's programs are keyed by shape, widths
+    and sharding: a second ``train()`` on the same table finds every
+    program (the counter ``compiles_in_window`` reads)."""
+    from benchmark.harness import COMPILE_EVENT, Monitor
+    from transmogrifai_tpu.impl.feature import vectorizers
+    monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS", 1000)
+    df = _mesh_table()
+    mesh = make_mesh(MeshSpec(data=4, model=1), devices=jax.devices()[:4])
+    seen = []
+    real = vectorizers._pivot_block
+
+    def spy(positions, widths, mesh):
+        seen.append((len(positions), widths, mesh is not None))
+        return real(positions, widths=widths, mesh=mesh)
+    monkeypatch.setattr(vectorizers, "_pivot_block", spy)
+    monitor = Monitor().install()
+    first = _train(df, "OpLogisticRegression", mesh)
+    monitor.phase = "second"
+    second = _train(df, "OpLogisticRegression", mesh)
+    monitor.phase = "after"
+    assert seen == [(1, (6 + 2,), True)] * 2        # the device path ran
+    assert monitor.count("setup", COMPILE_EVENT) > 0
+    assert monitor.count("second", COMPILE_EVENT) == 0
+    for k, v in first.fitted.params.items():
+        np.testing.assert_array_equal(np.asarray(second.fitted.params[k]),
+                                      np.asarray(v))

@@ -173,6 +173,49 @@ def test_one_hot_spans_one_per_column(spans):
     assert concat.attrs["bytes"] == ROWS * (6 + 4) * 4
 
 
+def _combiner_span(spans):
+    (s,) = [s for s in _named(spans, "stage.transform")
+            if s.attrs["stage"] == "VectorsCombiner"]
+    return s
+
+
+def test_the_host_path_says_so(spans):
+    """600 rows lie under the row constant: the host writes the blocks and
+    joins them, and the whole matrix goes up."""
+    _, mine = _root(spans, "workflow.train")
+    assert [s.attrs["path"] for s in _named(mine, "onehot.expand")] == [
+        "host", "host"]
+    attrs = _combiner_span(mine).attrs
+    assert (attrs["deviceInputs"], attrs["hostInputs"],
+            attrs["h2dBytes"]) == (0, 2, ROWS * (4 + 10) * 4)
+
+
+def test_the_device_path_says_so(monkeypatch):
+    """Over the row constant: every ``onehot.expand`` says ``"device"``,
+    ``onehot.concat`` still says the block's bytes, and the combiner says
+    that the block stayed where it was and only the reals went up."""
+    from transmogrifai_tpu.impl.feature import vectorizers
+    monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS", ROWS)
+    ot.reset()
+    ot.enable_tracing(True)
+    try:
+        wf, _ = _workflow(_df())
+        wf.train()
+        spans = ot.tracer().finished()
+    finally:
+        ot.reset()
+    _, mine = _root(spans, "workflow.train")
+    expands = _named(mine, "onehot.expand")
+    assert [s.attrs for s in expands] == [
+        {"column": "c1", "path": "device"}, {"column": "c2", "path": "device"}]
+    (concat,) = _named(mine, "onehot.concat")
+    assert concat.attrs["bytes"] == ROWS * (6 + 4) * 4
+    attrs = _combiner_span(mine).attrs
+    # x1, x2 and their null flags: four float32 columns from the host
+    assert (attrs["deviceInputs"], attrs["hostInputs"],
+            attrs["h2dBytes"]) == (1, 1, ROWS * 4 * 4)
+
+
 @pytest.mark.parametrize("values,path", [
     (["a", "b", "a", None], "hashed"),
     (list(np.array(["a", "b", "a"])), "hashed"),        # numpy's str

@@ -312,3 +312,250 @@ def test_hashing_raw_mixed_objects_would_not_pass(monkeypatch):
         test_pivot_equals_the_per_row_loops(
             "smart_text", "mixed_1_True_str1_float1", 1)
     test_pivot_equals_the_per_row_loops("one_hot", "all_str", 64)
+
+
+# -- the chip makes the dense block from positions (PR 35) --------------------
+
+def _pivot_on(monkeypatch, path, stage, fit_table, table):
+    """``stage`` fitted on ``fit_table`` and applied to ``table`` with the
+    row constant set so that ``path`` is taken; (column, its spans)."""
+    from transmogrifai_tpu.impl.feature import vectorizers
+    from transmogrifai_tpu.observability import trace as ot
+    monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS",
+                        1 if path == "device" else 10 ** 12)
+    model = stage.fit(fit_table)
+    ot.reset()
+    ot.enable_tracing(True)
+    try:
+        col = model.transform_column(table)
+        spans = ot.tracer().finished()
+    finally:
+        ot.reset()
+    return col, spans
+
+
+def _meta_tuples(col):
+    return [(c.parent_feature_name, c.parent_feature_type, c.grouping,
+             c.indicator_value, c.descriptor_value, c.index)
+            for c in col.metadata["vector_meta"].columns]
+
+
+@pytest.mark.parametrize("name", [n for n in PIVOT_CASES if n not in (
+    "empty_table",)])
+def test_device_pivot_equals_the_host_pivot_bit_for_bit(monkeypatch, name):
+    """Nulls tracked and not, levels outside the vocabulary, an all-null
+    column, a ``top_k`` cut, ties, a column that is no ``str``: values AND
+    ``vector_meta`` of the block the chip wrote from positions are the
+    host block's."""
+    import jax
+    case = PIVOT_CASES[name]
+    reps = 1 if name in ("one_row", "one_null_row") else 64
+    feat = FeatureBuilder.PickList("c").extract_field().as_predictor()
+    fit_col = _scalar_column(PickList, case["values"], case["masked"], reps)
+    seen = (fit_col if case["transform"] is None
+            else _scalar_column(PickList, case["transform"], (), reps))
+    got = {}
+    for path in ("host", "device"):
+        st = OneHotVectorizer(top_k=case["top_k"],
+                              min_support=case["min_support"] * reps,
+                              track_nulls=case["track_nulls"])
+        st.set_input(feat)
+        col, spans = _pivot_on(
+            monkeypatch, path, st, FeatureTable({"c": fit_col}, len(fit_col)),
+            FeatureTable({"c": seen}, len(seen)))
+        assert [s.attrs["path"] for s in spans
+                if s.name == "onehot.expand"] == [path]
+        got[path] = col
+    assert isinstance(got["host"].values, np.ndarray)
+    assert isinstance(got["device"].values, jax.Array)
+    dev = np.asarray(got["device"].values)
+    assert dev.dtype == np.float32 and dev.shape == got["host"].values.shape
+    assert np.array_equal(dev, got["host"].values)
+    assert _meta_tuples(got["device"]) == _meta_tuples(got["host"])
+    if name == "ints":      # what the spans call "str_pass"
+        assert {s.attrs["path"] for s in spans
+                if s.name == "onehot.encode"} == {"str_pass"}
+
+
+@pytest.mark.parametrize("rows_off", [-1, 0], ids=["just_under", "at"])
+def test_the_row_constant_decides_the_pivots_path(rows_off):
+    """Several columns at the constant itself (not patched): one row fewer
+    and the host writes the block."""
+    import jax
+    from transmogrifai_tpu.impl.feature import vectorizers
+    from transmogrifai_tpu.table import Column
+    n = vectorizers._DEVICE_BLOCK_MIN_ROWS + rows_off
+    rng = np.random.RandomState(5)
+    feats, cols = [], {}
+    for j, k in enumerate((3, 40)):
+        lv = np.array([f"v{i}" for i in range(k)] + [None], dtype=object)
+        vals = lv[rng.randint(0, k + 1, size=n)]
+        cols[f"c{j}"] = Column(PickList, vals,
+                               np.array([v is not None for v in vals]))
+        feats.append(FeatureBuilder.PickList(f"c{j}").extract_field()
+                     .as_predictor())
+    table = FeatureTable(cols, n)
+    st = OneHotVectorizer(top_k=8)
+    st.set_input(*feats)
+    model = st.fit(table)
+    col = model.transform_column(table)
+    assert isinstance(col.values, jax.Array if rows_off == 0 else np.ndarray)
+    vals = np.asarray(col.values)
+    assert vals.shape == (n, (3 + 2) + (8 + 2)) and vals.dtype == np.float32
+    # every row has exactly one 1 in each column's block
+    assert np.array_equal(vals[:, :5].sum(axis=1), np.ones(n, np.float32))
+    assert np.array_equal(vals[:, 5:].sum(axis=1), np.ones(n, np.float32))
+    # c0: vocabulary by count, then OTHER (never met), then the nulls
+    c0 = cols["c0"]
+    for i, v in enumerate(model.vocabs[0]):
+        assert np.array_equal(vals[:, i] == 1.0, c0.values == v)
+    assert not vals[:, 3].any()
+    assert np.array_equal(vals[:, 4] == 1.0, ~c0.mask)
+
+
+def test_a_multipicklist_input_keeps_the_host_path(monkeypatch):
+    from transmogrifai_tpu.table import Column
+    tags = FeatureBuilder.MultiPickList("t").extract_field().as_predictor()
+    pick = FeatureBuilder.PickList("c").extract_field().as_predictor()
+    sets = [{"a", "b"}, {"a"}, set(), None, {"c", "a"}] * 20
+    picks = ["x", "y", None, "x", "z"] * 20
+    table = FeatureTable({"t": Column.of_values(MultiPickList, sets),
+                          "c": Column.of_values(PickList, picks)}, 100)
+    got = {}
+    for path in ("host", "device"):
+        st = OneHotVectorizer(top_k=3, min_support=1)
+        st.set_input(tags, pick)
+        got[path], spans = _pivot_on(monkeypatch, path, st, table, table)
+        # a model with a multi-valued column takes the host path whole
+        assert [s.attrs["path"] for s in spans
+                if s.name == "onehot.expand"] == ["host", "host"]
+        assert isinstance(got[path].values, np.ndarray)
+    assert np.array_equal(got["host"].values, got["device"].values)
+
+
+def _categorical_workflow(df):
+    from transmogrifai_tpu.impl.selector.factories import (
+        BinaryClassificationModelSelector,
+    )
+    label = FeatureBuilder.RealNN("y").extract_field().as_response()
+    feats = [FeatureBuilder.Real("x1").extract_field().as_predictor(),
+             FeatureBuilder.PickList("c1").extract_field().as_predictor(),
+             FeatureBuilder.PickList("c2").extract_field().as_predictor()]
+    checked = transmogrify(feats).sanity_check(label)
+    pred = (BinaryClassificationModelSelector.with_cross_validation(
+        models=[("OpLogisticRegression", [{"regParam": 0.0135,
+                                           "elasticNetParam": 0.0}])])
+            .set_input(label, checked).get_output())
+    return OpWorkflow().set_input_dataset(df).set_result_features(pred), pred
+
+
+def test_a_score_over_the_row_constant_is_the_same_planned_and_eager(
+        monkeypatch):
+    import pandas as pd
+    from transmogrifai_tpu import plan as plan_mod
+    from transmogrifai_tpu.impl.feature import vectorizers
+    rng = np.random.RandomState(3)
+    n = 700
+    df = pd.DataFrame({
+        "x1": rng.randn(n),
+        "c1": rng.choice(["a", "b", "c", None], size=n),
+        "c2": rng.choice(["u", "v"], size=n)})
+    df["y"] = ((df.x1 + (df.c1 == "a") - (df.c2 == "u")) > 0).astype(float)
+    scores = {}
+    for path, rows in (("host", 10 ** 12), ("device", 256)):
+        monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS", rows)
+        wf, pred = _categorical_workflow(df)
+        model = wf.train()
+        planned = np.asarray(model.score(df=df)[pred.name].values)
+        plan_mod.enable_planning(False)
+        try:
+            eager = np.asarray(model.score(df=df)[pred.name].values)
+        finally:
+            plan_mod.enable_planning(None)
+        assert np.array_equal(planned, eager), path
+        scores[path] = planned
+    # and the chip's block trains and scores to the host block's bits
+    assert np.array_equal(scores["host"], scores["device"])
+
+
+def test_the_combiner_joins_on_the_chip_and_reads_nothing_back(monkeypatch):
+    """Mixed inputs: a device block, a host matrix, a host vector. The
+    result is ``np.concatenate`` of their host values; only the host
+    inputs' bytes go up; no device input is read to the host."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src.array import ArrayImpl
+    from transmogrifai_tpu.impl.feature import vectorizers
+    from transmogrifai_tpu.observability import metrics as om, trace as ot
+    from transmogrifai_tpu.table import Column
+    from transmogrifai_tpu.types import OPVector
+    monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS", 64)
+    n = 100
+    rng = np.random.RandomState(2)
+    host = {"a": rng.rand(n, 5).astype(np.float32),
+            "b": rng.rand(n, 3).astype(np.float32),
+            "c": rng.rand(n).astype(np.float32)}
+    feats = [FeatureBuilder.OPVector(k).extract_field().as_predictor()
+             for k in ("a", "b")]
+    feats.append(FeatureBuilder.Real("c").extract_field().as_predictor())
+    table = FeatureTable({
+        "a": Column(OPVector, jnp.asarray(host["a"]), None),
+        "b": Column(OPVector, host["b"], None),
+        "c": Column(Real, host["c"], None)}, n)
+    comb = VectorsCombiner()
+    comb.set_input(*feats)
+    om.reset()
+    om.enable_metrics(True)
+    ot.reset()
+    ot.enable_tracing(True)
+
+    def read_back(self, *a, **kw):
+        raise AssertionError("a device array was read to the host")
+    real = ArrayImpl.__array__
+    try:
+        with ot.span("stage.transform", stage="VectorsCombiner") as sp:
+            monkeypatch.setattr(ArrayImpl, "__array__", read_back)
+            col = comb.transform_column(table)
+            monkeypatch.setattr(ArrayImpl, "__array__", real)
+        moved = om.registry().snapshot()["tg_transfer_bytes_total"]
+    finally:
+        monkeypatch.setattr(ArrayImpl, "__array__", real)
+        om.reset()
+        ot.reset()
+    assert moved == {"direction=h2d": float(n * 3 * 4 + n * 4)}
+    assert sp.attrs == {"stage": "VectorsCombiner", "deviceInputs": 1,
+                        "hostInputs": 2, "h2dBytes": n * 3 * 4 + n * 4}
+    assert isinstance(col.values, jax.Array)
+    assert np.array_equal(np.asarray(col.values), np.concatenate(
+        [host["a"], host["b"], host["c"][:, None]], axis=1))
+    assert [c.parent_feature_name
+            for c in col.metadata["vector_meta"].columns] == \
+        ["a"] * 5 + ["b"] * 3 + ["c"]
+    # one device input alone is handed on as it is
+    one = VectorsCombiner()
+    one.set_input(feats[0])
+    assert one.transform_column(table).values is table["a"].values
+
+
+def test_the_pivot_sends_positions_and_nothing_else(monkeypatch):
+    """``tg_transfer_bytes_total`` of a device-path pivot: four bytes a row
+    and column, against the block's (k + 2) x 4."""
+    from transmogrifai_tpu.impl.feature import vectorizers
+    from transmogrifai_tpu.observability import metrics as om
+    monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS", 64)
+    table = FeatureTable.from_columns({
+        "c": (PickList, ["a", "b", None, "a"] * 50),
+        "d": (PickList, ["p", "q", "r", "s"] * 50)})
+    st = OneHotVectorizer(min_support=1)
+    st.set_input(*[FeatureBuilder.PickList(k).extract_field().as_predictor()
+                   for k in ("c", "d")])
+    model = st.fit(table)
+    om.reset()
+    om.enable_metrics(True)
+    try:
+        col = model.transform_column(table)
+        moved = om.registry().snapshot()["tg_transfer_bytes_total"]
+    finally:
+        om.reset()
+    assert moved == {"direction=h2d": 200 * 2 * 4.0}
+    assert col.values.shape == (200, 4 + 6)

@@ -9,22 +9,30 @@ VectorsCombiner.scala, TransmogrifierDefaults Transmogrifier.scala:52-90).
 Execution split: statistics and string handling (vocab counts, tokenizing,
 hashing) run host-side in vectorized numpy — they are string work the TPU
 cannot express — and emit dense float32 blocks; everything downstream (models,
-stats, scoring) consumes the resulting device arrays. Null semantics match the
-reference: mean/mode fill + a tracked null-indicator column per feature.
+stats, scoring) consumes the resulting device arrays. From
+``_DEVICE_BLOCK_MIN_ROWS`` rows on, the pivot hands the device each row's
+position in its column's block and the device writes the dense block, and
+the combiner joins its inputs there: no host array of (rows x derived
+columns) is made. Null semantics match the reference: mean/mode fill + a
+tracked null-indicator column per feature.
 """
 from __future__ import annotations
 
 import weakref
 import zlib
 from collections import Counter
+from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 
 from ...features import Feature
-from ...observability.trace import span as _obs_span
+from ...observability.trace import span as _obs_span, tracer as _obs_tracer
 from ...parallel.distributed import _count_transfer_bytes
+from ...parallel.sharded import place_rows, row_sharding
 from ...stages.base import Estimator, SequenceTransformer, Transformer, UnaryTransformer
 from ...table import Column, FeatureTable
 from ...types import (
@@ -413,21 +421,68 @@ def _keep_fit_codes(vals: np.ndarray, m: np.ndarray, codes: np.ndarray,
     _FIT_CODES[key] = (ref, m, codes, levels, path)
 
 
-def _encode_valid(vals: np.ndarray, m: np.ndarray, index: Dict[str, int]
-                  ) -> Tuple[np.ndarray, str]:
-    """Each row's vocabulary index under ``index`` (-1 for a level not in
-    it, -2 where ``m`` is false) and the path ``_factorize_valid`` took:
-    the dictionary is asked once per level, not once per row. The codes are
-    the fit's own where this is the array (and the mask) it counted."""
+def _encode_valid(vals: np.ndarray, m: np.ndarray, index: Dict[str, int],
+                  track_nulls: bool) -> Tuple[np.ndarray, str]:
+    """Each row's position inside its pivot block (int32: the vocabulary
+    index under ``index``, ``k = len(index)`` for a level not in it and,
+    where ``m`` is false, ``k + 1`` if nulls are tracked and -1, no column,
+    if not) and the path ``_factorize_valid`` took: the dictionary is asked
+    once per level, not once per row. The codes are the fit's own where
+    this is the array (and the mask) it counted."""
     kept = _FIT_CODES.pop(id(vals), None)
     if kept is not None and kept[0]() is vals and np.array_equal(kept[1], m):
         _, _, codes, levels, path = kept
     else:
         codes, counts, path = _factorize_valid(vals, m)
         levels = list(counts)
+    k = len(index)
     # the last entry is the one the null rows' -1 reaches
-    lut = np.array([index.get(v, -1) for v in levels] + [-2], dtype=np.int64)
+    lut = np.array([index.get(v, k) for v in levels]
+                   + [k + 1 if track_nulls else -1], dtype=np.int32)
     return lut[codes], path
+
+
+#: rows from which the chip makes the dense blocks: the pivot hands it each
+#: column's positions and one program writes the block there, the combiner
+#: joins its inputs there; below it the host writes and joins them (PERF.md
+#: section 6, PR 35: where positions + dispatch beat block + upload on the
+#: chip). An eager stage sees the caller's exact row count and a program
+#: compiles once per count, so a stream of small batches of many sizes
+#: (serving, micro-batch scoring, ``transform_row``) never meets either.
+_DEVICE_BLOCK_MIN_ROWS = 262144
+
+
+def _upload(host: np.ndarray, mesh, site: str):
+    """A host array onto the device, its bytes counted: as shards of rows
+    under ``mesh`` (whose data axis the caller saw divide the rows), whole
+    on the default device without one."""
+    if mesh is not None:
+        return place_rows(host, mesh, site=site)
+    arr = jnp.asarray(host)
+    _count_transfer_bytes(arr, "h2d")
+    return arr
+
+
+def _rows_mesh(mesh, n: int):
+    """``mesh`` where its data axis divides ``n`` rows, else None: a table
+    that cannot be split evenly stays on one device (padding here would
+    change its row count; consumers that need exact shards re-pad with
+    masked rows, see ``shard_rows``)."""
+    return mesh if mesh is not None and n % mesh.shape["data"] == 0 else None
+
+
+@partial(jax.jit, static_argnames=("widths", "mesh"))
+def _pivot_block(positions, widths: Tuple[int, ...], mesh):
+    """Each column's (n,) positions → the (n, sum(widths)) float32 block:
+    a one where a row's position meets the column's index, so -1 leaves its
+    row empty. Rows are independent: under a mesh every chip writes its
+    own."""
+    out = jnp.concatenate(
+        [(p[:, None] == jnp.arange(w, dtype=p.dtype)[None, :]
+          ).astype(jnp.float32) for p, w in zip(positions, widths)], axis=1)
+    if mesh is not None:
+        out = jax.lax.with_sharding_constraint(out, row_sharding(mesh, 2))
+    return out
 
 
 class OneHotVectorizer(Estimator):
@@ -444,6 +499,13 @@ class OneHotVectorizer(Estimator):
         self.top_k = top_k
         self.min_support = min_support
         self.track_nulls = track_nulls
+        self.mesh = None
+
+    def set_mesh(self, mesh) -> "OneHotVectorizer":
+        """The fitted model writes its block with the rows sharded over the
+        mesh's 'data' axis (SURVEY §2.10 P1)."""
+        self.mesh = mesh
+        return self
 
     def fit(self, table: FeatureTable) -> Transformer:
         vocabs: List[List[str]] = []
@@ -463,6 +525,7 @@ class OneHotVectorizer(Estimator):
                 vocabs.append(_top_levels(cnt, self.min_support, self.top_k))
                 count_span.set_attr(levels=len(cnt))
         model = OneHotVectorizerModel(vocabs=vocabs, track_nulls=self.track_nulls)
+        model.mesh = getattr(self, "mesh", None)     # run-time, never saved
         return self._finalize_model(model)
 
 
@@ -471,48 +534,66 @@ class OneHotVectorizerModel(_VectorModelBase):
         super().__init__("pivot", uid)
         self.vocabs = vocabs
         self.track_nulls = track_nulls
+        self.mesh = None
 
     def transform_column(self, table: FeatureTable) -> Column:
         n = table.num_rows
-        blocks, meta = [], []
-        for f, vocab in zip(self.input_features, self.vocabs):
-            col = table[f.name]
+        cols = [table[f.name] for f in self.input_features]
+        # the chip makes the block from positions where every column has one
+        # per row (a multi-valued column has none) and the table is large
+        on_device = n >= _DEVICE_BLOCK_MIN_ROWS and not any(
+            c.kind == "multipicklist" for c in cols)
+        mesh = _rows_mesh(getattr(self, "mesh", None), n)
+        blocks, widths, meta = [], [], []
+        for f, vocab, col in zip(self.input_features, self.vocabs, cols):
             vals = np.asarray(col.values)
             m = col.valid_mask()
             k = len(vocab)
-            block = np.zeros((n, k + 1 + (1 if self.track_nulls else 0)),
-                             dtype=np.float32)
+            widths.append(k + 1 + (1 if self.track_nulls else 0))
             index = {v: i for i, v in enumerate(vocab)}
             multi = col.kind == "multipicklist"
-            # values to codes: one hash pass and a look-up per level (a
-            # multi-valued column has no code per row and fills its block
-            # as it goes)
+            # values to positions: one hash pass and a look-up per level (a
+            # multi-valued column fills its block as it goes)
             with _obs_span("onehot.encode", column=f.name) as encode_span:
                 if multi:
+                    block = np.zeros((n, widths[-1]), dtype=np.float32)
                     for i, (vs, ok) in enumerate(zip(vals, m)):
                         if not ok:
                             continue
                         for v in (vs or ()):
                             block[i, index.get(v, k)] = 1.0
                 else:
-                    codes, path = _encode_valid(vals, m, index)
+                    pos, path = _encode_valid(vals, m, index,
+                                              self.track_nulls)
                     encode_span.set_attr(path=path)
-            # codes to the dense block
-            with _obs_span("onehot.expand", column=f.name):
-                if not multi:
-                    rows = np.arange(n)
-                    hit = codes >= 0
-                    block[rows[hit], codes[hit]] = 1.0
-                    block[rows[codes == -1], k] = 1.0
-                if self.track_nulls:
-                    block[~m, k + 1] = 1.0
+            # positions to the dense block: on the host, or on their way to
+            # the chip (the launch of the upload)
+            with _obs_span("onehot.expand", column=f.name,
+                           path="device" if on_device else "host"):
+                if on_device:
+                    block = _upload(pos, mesh, "onehot.upload")
+                elif multi:
+                    if self.track_nulls:
+                        block[~m, k + 1] = 1.0
+                else:
+                    block = np.zeros((n, widths[-1]), dtype=np.float32)
+                    rows = np.flatnonzero(pos >= 0)
+                    block[rows, pos[rows]] = 1.0
             blocks.append(block)
             mc = [(f.name, v) for v in vocab] + [(f.name, OTHER_INDICATOR)]
             if self.track_nulls:
                 mc.append((f.name, NULL_INDICATOR))
             meta.extend(_meta_cols(f, mc))
+        # the blocks joined: on the device path the launch of the one
+        # program that writes them
         with _obs_span("onehot.concat") as concat_span:
-            out = self._emit(np.concatenate(blocks, axis=1), meta)
+            if on_device:
+                out = Column(OPVector, _pivot_block(
+                    tuple(blocks), widths=tuple(widths), mesh=mesh), None,
+                    {"vector_meta": VectorMetadata.of(
+                        self.get_output().name, meta)})
+            else:
+                out = self._emit(np.concatenate(blocks, axis=1), meta)
             concat_span.set_attr(bytes=int(out.values.nbytes))
         return out
 
@@ -1007,8 +1088,7 @@ class SmartTextVectorizerModel(_VectorModelBase):
                 k = len(vocab)
                 block = np.zeros((n, k + 1), dtype=np.float32)
                 index = {v: i for i, v in enumerate(vocab)}
-                codes = _encode_valid(vals, m, index)[0][m]
-                block[m, np.where(codes >= 0, codes, k)] = 1.0
+                block[m, _encode_valid(vals, m, index, False)[0][m]] = 1.0
                 blocks.append(block)
                 meta.extend(_meta_cols(
                     f, [(f.name, v) for v in vocab] + [(f.name, OTHER_INDICATOR)]))
@@ -1028,6 +1108,26 @@ class SmartTextVectorizerModel(_VectorModelBase):
 # ---------------------------------------------------------------------------
 # Combiner
 # ---------------------------------------------------------------------------
+
+@partial(jax.jit, static_argnames=("widths", "mesh"))
+def _join_columns(parts, widths: Tuple[int, ...], mesh):
+    """Device arrays of the same rows, each ``(n, w)`` or ``(n,)``, → the
+    ``(n, sum(widths))`` float32 matrix of them side by side, its rows
+    sharded over ``mesh``'s 'data' axis where there is one."""
+    out = jnp.concatenate([p.reshape(-1, w).astype(jnp.float32)
+                           for p, w in zip(parts, widths)], axis=1)
+    if mesh is not None:
+        out = jax.lax.with_sharding_constraint(out, row_sharding(mesh, 2))
+    return out
+
+
+def _say_on_stage_span(**attrs: Any) -> None:
+    """Attributes on the ``stage.transform`` span around the caller, where
+    there is one."""
+    s = _obs_tracer().current()
+    if s is not None and s.name == "stage.transform":
+        s.set_attr(**attrs)
+
 
 class VectorsCombiner(SequenceTransformer):
     """Seq[OPVector] → OPVector concatenation with metadata flattening
@@ -1057,39 +1157,55 @@ class VectorsCombiner(SequenceTransformer):
         return jnp.concatenate(blocks, axis=1), None
 
     def transform_column(self, table: FeatureTable) -> Column:
-        blocks, metas = [], []
+        """One device matrix of the inputs side by side, which every
+        downstream consumer (SanityChecker, ModelSelector, scoring) reuses.
+        From ``_DEVICE_BLOCK_MIN_ROWS`` rows on, an input that is on the
+        device stays there, each host input goes up by itself and one
+        program joins them; a smaller table is joined on the host and goes
+        up whole. Under the mesh what goes up goes as shards of rows."""
+        n = table.num_rows
+        mesh = _rows_mesh(getattr(self, "mesh", None), n)
+        arrs, widths, metas = [], [], []
         for f in self.input_features:
             col = table[f.name]
-            arr = np.asarray(col.values, dtype=np.float32)
-            if arr.ndim == 1:
-                arr = arr[:, None]
-            blocks.append(arr)
+            arrs.append(col.values)
+            widths.append(col.width)
             vm = col.metadata.get("vector_meta")
             if vm is None:
                 vm = VectorMetadata.of(f.name, [
                     VectorColumnMetadata(f.name, f.type_name, None, None,
                                          descriptor_value=f"col_{j}")
-                    for j in range(arr.shape[1])])
+                    for j in range(widths[-1])])
             metas.append(vm)
         vm = VectorMetadata.flatten(self.get_output().name, metas)
-        mat = np.concatenate(blocks, axis=1)
-        assert vm.size == mat.shape[1], (vm.size, mat.shape)
-        # one host→device upload here; every downstream consumer
-        # (SanityChecker, ModelSelector, scoring) reuses the device buffer
-        import jax.numpy as jnp
-        mesh = getattr(self, "mesh", None)
-        if mesh is not None and mat.shape[0] % mesh.shape["data"] == 0:
-            # row-sharded upload (only when rows split evenly — padding here
-            # would change the table's row count; consumers that need exact
-            # shards re-pad internally with masked rows, see shard_rows).
-            # Straight from the host array: each chip is sent its own rows
-            # (place_rows counts the bytes)
-            from ...parallel.sharded import place_rows
-            arr = place_rows(mat, mesh, site="combiner.upload")
+        on_device = [isinstance(a, jax.Array) for a in arrs]
+        if (len(arrs) == 1 and on_device[0] and arrs[0].ndim == 2
+                and arrs[0].dtype == jnp.float32):
+            mat, stayed, h2d_bytes = arrs[0], 1, 0    # handed on as it is
+        elif n < _DEVICE_BLOCK_MIN_ROWS or len(arrs) == 1:
+            blocks = [np.asarray(a, dtype=np.float32).reshape(n, w)
+                      for a, w in zip(arrs, widths)]
+            mat = blocks[0] if len(blocks) == 1 else np.concatenate(
+                blocks, axis=1)
+            stayed, h2d_bytes = 0, mat.nbytes
+            mat = _upload(mat, mesh, "combiner.upload")
         else:
-            arr = jnp.asarray(mat)
-            _count_transfer_bytes(arr, "h2d")
-        return Column(OPVector, arr, None, {"vector_meta": vm})
+            parts, stayed, h2d_bytes = [], sum(on_device), 0
+            for a, dev in zip(arrs, on_device):
+                if not dev:
+                    a = np.asarray(a, dtype=np.float32)
+                    h2d_bytes += a.nbytes
+                    a = _upload(a, mesh, "combiner.upload")
+                elif mesh is not None and not a.sharding.is_equivalent_to(
+                        row_sharding(mesh, a.ndim), a.ndim):
+                    a = jax.device_put(a, row_sharding(mesh, a.ndim))
+                parts.append(a)
+            mat = _join_columns(tuple(parts), widths=tuple(widths),
+                                mesh=mesh)
+        assert vm.size == mat.shape[1], (vm.size, mat.shape)
+        _say_on_stage_span(deviceInputs=stayed, hostInputs=len(arrs) - stayed,
+                           h2dBytes=h2d_bytes)
+        return Column(OPVector, mat, None, {"vector_meta": vm})
 
     def transform_row(self, row: Dict[str, Any]) -> Any:
         out: List[float] = []
