@@ -201,3 +201,81 @@ def test_the_mesh_combine_sums_two_operands_a_step(topo, piece):
     groups = _all_reduce_group_sizes(text)
     assert groups, "rows are sharded: the combine must cross devices"
     assert all(0 < g <= 2 for g in groups), groups
+
+
+def _matrix_reads(text, rows, features):
+    """Reads of a ``(rows, features)`` array in one run of a compiled
+    module: every scheduled instruction of the entry computation and of the
+    loops' bodies with such an operand counts one read an operand, times
+    the trip counts of the loops around it (the bound its condition
+    compares with). The text names operands without their shapes, so each
+    is looked up where it is defined; tuples, bitcasts and the loops
+    themselves move no bytes."""
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            cur = head.group(2)
+            comps[cur] = []
+            entry = cur if head.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            comps[cur].append(line.strip().replace("ROOT ", "").split(
+                " = ", 1))
+    wanted = re.compile(r"(f32|bf16)\[%d,%d\]" % (rows, features))
+    moves_nothing = {"parameter", "get-tuple-element", "tuple", "bitcast",
+                     "while", "constant", "copy-start", "copy-done"}
+
+    def reads(comp):
+        defined = dict(comps[comp])
+        total = 0
+        for _, rhs in comps[comp]:
+            op = re.match(r"(?:\(.*?\)|\S+) ([\w\-]+)\(", rhs)
+            if op.group(1) == "while":
+                cond = re.search(r"condition=%?([\w.\-]+)", rhs).group(1)
+                body = re.search(r"body=%?([\w.\-]+)", rhs).group(1)
+                (trips,) = {int(c) for _, r in comps[cond]
+                            for c in re.findall(r"constant\((\d+)\)", r)}
+                total += trips * reads(body)
+            elif op.group(1) not in moves_nothing:
+                operands = rhs[op.end():].split("), ")[0]
+                total += sum(
+                    bool(wanted.match(defined.get("%" + name, "")))
+                    for name in re.findall(r"%([\w.\-]+)", operands))
+        return total
+    return reads(entry)
+
+
+@pytest.mark.parametrize("solver,lanes,sweep", [
+    ("logreg", 6, True), ("logreg", 1, False),
+    ("svc", 3, True), ("svc", 1, False)])
+def test_matrix_passes_is_the_compiled_programs_count(topo, solver, lanes,
+                                                      sweep):
+    """``matrixPasses`` (what ``mesh_refit_roofline`` multiplies a chip's
+    rows by) against the binary solvers' programs as the chip's compiler
+    makes them under a ``data=4`` mesh: the sweep's lanes in bfloat16 at
+    the sweep's schedule, the refit's one float32 lane at the refit's
+    (PERF.md Open (k): the attribute was a count of the schedule's
+    products, and the compiler fuses three of the refit's into one)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from transmogrifai_tpu.models import linear
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    n, d = 8192, 12
+
+    def shape(dims, spec):
+        return jax.ShapeDtypeStruct(dims, jnp.float32,
+                                    sharding=NamedSharding(mesh, spec))
+    args = [shape((n, d), P("data", None)), shape((n,), P("data")),
+            shape((lanes, n), P(None, "data")), shape((lanes,), P())]
+    if solver == "logreg":
+        newton, cg = linear._LOGREG_STEPS[sweep]
+        compiled = linear._fit_logreg_batch.lower(
+            *args, shape((lanes,), P()), newton_iters=newton, cg_iters=cg,
+            sweep=sweep).compile()
+        said = linear.logreg_matrix_passes(sweep)
+    else:
+        compiled = linear._fit_svc_batch.lower(*args, sweep=sweep).compile()
+        said = linear.svc_matrix_passes(sweep)
+    assert _matrix_reads(compiled.as_text(), n // 4, d) == said

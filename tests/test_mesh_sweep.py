@@ -764,7 +764,7 @@ def test_the_spans_say_what_the_mesh_did(force_mesh):
     assert (by["sweep.family"].attrs["meshData"],
             by["sweep.family"].attrs["engaged"]) == (1, False)
     assert by["sweep.family"].attrs["matrixPasses"] == 5 + 8 * (4 + 2 * 6)
-    assert by["selector.refit"].attrs["matrixPasses"] == 5 + 10 * (4 + 16)
+    assert by["selector.refit"].attrs["matrixPasses"] == 4 + 10 * (3 + 16)
     assert by["workflow.train"].attrs["chips"] == 1
 
 

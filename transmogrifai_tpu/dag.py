@@ -110,7 +110,7 @@ def fit_and_transform_dag(table: FeatureTable, layers: List[StageLayer],
                         # mid-DAG with earlier stages already checkpointed
                         faults.inject("preempt.stage_fit", key=stage.uid)
                         faults.inject("dag.stage_fit", key=stage.uid)
-                        with _obs_span("stage.fit", cat="train",
+                        with _obs_span("stage.fit", cat="train", hbm=True,
                                        uid=stage.uid,
                                        stage=type(stage).__name__,
                                        layer=li):
@@ -156,7 +156,7 @@ def _transform_stages(table: FeatureTable, models: Sequence[Any], *,
             return out
     for model in models:
         _plan.count_eager_dispatch(model)
-        with _obs_span("stage.transform", cat=cat,
+        with _obs_span("stage.transform", cat=cat, hbm=True,
                        uid=getattr(model, "uid", "?"),
                        stage=type(model).__name__, layer=layer):
             table = model.transform(table)

@@ -115,42 +115,50 @@ class RealVectorizer(Estimator):
         mesh = getattr(self, "mesh", None)
         if self.fill_with_mean and mesh is not None and self.input_features:
             from ...parallel.sharded import sharded_col_stats
-            cols = [table[f.name] for f in self.input_features]
-            mask = np.stack([c.valid_mask() for c in cols], axis=1)
-            vals64 = [np.asarray(c.values, dtype=np.float64).reshape(-1)
-                      for c in cols]
-            # anchor each column at a coarse host mean so the f32 device
-            # reduction works on deviations (error ~ eps·std, matching the
-            # f64 host path's fills to float precision even for columns with
-            # mean >> std); invalid slots are zeroed, inf still propagates.
-            # STRIDED sample — a head sample would misanchor sorted/trending
-            # columns (ids, timestamps)
-            def _anchor(v, m):
-                mv = v[m]
-                if not len(mv):
-                    return 0.0
-                return mv[::max(1, len(mv) // 1024)][:1024].mean()
-            anchors = np.array(
-                [_anchor(v, mask[:, i]) for i, v in enumerate(vals64)])
-            X = np.stack(
-                [np.where(mask[:, i], v - anchors[i], 0.0)
-                 for i, v in enumerate(vals64)], axis=1).astype(np.float32)
-            st = sharded_col_stats(X, mask, mesh)
-            cnt = np.asarray(st.count)
-            mean = np.asarray(st.mean)
-            fills = [float(anchors[i] + mean[i]) if cnt[i] > 0
-                     else self.fill_value for i in range(len(cols))]
+            with _obs_span("realvec.stack",
+                           columns=len(self.input_features)) as step:
+                cols = [table[f.name] for f in self.input_features]
+                mask = np.stack([c.valid_mask() for c in cols], axis=1)
+                vals64 = [np.asarray(c.values, dtype=np.float64).reshape(-1)
+                          for c in cols]
+                # anchor each column at a coarse host mean so the f32 device
+                # reduction works on deviations (error ~ eps·std, matching
+                # the f64 host path's fills to float precision even for
+                # columns with mean >> std); invalid slots are zeroed, inf
+                # still propagates. STRIDED sample — a head sample would
+                # misanchor sorted/trending columns (ids, timestamps)
+                def _anchor(v, m):
+                    mv = v[m]
+                    if not len(mv):
+                        return 0.0
+                    return mv[::max(1, len(mv) // 1024)][:1024].mean()
+                anchors = np.array(
+                    [_anchor(v, mask[:, i]) for i, v in enumerate(vals64)])
+                X = np.stack(
+                    [np.where(mask[:, i], v - anchors[i], 0.0)
+                     for i, v in enumerate(vals64)],
+                    axis=1).astype(np.float32)
+                step.set_attr(bytes=int(X.nbytes))
+            # the upload, the sharded moments and the fetch of count / mean
+            with _obs_span("realvec.stats", path="mesh"):
+                st = sharded_col_stats(X, mask, mesh)
+                cnt = np.asarray(st.count)
+                mean = np.asarray(st.mean)
+                fills = [float(anchors[i] + mean[i]) if cnt[i] > 0
+                         else self.fill_value for i in range(len(cols))]
         else:
-            fills = []
-            for f in self.input_features:
-                col = table[f.name]
-                vals = np.asarray(col.values, dtype=np.float64)
-                m = col.valid_mask()
-                if self.fill_with_mean:
-                    fills.append(float(vals[m].mean()) if m.any()
-                                 else self.fill_value)
-                else:
-                    fills.append(self.fill_value)
+            # the host moments: a float64 cast and a masked mean a column
+            with _obs_span("realvec.stats", path="host"):
+                fills = []
+                for f in self.input_features:
+                    col = table[f.name]
+                    vals = np.asarray(col.values, dtype=np.float64)
+                    m = col.valid_mask()
+                    if self.fill_with_mean:
+                        fills.append(float(vals[m].mean()) if m.any()
+                                     else self.fill_value)
+                    else:
+                        fills.append(self.fill_value)
         model = RealVectorizerModel(fills=fills, track_nulls=self.track_nulls)
         return self._finalize_model(model)
 
@@ -226,17 +234,24 @@ class RealVectorizerModel(_VectorModelBase):
 
     def transform_column(self, table: FeatureTable) -> Column:
         blocks, meta = [], []
-        for f, fill in zip(self.input_features, self.fills):
-            col = table[f.name]
-            vals = np.asarray(col.values, dtype=np.float32).reshape(-1)
-            m = col.valid_mask()
-            filled = np.where(m, vals, np.float32(fill))
-            blocks.append(filled)
-            meta.extend(_meta_cols(f, [(f.name, None)]))
-            if self.track_nulls:
-                blocks.append((~m).astype(np.float32))
-                meta.extend(_meta_cols(f, [(f.name, NULL_INDICATOR)]))
-        return self._emit(np.stack(blocks, axis=1), meta)
+        # a cast, a fill and a null indicator a column, on the host
+        with _obs_span("realvec.fill", path="host") as step:
+            for f, fill in zip(self.input_features, self.fills):
+                col = table[f.name]
+                vals = np.asarray(col.values, dtype=np.float32).reshape(-1)
+                m = col.valid_mask()
+                filled = np.where(m, vals, np.float32(fill))
+                blocks.append(filled)
+                meta.extend(_meta_cols(f, [(f.name, None)]))
+                if self.track_nulls:
+                    blocks.append((~m).astype(np.float32))
+                    meta.extend(_meta_cols(f, [(f.name, NULL_INDICATOR)]))
+            step.set_attr(bytes=sum(int(b.nbytes) for b in blocks))
+        # the (rows, columns) block the combiner reads
+        with _obs_span("realvec.stack", columns=len(blocks)) as step:
+            out = self._emit(np.stack(blocks, axis=1), meta)
+            step.set_attr(bytes=int(out.values.nbytes))
+        return out
 
 
 class IntegralVectorizer(Estimator):

@@ -19,12 +19,13 @@ from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ...observability.trace import span as _obs_span
 from ...ops.stats import (
     col_stats, contingency_stats, contingency_table, pearson_correlation,
     pearson_correlation_matrix, spearman_correlation,
 )
 from ...stages.base import (AllowLabelAsInput, Estimator, PendingFit,
-                            Transformer)
+                            Transformer, fetch_pending)
 from ...table import Column, FeatureTable
 from ...types import OPVector, RealNN
 from ...utils.padding import pad_rows, padded_valid_mask
@@ -162,7 +163,14 @@ class SanityChecker(AllowLabelAsInput, Estimator):
 
     # -- fit ------------------------------------------------------------------
     def fit(self, table: FeatureTable) -> Transformer:
-        return self.fit_queued(table).finish_now()
+        pending = self.fit_queued(table)
+        # the one fetch the fit blocks on: the host waits here for every
+        # program fit_queued launched (and for what they wait for). Even a
+        # single fit goes through the fused per-dtype transfer: a plain
+        # np.asarray per leaf is one blocking device->host sync EACH
+        with _obs_span("sanity.collect", leaves=len(pending.dev)):
+            (host,) = fetch_pending([pending])
+        return pending._finish(host)
 
     def fit_queued(self, table: FeatureTable) -> PendingFit:
         """Queued-fit protocol (stages/base.py): dispatch every device stat
@@ -172,49 +180,70 @@ class SanityChecker(AllowLabelAsInput, Estimator):
         fits before one sync (reference OpValidator.applyDAG :228-256 runs
         fold DAG copies on concurrent Futures)."""
         label_f, vec_f = self.input_features
-        y = np.asarray(table[label_f.name].values, dtype=np.float32).reshape(-1)
-        col = table[vec_f.name]
-        vm: Optional[VectorMetadata] = col.metadata.get("vector_meta")
-        # the feature matrix stays on device end to end — at millions of rows
-        # a host round-trip would dwarf the stats kernels themselves
-        Xd_all = jnp.asarray(col.values, dtype=jnp.float32)
-        n, d = Xd_all.shape
-
-        # sampling (reference fraction :524-529: the requested check_sample
-        # fraction is clamped so the sample never goes below
-        # sample_lower_limit rows nor above sample_upper_limit)
-        min_frac = min(1.0, self.sample_lower_limit / max(n, 1))
-        max_frac = max(0.0, self.sample_upper_limit / max(n, 1))
-        frac = max(min(self.check_sample, max_frac), min_frac)
-        target = min(int(round(n * frac)), n)
         mesh = getattr(self, "mesh", None)
-        n_data = mesh.shape["data"] if mesh is not None else 1
-        row_mask = None
-        idx = None
-        if target < n:
-            idx = np.random.RandomState(self.seed).choice(
-                n, size=target, replace=False)
-        ys = y if idx is None else y[idx]
-        if idx is not None and mesh is not None and n % n_data == 0:
-            # the sample of a table sharded over the mesh: gathered shard
-            # to shard (no chip holds the sample whole), padded to the data
-            # axis with row 0 under a False mask
-            from ...parallel.sharded import place_rows, take_rows
-            n_s = -(-target // n_data) * n_data
-            Xd = take_rows(Xd_all, pad_rows(idx, n_s), mesh,
-                           site="sanity.sample")
-            yd = place_rows(pad_rows(ys, n_s), mesh, site="checker.upload")
-            row_mask = place_rows(padded_valid_mask(None, target, n_s), mesh,
-                                  site="checker.upload")
-        else:
-            Xd = Xd_all if idx is None else Xd_all[jnp.asarray(idx)]
-            yd = jnp.asarray(ys)
-            if mesh is not None:
-                from ...parallel.sharded import shard_rows
-                Xd, row_mask, _ = shard_rows(Xd, None, mesh)
-                yd, _, _ = shard_rows(yd, None, mesh)
+        with _obs_span("sanity.sample") as step:
+            y = np.asarray(table[label_f.name].values,
+                           dtype=np.float32).reshape(-1)
+            col = table[vec_f.name]
+            vm: Optional[VectorMetadata] = col.metadata.get("vector_meta")
+            # the feature matrix stays on device end to end — at millions of
+            # rows a host round-trip would dwarf the stats kernels themselves
+            Xd_all = jnp.asarray(col.values, dtype=jnp.float32)
+            n, d = Xd_all.shape
+
+            # sampling (reference fraction :524-529: the requested
+            # check_sample fraction is clamped so the sample never goes
+            # below sample_lower_limit rows nor above sample_upper_limit)
+            min_frac = min(1.0, self.sample_lower_limit / max(n, 1))
+            max_frac = max(0.0, self.sample_upper_limit / max(n, 1))
+            frac = max(min(self.check_sample, max_frac), min_frac)
+            target = min(int(round(n * frac)), n)
+            n_data = mesh.shape["data"] if mesh is not None else 1
+            row_mask = None
+            idx = None
+            if target < n:
+                idx = np.random.RandomState(self.seed).choice(
+                    n, size=target, replace=False)
+            ys = y if idx is None else y[idx]
+            if idx is not None and mesh is not None and n % n_data == 0:
+                # the sample of a table sharded over the mesh: gathered
+                # shard to shard (no chip holds the sample whole), padded to
+                # the data axis with row 0 under a False mask
+                from ...parallel.sharded import place_rows, take_rows
+                n_s = -(-target // n_data) * n_data
+                Xd = take_rows(Xd_all, pad_rows(idx, n_s), mesh,
+                               site="sanity.sample")
+                yd = place_rows(pad_rows(ys, n_s), mesh,
+                                site="checker.upload")
+                row_mask = place_rows(padded_valid_mask(None, target, n_s),
+                                      mesh, site="checker.upload")
+            else:
+                Xd = Xd_all if idx is None else Xd_all[jnp.asarray(idx)]
+                yd = jnp.asarray(ys)
+                if mesh is not None:
+                    from ...parallel.sharded import shard_rows
+                    Xd, row_mask, _ = shard_rows(Xd, None, mesh)
+                    yd, _, _ = shard_rows(yd, None, mesh)
+            step.set_attr(rows=n, sampleRows=target)
         if mesh is not None:
             self._stats_input_sharding = str(Xd.sharding)
+        with _obs_span("sanity.stats", features=d):
+            dev, groups = self._launch_stats(Xd, yd, ys, row_mask, vm)
+        n_sample = int(len(ys))
+        sharding_note = getattr(self, "_stats_input_sharding", None)
+
+        def finish(host: Dict[str, np.ndarray]) -> Transformer:
+            return self._finish_from_host(host, d=d, vm=vm, groups=groups,
+                                          n_sample=n_sample,
+                                          sharding_note=sharding_note)
+
+        return PendingFit(dev, finish)
+
+    def _launch_stats(self, Xd, yd, ys: np.ndarray, row_mask,
+                      vm: Optional[VectorMetadata]
+                      ) -> Tuple[Dict[str, Any], List[Any]]:
+        """Launch the stat programs over the sample: (name -> device array,
+        the indicator groups whose contingency counts are among them)."""
         stats = col_stats(Xd, row_mask)
         if self.correlation_type_spearman:
             corr = spearman_correlation(Xd, yd, row_mask)
@@ -256,15 +285,7 @@ class SanityChecker(AllowLabelAsInput, Estimator):
                     dev["counts"] = contingency_table(
                         Xd[:, jnp.asarray(all_idx)], label_idx, num_labels,
                         row_mask)
-        n_sample = int(len(ys))
-        sharding_note = getattr(self, "_stats_input_sharding", None)
-
-        def finish(host: Dict[str, np.ndarray]) -> Transformer:
-            return self._finish_from_host(host, d=d, vm=vm, groups=groups,
-                                          n_sample=n_sample,
-                                          sharding_note=sharding_note)
-
-        return PendingFit(dev, finish)
+        return dev, groups
 
     # -- streaming fit (OpWorkflow.train(stream=...), docs/streaming.md) -----
     def fit_streaming_prep(self, run):
@@ -360,6 +381,17 @@ class SanityChecker(AllowLabelAsInput, Estimator):
         (``fit_streaming``): both paths hand the identical host dict
         (count/mean/variance/min/max, corr, optional feature_corr, stacked
         contingency counts) to the identical removal logic."""
+        with _obs_span("sanity.decide", features=d) as step:
+            model = self._decide(host, d=d, vm=vm, groups=groups,
+                                 n_sample=n_sample,
+                                 sharding_note=sharding_note)
+            step.set_attr(dropped=d - len(model.keep_indices))
+        return model
+
+    def _decide(self, host: Dict[str, np.ndarray], *, d: int,
+                vm: Optional[VectorMetadata], groups: List[Any],
+                n_sample: int, sharding_note: Optional[str]) -> Transformer:
+        """The host's rules over the stat arrays: the fitted model."""
         stats = {k: host[k]
                  for k in ("count", "mean", "variance", "min", "max")}
         corr = host["corr"]

@@ -313,60 +313,76 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         label_f, vec_f = self.input_features
         # label preparation, the split, and the one upload of the feature
         # matrix and labels: everything before the sweep
-        with _obs_span("selector.prepare", cat="train") as prepare_span:
-            y_all = np.asarray(table[label_f.name].values,
-                               dtype=np.float32).reshape(-1)
-            # the feature matrix never visits the host again: row selections
-            # for the holdout/balancer are index gathers on device
-            vec = table[vec_f.name].values
-            Xd_all = jnp.asarray(vec, dtype=jnp.float32)
-            if isinstance(vec, np.ndarray):
-                _count_transfer_bytes(Xd_all, "h2d")
-            n = len(y_all)
+        with _obs_span("selector.prepare", cat="train",
+                       hbm=True) as prepare_span:
+            with _obs_span("prepare.labels") as step:
+                y_all = np.asarray(table[label_f.name].values,
+                                   dtype=np.float32).reshape(-1)
+                # the feature matrix never visits the host again: row
+                # selections for the holdout/balancer are index gathers on
+                # device
+                vec = table[vec_f.name].values
+                Xd_all = jnp.asarray(vec, dtype=jnp.float32)
+                if isinstance(vec, np.ndarray):
+                    _count_transfer_bytes(Xd_all, "h2d")
+                n = len(y_all)
+                step.set_attr(rows=n)
 
             # reserve holdout (reference splitter.split in workflow fitStages)
-            if (self.splitter is not None
-                    and self.splitter.reserve_test_fraction > 0):
-                train_idx, test_idx = self.splitter.split(n)
-            else:
-                train_idx = np.arange(n)
-                test_idx = np.array([], dtype=np.int64)
+            with _obs_span("prepare.split", rows=n) as step:
+                if (self.splitter is not None
+                        and self.splitter.reserve_test_fraction > 0):
+                    train_idx, test_idx = self.splitter.split(n)
+                else:
+                    train_idx = np.arange(n)
+                    test_idx = np.array([], dtype=np.int64)
+                step.set_attr(trainRows=len(train_idx),
+                              testRows=len(test_idx))
 
-            y_train_raw = y_all[train_idx]
-            prep = (self.splitter.pre_validation_prepare(y_train_raw)
-                    if self.splitter is not None
-                    else PreparedData(indices=np.arange(len(y_train_raw))))
-            sel = train_idx[prep.indices]
-            # the cutter's re-indexing, once for every row: the fit reads
-            # the rows kept, the evaluation below every row of the split
-            labels = label_index(prep.label_mapping)
-            y_dense = y_all if labels is None else labels.forward(y_all)
+            with _obs_span("prepare.balance",
+                           splitter=type(self.splitter).__name__,
+                           rowsIn=len(train_idx)) as step:
+                y_train_raw = y_all[train_idx]
+                prep = (self.splitter.pre_validation_prepare(y_train_raw)
+                        if self.splitter is not None
+                        else PreparedData(indices=np.arange(len(y_train_raw))))
+                sel = train_idx[prep.indices]
+                # the cutter's re-indexing, once for every row: the fit
+                # reads the rows kept, the evaluation below every row of
+                # the split
+                labels = label_index(prep.label_mapping)
+                y_dense = y_all if labels is None else labels.forward(y_all)
+                step.set_attr(rowsKept=len(sel))
             prepare_span.set_attr(
                 labelMap="none" if labels is None else "lookup")
-            y = y_dense[sel]
-            num_classes = (int(y.max()) + 1 if self.problem != "regression"
-                           else 1)
-            if self.problem == "binary":
-                num_classes = 2
 
-            metric_name, larger_better = self.validation_metric
-            yd = jnp.asarray(y)
-            _count_transfer_bytes(yd, "h2d")
-            if self.mesh is not None:
-                # shard to shard, straight into the row bucket the sweep
-                # and the refit share (rows past len(y) are zeros): no chip
-                # holds the rows kept whole, and neither pads a copy
-                from ...parallel.sharded import take_rows
-                n_b = bucket_for(len(sel),
-                                 multiple_of=self.mesh.shape["data"])
-                Xd = take_rows(Xd_all, np.pad(sel, (0, n_b - len(sel)),
-                                              constant_values=-1), self.mesh,
-                               site="selector.prepare")
-            else:
-                sel_d = jnp.asarray(sel)
-                _count_transfer_bytes(sel_d, "h2d")
-                Xd = Xd_all[sel_d]
-                del sel_d    # an index vector, not to outlive its gather
+            with _obs_span("prepare.gather", rows=len(sel)):
+                y = y_dense[sel]
+                num_classes = (int(y.max()) + 1
+                               if self.problem != "regression" else 1)
+                if self.problem == "binary":
+                    num_classes = 2
+
+                metric_name, larger_better = self.validation_metric
+                yd = jnp.asarray(y)
+                _count_transfer_bytes(yd, "h2d")
+                if self.mesh is not None:
+                    # shard to shard, straight into the row bucket the
+                    # sweep and the refit share (rows past len(y) are
+                    # zeros): no chip holds the rows kept whole, and
+                    # neither pads a copy
+                    from ...parallel.sharded import take_rows
+                    n_b = bucket_for(len(sel),
+                                     multiple_of=self.mesh.shape["data"])
+                    Xd = take_rows(Xd_all,
+                                   np.pad(sel, (0, n_b - len(sel)),
+                                          constant_values=-1), self.mesh,
+                                   site="selector.prepare")
+                else:
+                    sel_d = jnp.asarray(sel)
+                    _count_transfer_bytes(sel_d, "h2d")
+                    Xd = Xd_all[sel_d]
+                    del sel_d    # an index vector, not to outlive its gather
             if "labelsKept" in prep.summary:     # what DataCutter kept
                 prepare_span.set_attr(
                     labelsKept=len(prep.summary["labelsKept"]),
@@ -424,7 +440,8 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         # finite candidate refits instead. With no fault the first candidate
         # IS the sweep winner, bit-identically. params_finite fetches, so
         # the span closes where the host has waited for the winner's fit.
-        with _obs_span("selector.refit", cat="train") as refit_span:
+        with _obs_span("selector.refit", cat="train",
+                       hbm=True) as refit_span:
             fitted = None
             best_used = (best.family_name, dict(best.hyper), best.metric_value)
             refit_quarantine: List[Dict[str, Any]] = []
@@ -494,32 +511,46 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         # fit, the family's device predict, the metrics beside it; the host
         # gets the numbers. Else the table goes through the model's
         # transform and the evaluator as a user's would.
-        with _obs_span("selector.evaluate", cat="train",
+        with _obs_span("selector.evaluate", cat="train", hbm=True,
                        rows=len(train_idx) + len(test_idx)) as eval_span:
             ev = self._default_evaluator()
             ev.set_label_col(label_f.name)
             ev.set_prediction_col(model.get_output().name)
             on_device = model.device_fusable and evaluates_parts(ev)
             results, host_bytes = [], 0
-            for idx in (train_idx, test_idx):
+            for split, idx in (("train", train_idx), ("holdout", test_idx)):
                 if not len(idx):
                     results.append({})
-                elif on_device:
-                    if idx is train_idx and np.array_equal(sel, train_idx):
-                        # nothing dropped or resampled: the refit's operands
-                        X, lab, mask = Xf, yf, padded_valid_mask(
-                            None, n_fit, n_pad)
-                    else:
-                        X, lab, mask = self._rows_on_device(
-                            Xd_all, y_dense, idx)
-                    with engine_mesh(self.mesh):
+                    continue
+                # the split's three steps: its rows, the launch of the
+                # predict, and the metrics, where the host waits for them
+                said = dict(split=split, rows=len(idx),
+                            path="device" if on_device else "table")
+                if on_device:
+                    with _obs_span("evaluate.rows", **said):
+                        if (idx is train_idx
+                                and np.array_equal(sel, train_idx)):
+                            # nothing dropped or resampled: the refit's
+                            # operands
+                            X, lab, mask = Xf, yf, padded_valid_mask(
+                                None, n_fit, n_pad)
+                        else:
+                            X, lab, mask = self._rows_on_device(
+                                Xd_all, y_dense, idx)
+                    with _obs_span("evaluate.predict", **said), \
+                            engine_mesh(self.mesh):
                         parts = MODEL_REGISTRY[fitted.family].predict_parts(
                             fitted, X)
-                    results.append(ev.evaluate_parts(lab, parts, mask))
+                    with _obs_span("evaluate.metrics", **said):
+                        results.append(ev.evaluate_parts(lab, parts, mask))
                     host_bytes += 4 * _count_numbers(results[-1])
                 else:
-                    scored = model.transform(table.take(idx))
-                    results.append(ev.evaluate_all(scored))
+                    with _obs_span("evaluate.rows", **said):
+                        rows = table.take(idx)
+                    with _obs_span("evaluate.predict", **said):
+                        scored = model.transform(rows)
+                    with _obs_span("evaluate.metrics", **said):
+                        results.append(ev.evaluate_all(scored))
                     host_bytes += _host_bytes(scored)
             summary.train_evaluation, summary.holdout_evaluation = (
                 _scalar_metrics(r) for r in results)
@@ -680,27 +711,36 @@ class SelectedModel(AllowLabelAsInput, Transformer):
 
     def transform_column(self, table: FeatureTable) -> Column:
         _, vec_f = self.input_features
-        X = jnp.asarray(table[vec_f.name].values, dtype=jnp.float32)
-        n = X.shape[0]
         # getattr: models loaded from disk predate the wiring attr (mesh is
         # never serialized; the loading context re-attaches it if sharding)
         mesh = getattr(self, "mesh", None)
-        n_data = mesh.shape["data"] if mesh is not None else 1
-        n_pad = bucket_for(n, multiple_of=n_data)
-        if mesh is not None:
-            # padded and sharded by one program: no whole copy on a chip
-            from ...parallel.sharded import pad_rows_sharded
-            X = pad_rows_sharded(X, n_pad, mesh)
-        elif n_pad != n:  # bucket rows so the predict program is reused
-            X = jnp.pad(X, ((0, n_pad - n), (0, 0)))
+        with _obs_span("predict.pad") as step:
+            X = jnp.asarray(table[vec_f.name].values, dtype=jnp.float32)
+            n = X.shape[0]
+            n_data = mesh.shape["data"] if mesh is not None else 1
+            n_pad = bucket_for(n, multiple_of=n_data)
+            if mesh is not None:
+                # padded and sharded by one program: no whole copy on a chip
+                from ...parallel.sharded import pad_rows_sharded
+                X = pad_rows_sharded(X, n_pad, mesh)
+            elif n_pad != n:  # bucket rows so the predict program is reused
+                X = jnp.pad(X, ((0, n_pad - n), (0, 0)))
+            step.set_attr(rows=n, paddedRows=n_pad)
         family = MODEL_REGISTRY[self.fitted.family]
-        with engine_mesh(mesh):
+        # predict_one hands back host arrays: the span holds the launch of
+        # the predict and the host's wait for its parts
+        with _obs_span("predict.parts", family=self.fitted.family), \
+                engine_mesh(mesh):
             parts = family.predict_one(self.fitted, X)
-        if n_pad != n:
-            parts = {k: v[:n] for k, v in parts.items()}
-        parts = dict(parts,
-                     prediction=self._unmap_prediction(parts["prediction"]))
-        return prediction_column(parts)
+        with _obs_span("predict.unmap"):
+            if n_pad != n:
+                parts = {k: v[:n] for k, v in parts.items()}
+            parts = dict(parts, prediction=self._unmap_prediction(
+                parts["prediction"]))
+        with _obs_span("predict.column") as step:
+            col = prediction_column(parts)
+            step.set_attr(bytes=int(col.values.nbytes))
+        return col
 
     def transform_row(self, row: Dict[str, Any]) -> Any:
         _, vec_f = self.input_features

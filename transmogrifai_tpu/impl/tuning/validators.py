@@ -865,7 +865,7 @@ class OpValidator:
             launched_before = launched
             with _obs_span("sweep.family", cat="sweep", family=family.name,
                            configs=len(grid), folds=F, metric=metric_name,
-                           order=dispatched) as sweep_span:
+                           order=dispatched, hbm=True) as sweep_span:
                 if tracing_enabled():
                     # the fit's shape, and what the family's own schedule
                     # fixes about it (contractions, chunks of lanes), read
@@ -969,7 +969,7 @@ class OpValidator:
             t0_fetch = _time.perf_counter()
             # the one statement where the host waits for the device sweep
             with _obs_span("sweep.collect", cat="sweep",
-                           families=len(valid_m)):
+                           families=len(valid_m), hbm=True):
                 m_host = fetch_to_host(all_m) if all_m is not None else None
             if m_host is not None:
                 _obs_metrics.observe(

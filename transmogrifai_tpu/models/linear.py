@@ -209,12 +209,21 @@ _LOGREG_STEPS = {True: (8, 6), False: (10, 8)}
 
 
 def logreg_matrix_passes(sweep: bool) -> int:
-    """Reads of the (rows, features) matrix that `_fit_logreg_batch`'s
-    schedule makes: five to standardise (`_BatchStd`), then a Newton step's
-    margin, gradient and two diagonal-curvature products and two products
-    a CG step."""
+    """Reads of the (rows, features) matrix in the program the chip's
+    compiler makes of `_fit_logreg_batch` (counted in ``as_text()`` for a
+    described v5e; tests/test_device_names_tpu.py holds it to that count).
+    A sweep's lanes in bfloat16: five to standardise and cast
+    (`_BatchStd`), then a Newton step's margin, gradient and two
+    diagonal-curvature products and two products a CG step. The refit's
+    one float32 lane: four before the loop, and the gradient and the two
+    curvature products of a Newton step are ONE fusion that reads the
+    matrix and its stored square, so three reads a step beside the CG's.
+    At a cell's size the compiler lays the matrix out anew first (a
+    ``copy``): one read more."""
     newton, cg = _LOGREG_STEPS[bool(sweep)]
-    return 5 + newton * (4 + 2 * cg)
+    if sweep:
+        return 5 + newton * (4 + 2 * cg)
+    return 4 + newton * (3 + 2 * cg)
 
 
 def _fit_logreg(X, y, w, reg, elastic_net):
@@ -834,11 +843,12 @@ class LinearRegressionFamily(ModelFamily):
 _SVC_STEPS = 100
 
 
-def svc_matrix_passes() -> int:
-    """Reads of the (rows, features) matrix that `_fit_svc_batch` makes:
-    five to standardise (`_BatchStd`), then a step's margin and gradient
-    products."""
-    return 5 + 2 * _SVC_STEPS
+def svc_matrix_passes(sweep: bool) -> int:
+    """Reads of the (rows, features) matrix in the compiled
+    `_fit_svc_batch` (as :func:`logreg_matrix_passes`): five to standardise
+    and cast for a sweep's bfloat16 lanes, three for the refit's float32
+    lane, then a step's margin and gradient products."""
+    return (5 if sweep else 3) + 2 * _SVC_STEPS
 
 
 @partial(jax.jit, static_argnames=("iters", "sweep"))
@@ -912,7 +922,7 @@ class LinearSVCFamily(ModelFamily):
         return {"coef": coef, "bias": bias}
 
     def fit_span_attrs(self, rows, features, grid, num_classes, sweep):
-        return {"matrixPasses": svc_matrix_passes()}
+        return {"matrixPasses": svc_matrix_passes(sweep)}
 
     def predict_batch(self, params, X, num_classes):
         # squash margins so threshold-style validation metrics (which cut at

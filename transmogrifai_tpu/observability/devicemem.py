@@ -50,17 +50,19 @@ _stats_supported: Optional[bool] = None
 
 
 def memory_stats() -> Optional[Dict[str, int]]:
-    """The first local device's ``memory_stats()`` (bytes_in_use /
-    peak_bytes_in_use / bytes_limit / num_allocs), or None where the
-    backend does not report (CPU) — the graceful-no-op contract every
-    caller leans on. The support probe is cached: once a backend says
-    no, later dispatches pay one flag check."""
+    """The fullest local device's ``memory_stats()`` (bytes_in_use /
+    peak_bytes_in_use / bytes_limit / num_allocs; every chip of a mesh is
+    asked: ``trace.fullest_device_stats``, the helper the spans'
+    ``hbmLive*`` read), or None where the backend does not report (CPU) —
+    the graceful-no-op contract every caller leans on. The support probe
+    is cached: once a backend says no, later dispatches pay one flag
+    check."""
     global _stats_supported
     if _stats_supported is False:
         return None
     try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats()
+        from .trace import fullest_device_stats
+        stats = fullest_device_stats()
     except Exception:
         stats = None
     if not stats:

@@ -81,7 +81,7 @@ class Span:
     point-in-time annotations: ``(name, ts_ns, attrs)``."""
 
     __slots__ = ("name", "cat", "span_id", "parent_id", "root_id", "ts_ns",
-                 "dur_ns", "attrs", "events", "tid")
+                 "dur_ns", "attrs", "events", "tid", "leaf")
 
     def __init__(self, name: str, cat: str, span_id: int,
                  parent_id: Optional[int], ts_ns: int, tid: int,
@@ -99,6 +99,9 @@ class Span:
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
         self.events: List[Tuple[str, int, Dict[str, Any]]] = []
         self.tid = tid
+        #: a leaf span records no children: what its thread opens while it
+        #: is open gets NULL_SPAN (``span(..., leaf=True)``)
+        self.leaf = False
 
     def set_attr(self, **kv: Any) -> "Span":
         self.attrs.update(kv)
@@ -258,15 +261,63 @@ def _now_rel_ns() -> int:
     return time.perf_counter_ns() - _TRACER.epoch_ns
 
 
+def fullest_device_stats() -> Optional[Dict[str, int]]:
+    """``memory_stats()`` of the local device with the most
+    ``bytes_in_use``: every chip of a mesh is asked, not chip 0. None where
+    the backend keeps no allocator statistics (the CPU). It does not wait
+    for the device: the numbers are what is allocated at dispatch."""
+    import jax
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    stats = [s for s in stats if s]
+    if not stats:
+        return None
+    return max(stats, key=lambda s: s.get("bytes_in_use", 0))
+
+
+def live_device_bytes() -> int:
+    """Bytes in use on the fullest local device; where the backend keeps
+    no statistics, the bytes of the live arrays' shards on it."""
+    stats = fullest_device_stats()
+    if stats is not None:
+        return int(stats.get("bytes_in_use", 0))
+    import jax
+    per_device: Dict[Any, int] = {}
+    seen = set()      # a shard's view is a live array of its own: once each
+    for a in jax.live_arrays():
+        if a.is_deleted():
+            continue
+        for sh in a.addressable_shards:
+            buf = (sh.device, sh.data.unsafe_buffer_pointer())
+            if buf not in seen:
+                seen.add(buf)
+                per_device[sh.device] = (per_device.get(sh.device, 0)
+                                         + int(sh.data.nbytes))
+    return max(per_device.values(), default=0)
+
+
 @contextmanager
-def span(name: str, cat: str = "", **attrs: Any):
+def span(name: str, cat: str = "", *, hbm: bool = False, leaf: bool = False,
+         **attrs: Any):
     """``with span("stage.fit", uid=...) as s:`` — records one Span when
-    tracing is enabled; otherwise yields the inert :data:`NULL_SPAN`."""
+    tracing is enabled; otherwise yields the inert :data:`NULL_SPAN`.
+
+    ``hbm=True`` (a layer boundary) adds ``hbmLiveStart`` / ``hbmLiveEnd``,
+    :func:`live_device_bytes` read before the span opens and after it
+    closes, outside its own seconds. ``leaf=True`` records the span and
+    nothing its thread opens inside it (the plan's zero-row probe runs
+    the stages' own code: its cost is the one span's)."""
     if not tracing_enabled():
         yield NULL_SPAN
         return
     t = _TRACER
+    cur = t.current()
+    if cur is not None and cur.leaf:
+        yield NULL_SPAN
+        return
+    if hbm:
+        attrs["hbmLiveStart"] = live_device_bytes()
     s = t.start(name, cat, attrs)
+    s.leaf = leaf
     try:
         # the same name on the profiler's own clock: a jax.profiler session
         # opened by anyone (a benchmark, an operator) shows the program's
@@ -276,6 +327,8 @@ def span(name: str, cat: str = "", **attrs: Any):
             yield s
     finally:
         t.end(s)
+        if hbm:
+            s.attrs["hbmLiveEnd"] = live_device_bytes()
 
 
 def add_event(name: str, **attrs: Any) -> None:

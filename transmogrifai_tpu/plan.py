@@ -301,6 +301,7 @@ class TransformPlan:
                         # numeric inputs) still launches eager programs
                         count_eager_dispatch(s)
                         with _obs_span("stage.transform", cat=self.cat,
+                                       hbm=True,
                                        uid=getattr(s, "uid", "?"),
                                        stage=type(s).__name__, planned=True):
                             table = s.transform(table)
@@ -646,8 +647,11 @@ def _build_plan(stages: List[Any], table: FeatureTable,
             None if col.mask is None else np.zeros(0, dtype=bool),
             dict(col.metadata))
     probe = FeatureTable(probe_cols, 0)
-    for s in stages:
-        probe = s.transform(probe)
+    # a leaf span: the stages run their own code over no rows, and what
+    # they would say of a table (onehot.*, realvec.*) is not recorded
+    with _obs_span("plan.probe", cat=cat, leaf=True, stages=len(stages)):
+        for s in stages:
+            probe = s.transform(probe)
     for kind, payload in plan.steps:
         if kind != "device":
             continue

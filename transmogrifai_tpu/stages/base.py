@@ -167,16 +167,17 @@ class PendingFit:
         self.dev = dev          # name -> device array, still materializing
         self._finish = finish   # host dict (same keys, np arrays) -> model
 
-    def finish_now(self) -> "Transformer":
-        # even a single fit resolves through the fused per-dtype transfer:
-        # a plain np.asarray per leaf is one blocking device->host sync
-        # EACH (7 leaves for a SanityChecker fit)
-        return materialize_pending([self])[0]
-
 
 def materialize_pending(pendings: "List[PendingFit]") -> "List[Transformer]":
-    """Resolve many queued fits with ONE host transfer per dtype: all
-    pending device leaves concatenate into flat vectors (grouped by dtype —
+    """Resolve many queued fits: one fused fetch (:func:`fetch_pending`),
+    then each fit's host decisions."""
+    return [p._finish(h) for p, h in zip(pendings, fetch_pending(pendings))]
+
+
+def fetch_pending(pendings: "List[PendingFit]") -> "List[Dict[str, Any]]":
+    """The queued fits' leaves on the host, one dict a fit, with ONE host
+    transfer per dtype: all pending device leaves concatenate into flat
+    vectors (grouped by dtype —
     casting counts through f32 would round above 2^24), transfer once, and
     split back. Every np.asarray on a device leaf is a blocking sync with a
     fixed cost whatever its size, so F·|leaves| separate calls would cost
@@ -210,7 +211,7 @@ def materialize_pending(pendings: "List[PendingFit]") -> "List[Transformer]":
         host_dicts[pi][k] = flat_host[dt][offs[dt]:offs[dt] + size
                                           ].reshape(shape)
         offs[dt] += size
-    return [p._finish(h) for p, h in zip(pendings, host_dicts)]
+    return host_dicts
 
 
 class Estimator(OpPipelineStage):

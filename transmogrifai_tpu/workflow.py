@@ -41,11 +41,12 @@ def _root_span(name: str, profiler, **attrs):
     """The outermost span of a train() or score(). With a profiler
     (``with_profiler``) tracing is on for the run and the profiler
     aggregates the run's stage spans when it ends; a traced run with
-    metrics on gets ``h2dBytes``, the bytes that went up during it."""
+    metrics on gets ``h2dBytes``, the bytes that went up during it, and
+    every traced one ``hbmLiveStart`` / ``hbmLiveEnd``."""
     from .observability.trace import forced_tracing, span
     with forced_tracing() if profiler is not None \
             else contextlib.nullcontext():
-        with span(name, **attrs) as root:
+        with span(name, hbm=True, **attrs) as root:
             h2d0 = _h2d_bytes()
             yield root
             if h2d0 is not None:
