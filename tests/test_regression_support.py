@@ -367,3 +367,53 @@ def test_fit_codes_are_not_taken_for_another_mask_and_die_with_the_array():
     del table, vals, got
     gc.collect()
     assert key not in vz._FIT_CODES
+
+
+def test_nothing_of_a_column_outlives_its_train():
+    """A train hashes its columns (by object since PR 37) and keeps none of
+    it: no codes, levels or pointers left in ``_FIT_CODES`` or in any other
+    module-level container of the vectorizers, nothing hung on a column, and
+    a second train of the same table counts every column again."""
+    import pandas as pd
+    import transmogrifai_tpu as tg
+    from transmogrifai_tpu import FeatureBuilder
+    from transmogrifai_tpu.impl.feature import vectorizers as vz
+    from transmogrifai_tpu.workflow import OpWorkflow
+    rng = np.random.RandomState(37)
+    levels = np.array([f"level{i}" for i in range(9)], dtype=object)
+    # object columns said to be such: a frame left to infer a string dtype
+    # hands out a new object a row
+    df = pd.DataFrame({
+        "c1": pd.Series(levels[rng.choice(9, 600)], dtype=object),
+        "c2": pd.Series(levels[rng.choice(3, 600)], dtype=object),
+        "x": rng.randn(600)})
+    feats = [FeatureBuilder.PickList("c1").extract_field().as_predictor(),
+             FeatureBuilder.PickList("c2").extract_field().as_predictor(),
+             FeatureBuilder.Real("x").extract_field().as_predictor()]
+    wf = OpWorkflow().set_input_dataset(df).set_result_features(
+        tg.transmogrify(feats))
+
+    def held():
+        return {k: len(v) for k, v in vars(vz).items()
+                if isinstance(v, (dict, list, set))}
+    counted = []
+    real = vz._factorize_valid
+
+    def counting(vals, m):
+        got = real(vals, m)
+        counted.append(got[3])
+        return got
+    vz._factorize_valid = counting
+    try:
+        before = held()
+        model = wf.train()
+        assert vz._FIT_CODES == {} and held() == before
+        assert counted == [9, 3]            # one object a level, both columns
+        table = model.train_table
+        for name in table.column_names:
+            assert set(vars(table[name])) == {"feature_type", "values",
+                                              "mask", "metadata"}
+        wf.train()
+        assert counted == [9, 3, 9, 3] and vz._FIT_CODES == {}
+    finally:
+        vz._factorize_valid = real

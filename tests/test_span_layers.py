@@ -162,12 +162,17 @@ def test_one_hot_spans_one_per_column(spans):
         got = _named(mine, name)
         assert sorted(s.attrs["column"] for s in got) == ["c1", "c2"], name
     counts = {s.attrs["column"]: s for s in _named(mine, "onehot.count")}
+    # the frame's equal one-letter strings reach the pivot as one object
+    # each: rows were grouped by object, four objects met for four levels
     assert counts["c1"].attrs == {"column": "c1", "rows": ROWS, "levels": 4,
-                                  "path": "hashed"}
-    assert counts["c2"].attrs["levels"] == 2
+                                  "path": "hashed", "objects": 4}
+    assert (counts["c2"].attrs["levels"], counts["c2"].attrs["objects"]) == (
+        2, 2)
     # every value of c1 and c2 is a str: hashed as it is, fit and transform
+    # (the transform of the fitted table takes the fit's codes and says its)
     assert [s.attrs for s in _named(mine, "onehot.encode")] == [
-        {"column": "c1", "path": "hashed"}, {"column": "c2", "path": "hashed"}]
+        {"column": "c1", "path": "hashed", "objects": 4},
+        {"column": "c2", "path": "hashed", "objects": 2}]
     (concat,) = _named(mine, "onehot.concat")
     # 4 + OTHER + null and 2 + OTHER + null columns of float32
     assert concat.attrs["bytes"] == ROWS * (6 + 4) * 4
@@ -216,18 +221,26 @@ def test_the_device_path_says_so(monkeypatch):
             attrs["h2dBytes"]) == (1, 1, ROWS * 4 * 4)
 
 
-@pytest.mark.parametrize("values,path", [
-    (["a", "b", "a", None], "hashed"),
-    (list(np.array(["a", "b", "a"])), "hashed"),        # numpy's str
-    ([3, 1, 3, None], "str_pass"),
-    (["1", 1, True, 1.0], "str_pass"),
-    ([None, None], "hashed"),                           # nothing to str()
-], ids=["str", "numpy_str", "ints", "mixed", "all_null"])
-def test_one_hot_spans_say_which_pass_ran(values, path):
+@pytest.mark.parametrize("values,path,objects,own", [
+    (["a", "b", "a", None], "hashed", 2, False),
+    # numpy's str: three objects a repeat, two levels
+    (list(np.array(["a", "b", "a"])), "hashed", 3, False),
+    ([3, 1, 3, None], "str_pass", 2, False),
+    (["1", 1, True, 1.0], "str_pass", 4, False),
+    ([None, None], "hashed", 0, False),                 # nothing to str()
+    (["aa", "bb", "aa", None], "hashed", 0, True),
+], ids=["str", "numpy_str", "ints", "mixed", "all_null", "object_a_row"])
+def test_one_hot_spans_say_which_pass_ran(values, path, objects, own):
+    """``path``: how values were resolved; ``objects``: the distinct objects
+    met where rows were grouped by object first (``values * reps`` repeats
+    the same objects), 0 where every row's value was resolved: the small
+    pass, and a column that holds an object of its own in every row."""
     from transmogrifai_tpu.impl.feature import OneHotVectorizer
     from transmogrifai_tpu.types import PickList
     for reps in (1, 100):       # the small pass and pandas' say the same
-        table = tg.FeatureTable.from_columns({"c": (PickList, values * reps)})
+        column = [v and (v + ".")[:-1] for v in values * reps] if own \
+            else values * reps
+        table = tg.FeatureTable.from_columns({"c": (PickList, column)})
         st = OneHotVectorizer()
         st.set_input(FeatureBuilder.PickList("c").extract_field().as_predictor())
         ot.reset()
@@ -235,8 +248,11 @@ def test_one_hot_spans_say_which_pass_ran(values, path):
         st.fit(table).transform_column(table)
         got = {s.name: s.attrs for s in ot.tracer().finished()}
         ot.reset()
-        assert got["onehot.count"]["path"] == path
-        assert got["onehot.encode"] == {"column": "c", "path": path}
+        met = objects if reps > 1 else 0
+        assert (got["onehot.count"]["path"],
+                got["onehot.count"]["objects"]) == (path, met)
+        assert got["onehot.encode"] == {"column": "c", "path": path,
+                                        "objects": met}
 
 
 def test_every_span_of_a_train_shares_the_roots_id(spans):

@@ -302,7 +302,7 @@ def test_hashing_raw_mixed_objects_would_not_pass(monkeypatch):
         codes = np.full(len(vals), -1, dtype=np.intp)
         codes[m] = sub
         return codes, dict(zip(levels, np.bincount(
-            sub, minlength=len(levels)).tolist())), "hashed"
+            sub, minlength=len(levels)).tolist())), "hashed", 0
 
     monkeypatch.setattr(vectorizers, "_factorize_valid", raw_hash)
     with pytest.raises(AssertionError):
@@ -312,6 +312,127 @@ def test_hashing_raw_mixed_objects_would_not_pass(monkeypatch):
         test_pivot_equals_the_per_row_loops(
             "smart_text", "mixed_1_True_str1_float1", 1)
     test_pivot_equals_the_per_row_loops("one_hot", "all_str", 64)
+
+
+# -- rows grouped by object before any value is hashed (PR 37) ---------------
+
+def _reference_factorize(vals, m):
+    """``_factorize_valid`` as plain as it comes: a dict over ``str(v)`` of
+    the valid rows. ``path`` is ``"hashed"`` where every valid value is a
+    ``str`` and ``str()`` merges none of them."""
+    seen, counts = {}, {}
+    codes = np.full(len(vals), -1, dtype=np.intp)
+    for i, (v, ok) in enumerate(zip(vals, m)):
+        if ok:
+            codes[i] = seen.setdefault(str(v), len(seen))
+            counts[str(v)] = counts.get(str(v), 0) + 1
+    valid = [v for v, ok in zip(vals, m) if ok]
+    hashed = (all(isinstance(v, str) for v in valid)
+              and len(set(valid)) == len(seen))
+    return codes, counts, "hashed" if hashed else "str_pass"
+
+
+def _objects(values):
+    arr = np.empty(len(values), dtype=object)
+    arr[:] = values
+    return arr
+
+
+def _fresh(values):
+    """Every value as an object of its own (a ``csv`` loop, ``astype(str)``)."""
+    return _objects([(v + ".")[:-1] for v in values])
+
+
+class _Lower(str):
+    """A ``str`` subclass with a ``str()`` of its own: equal levels from
+    values the hash keeps apart."""
+    def __str__(self):
+        return self.lower()
+
+
+def _object_cases():
+    """name -> (values, mask or None, objects: what the count pass must say,
+    None where either answer is sound)."""
+    rng = np.random.RandomState(37)
+    names = _objects([f"level{i}" for i in range(40)])
+    draws = rng.choice(40, size=5000, p=np.arange(40, 0, -1) / 820.0)
+    interned = names[draws]
+    a_row = _fresh(list(interned))
+    mostly_shared = interned.copy()
+    mostly_shared[::50] = a_row[::50]
+    mostly_a_row = a_row.copy()
+    mostly_a_row[::50] = interned[::50]
+    per_chunk = np.concatenate([_fresh(list(names))[draws[:2500]],
+                                _fresh(list(names))[draws[2500:]]])
+    some_null = interned.copy()
+    mask = rng.rand(5000) > 0.2
+    some_null[~mask] = None
+    wide = _objects([None] * 10000)
+    wide[1::2] = interned
+    late = _objects(["early"] * 150000)
+    late[140000:] = names[0]
+    late[149000] = names[1]
+    few = ["red", "blue", "red", "green"]
+    return {
+        "interned": (interned, None, 40),
+        "an_object_a_row": (a_row, None, 0),
+        "mostly_shared": (mostly_shared, None, 140),
+        "mostly_an_object_a_row": (mostly_a_row, None, 0),
+        "an_object_a_level_and_chunk": (per_chunk, None, 80),
+        "all_unique": (_objects([f"id{i}" for i in range(3000)]), None, 0),
+        "mixed_1_True_str1_float1": (_objects(["1", 1, True, 1.0] * 100),
+                                     None, 4),
+        "ints": (_objects([3, 1, 300, 3, 1] * 60), None, 3),
+        "str_subclass": (_objects([_Lower("Aa"), _Lower("aa"), "b"] * 50),
+                         None, 3),
+        "numpy_str": (_objects(list(np.array(["u", "v", "u", "w"])) * 50),
+                      None, 4),
+        "none_under_a_partial_mask": (some_null, mask, 40),
+        "none_held_valid": (_objects(["a", None, "a", "b"] * 50), None, 3),
+        "all_null": (_objects([None] * 300), np.zeros(300, dtype=bool), 0),
+        "sliced_view": (wide[5001:], np.arange(4999) % 2 == 0, 40),
+        "strided_view": (wide[1::2], None, 40),
+        "reversed_view": (interned[::-1], None, 40),
+        "a_level_first_met_in_the_third_block": (late, None, 3),
+        "rows_128": (_objects(few * 32), None, 0),
+        "rows_129": (_objects(few * 32 + ["red"]), None, 3),
+        "rows_0": (_objects([]), None, 0),
+    }
+
+
+OBJECT_CASES = _object_cases()
+
+
+@pytest.mark.parametrize("name", list(OBJECT_CASES))
+def test_count_pass_by_object_equals_the_dict_over_str(name):
+    """Codes, counts with their key order, and path as the per-row dict
+    gives them, whether rows were grouped by object first or not; and
+    ``objects`` says which it was."""
+    from transmogrifai_tpu.impl.feature import vectorizers
+    vals, mask, objects = OBJECT_CASES[name]
+    m = np.ones(len(vals), dtype=bool) if mask is None else mask
+    before = vals.copy()
+    codes, counts, path, met = vectorizers._factorize_valid(vals, m)
+    want_codes, want_counts, want_path = _reference_factorize(vals, m)
+    assert codes.dtype == np.intp and np.array_equal(codes, want_codes)
+    assert list(counts.items()) == list(want_counts.items())
+    assert all(type(k) is str and type(c) is int for k, c in counts.items())
+    assert path == want_path
+    assert met == objects
+    assert all(a is b for a, b in zip(vals, before))     # nothing written
+
+
+@pytest.mark.parametrize("codes", [
+    [0], [0, 0, 0], [0, 1, 2, 3], [0, 0, 1, 0, 2, 1, 2, 3],
+    [0] * 70000 + [1] + [0] * 70000 + [2, 1, 3],
+    list(range(5)) * 30000 + [5]], ids=["one", "same", "rising", "mixed",
+                                        "blocks", "last_row"])
+def test_first_rows_are_where_each_code_first_appears(codes):
+    from transmogrifai_tpu.impl.feature import vectorizers
+    codes = np.asarray(codes, dtype=np.intp)
+    levels, want = np.unique(codes, return_index=True)
+    got = vectorizers._first_rows(codes, len(levels))
+    assert got.dtype == np.intp and np.array_equal(got, want)
 
 
 # -- the chip makes the dense block from positions (PR 35) --------------------

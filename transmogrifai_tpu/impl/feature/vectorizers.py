@@ -373,36 +373,120 @@ def _hash_pass(values: np.ndarray) -> Tuple[np.ndarray, List[str]]:
     return codes, list(uniques)
 
 
-def _factorize_valid(vals: np.ndarray, m: np.ndarray
-                     ) -> Tuple[np.ndarray, Dict[str, int], str]:
-    """Values of the rows where ``m`` holds → ``(codes, counts, path)``:
-    ``counts`` maps each distinct value, as ``str`` and in order of first
-    appearance, to its occurrences; ``codes`` (n,) is each valid row's
-    position among them and -1 where ``m`` is false. ``path`` is
-    ``"hashed"`` where every valid value is a ``str`` already and the
-    objects are hashed as they are; anything else goes through ``str()``
-    first (``"str_pass"``), since ``1 == True == 1.0`` would merge under the
-    hash what ``str()`` keeps apart. No per-row Python on the hashed path."""
-    every = bool(m.all())
-    valid = vals if every else vals[m]
-    hashed = valid.dtype == object and pd.api.types.infer_dtype(
-        valid, skipna=False) in ("string", "empty")
+#: rows the count pass looks at before it chooses, as evenly spaced runs of
+#: neighbouring rows (a reader that boxes the equal strings of a chunk as one
+#: object shares objects inside a chunk, not between two): 0.2 ms a column on
+#: the chip's host, a two-hundredth of a value pass over 1 M rows (PERF.md
+#: section 6, PR 37)
+_SAMPLE_ROWS = 2048
+_SAMPLE_RUNS = 4
+
+
+class _Slots:
+    """An object array's slots (the pointers to its values) as a read-only
+    ``intp`` array over the array's own buffer, strides as they are: numpy
+    refuses ``view`` on an array of references. Rows hold the same pointer
+    where they are the same object, and the view keeps the array alive."""
+
+    def __init__(self, arr: np.ndarray):
+        face = dict(arr.__array_interface__)
+        face.pop("descr", None)
+        face.update(typestr=np.dtype(np.intp).str, data=(face["data"][0], True))
+        self.base, self.__array_interface__ = arr, face
+
+
+def _shares_objects(valid: np.ndarray, slots: np.ndarray) -> bool:
+    """Whether equal values of the column are the same OBJECT nearly
+    everywhere, from the sample: inside its runs at least a sixteenth of the
+    rows repeat an object of their run, and of the rows that repeat a value
+    at most a fifth fail to repeat an object. A table made by
+    ``astype(str)`` or a ``csv`` loop, or free text, has an object a row (no
+    row repeats one); grouping rows by object would buy nothing there and
+    cost more than the value pass, since a hash table of as many pointers as
+    rows lives in no cache (PERF.md section 6, PR 37)."""
+    n = len(valid)
+    runs = _SAMPLE_RUNS if n > _SAMPLE_ROWS else 1
+    width = min(n, _SAMPLE_ROWS) // runs
+    same_object = same_value = 0
+    for i in range(runs):
+        at = (2 * i + 1) * n // (2 * runs) - width // 2     # mid-segment
+        same_object += width - len(np.unique(slots[at:at + width]))
+        same_value += width - len({str(v) for v in valid[at:at + width]})
+    return (16 * same_object >= runs * width
+            and 4 * same_value <= 5 * same_object)
+
+
+def _first_rows(codes: np.ndarray, k: int) -> np.ndarray:
+    """The row where each of ``k`` codes, numbered in order of first
+    appearance, first appears: where the running maximum of ``codes`` steps
+    up. Read by blocks (short ones first) until the last code is met, so a
+    column whose levels all turn up early is not read to its end, and only a
+    block's rows above the maximum so far are looked at twice."""
+    first = np.empty(k, dtype=np.intp)
+    top, start = -1, 0
+    while top < k - 1 and start < len(codes):
+        part = codes[start:start + min(65536, max(4096, start))]
+        new = np.flatnonzero(part > top)        # rows of codes not met before
+        if len(new):
+            run = np.maximum.accumulate(part[new])
+            step = new[np.flatnonzero(np.diff(run, prepend=top))]
+            first[part[step]] = start + step
+            top = int(run[-1])
+        start += len(part)
+    return first
+
+
+def _resolve(values: np.ndarray) -> Tuple[np.ndarray, List[str], str]:
+    """Values → (each one's index into the distinct values, these as ``str``
+    in order of first appearance, ``path``): ``"hashed"`` where every value
+    is a ``str`` already and the objects are hashed as they are; anything
+    else goes through ``str()`` first (``"str_pass"``), since
+    ``1 == True == 1.0`` would merge under the hash what ``str()`` keeps
+    apart."""
+    hashed = values.dtype == object and pd.api.types.infer_dtype(
+        values, skipna=False) in ("string", "empty")
     if hashed:
-        sub, uniques = _hash_pass(valid)
+        sub, uniques = _hash_pass(values)
         levels = [str(u) for u in uniques]
         # a str subclass with a str() of its own: levels that str() merges
         hashed = len(set(levels)) == len(levels)
     if not hashed:
         sub, levels = _hash_pass(
-            np.array([str(v) for v in valid], dtype=object))
+            np.array([str(v) for v in values], dtype=object))
+    return sub, levels, "hashed" if hashed else "str_pass"
+
+
+def _factorize_valid(vals: np.ndarray, m: np.ndarray
+                     ) -> Tuple[np.ndarray, Dict[str, int], str, int]:
+    """Values of the rows where ``m`` holds → ``(codes, counts, path,
+    objects)``: ``counts`` maps each distinct value, as ``str`` and in order
+    of first appearance, to its occurrences; ``codes`` (n,) is each valid
+    row's position among them and -1 where ``m`` is false; ``path`` says how
+    values were resolved (``_resolve``). Rows that are the same object have
+    the same value: where the sample shows that equal values mostly are one
+    object (``_shares_objects``), the rows are grouped by object first, an
+    integer pass over the pointers, and values are resolved once an object,
+    ``objects`` of them, not once a row; ``objects`` is 0 where every row's
+    value was resolved. No per-row Python on the hashed path."""
+    every = bool(m.all())
+    valid = vals if every else vals[m]
+    objects = 0
+    if (len(valid) > _SMALL_HASH_PASS and valid.dtype == object
+            and _shares_objects(valid, slots := np.asarray(_Slots(valid)))):
+        sub, distinct = pd.factorize(slots)
+        objects = len(distinct)
+        merge, levels, path = _resolve(valid[_first_rows(sub, objects)])
+        if len(levels) < objects:       # objects that resolve to one level
+            sub = merge[sub]
+    else:
+        sub, levels, path = _resolve(valid)
     counts = dict(zip(levels,
                       np.bincount(sub, minlength=len(levels)).tolist()))
-    path = "hashed" if hashed else "str_pass"
     if every:
-        return sub, counts, path
+        return sub, counts, path, objects
     codes = np.full(len(vals), -1, dtype=np.intp)
     codes[m] = sub
-    return codes, counts, path
+    return codes, counts, path, objects
 
 
 def _top_levels(counts: Dict[str, int], min_support: int, top_k: int
@@ -415,17 +499,18 @@ def _top_levels(counts: Dict[str, int], min_support: int, top_k: int
 
 #: the codes a fit's count pass made of a column, until the transform of the
 #: same array takes them: ``id(values) -> (weakref to the values, mask,
-#: codes, levels, path)``. ``train()`` fits a pivot and then transforms the
-#: table it was fitted on, so the column is hashed once and not twice. Every
-#: fit overwrites its column's entry and the first transform of that array
-#: removes it, so nothing is carried from one train to the next or to a
-#: score; an entry whose array has died goes with it.
-_FIT_CODES: Dict[int, Tuple[Any, np.ndarray, np.ndarray, List[str], str]] = {}
+#: codes, levels, path, objects)``. ``train()`` fits a pivot and then
+#: transforms the table it was fitted on, so the column is hashed once and
+#: not twice. Every fit overwrites its column's entry and the first transform
+#: of that array removes it, so nothing is carried from one train to the next
+#: or to a score; an entry whose array has died goes with it.
+_FIT_CODES: Dict[int, Tuple[Any, np.ndarray, np.ndarray, List[str], str,
+                           int]] = {}
 _FIT_CODES_MAX = 64
 
 
 def _keep_fit_codes(vals: np.ndarray, m: np.ndarray, codes: np.ndarray,
-                    levels: List[str], path: str) -> None:
+                    levels: List[str], path: str, objects: int) -> None:
     key = id(vals)
     try:
         ref = weakref.ref(vals, lambda _: _FIT_CODES.pop(key, None))
@@ -433,28 +518,28 @@ def _keep_fit_codes(vals: np.ndarray, m: np.ndarray, codes: np.ndarray,
         return
     while len(_FIT_CODES) >= _FIT_CODES_MAX:
         _FIT_CODES.pop(next(iter(_FIT_CODES)), None)
-    _FIT_CODES[key] = (ref, m, codes, levels, path)
+    _FIT_CODES[key] = (ref, m, codes, levels, path, objects)
 
 
 def _encode_valid(vals: np.ndarray, m: np.ndarray, index: Dict[str, int],
-                  track_nulls: bool) -> Tuple[np.ndarray, str]:
+                  track_nulls: bool) -> Tuple[np.ndarray, str, int]:
     """Each row's position inside its pivot block (int32: the vocabulary
     index under ``index``, ``k = len(index)`` for a level not in it and,
     where ``m`` is false, ``k + 1`` if nulls are tracked and -1, no column,
-    if not) and the path ``_factorize_valid`` took: the dictionary is asked
-    once per level, not once per row. The codes are the fit's own where
-    this is the array (and the mask) it counted."""
+    if not), and the path and the objects ``_factorize_valid`` gave: the
+    dictionary is asked once per level, not once per row. The codes are the
+    fit's own where this is the array (and the mask) it counted."""
     kept = _FIT_CODES.pop(id(vals), None)
     if kept is not None and kept[0]() is vals and np.array_equal(kept[1], m):
-        _, _, codes, levels, path = kept
+        _, _, codes, levels, path, objects = kept
     else:
-        codes, counts, path = _factorize_valid(vals, m)
+        codes, counts, path, objects = _factorize_valid(vals, m)
         levels = list(counts)
     k = len(index)
     # the last entry is the one the null rows' -1 reaches
     lut = np.array([index.get(v, k) for v in levels]
                    + [k + 1 if track_nulls else -1], dtype=np.int32)
-    return lut[codes], path
+    return lut[codes], path, objects
 
 
 #: rows from which the chip makes the dense blocks: the pivot hands it each
@@ -534,9 +619,9 @@ class OneHotVectorizer(Estimator):
                     cnt = Counter(v for vs, ok in zip(vals, m) if ok
                                   for v in (vs or ()))
                 else:
-                    codes, cnt, path = _factorize_valid(vals, m)
-                    _keep_fit_codes(vals, m, codes, list(cnt), path)
-                    count_span.set_attr(path=path)
+                    codes, cnt, path, objects = _factorize_valid(vals, m)
+                    _keep_fit_codes(vals, m, codes, list(cnt), path, objects)
+                    count_span.set_attr(path=path, objects=objects)
                 vocabs.append(_top_levels(cnt, self.min_support, self.top_k))
                 count_span.set_attr(levels=len(cnt))
         model = OneHotVectorizerModel(vocabs=vocabs, track_nulls=self.track_nulls)
@@ -578,9 +663,9 @@ class OneHotVectorizerModel(_VectorModelBase):
                         for v in (vs or ()):
                             block[i, index.get(v, k)] = 1.0
                 else:
-                    pos, path = _encode_valid(vals, m, index,
-                                              self.track_nulls)
-                    encode_span.set_attr(path=path)
+                    pos, path, objects = _encode_valid(vals, m, index,
+                                                       self.track_nulls)
+                    encode_span.set_attr(path=path, objects=objects)
             # positions to the dense block: on the host, or on their way to
             # the chip (the launch of the upload)
             with _obs_span("onehot.expand", column=f.name,
@@ -1072,7 +1157,7 @@ class SmartTextVectorizer(Estimator):
             col = table[f.name]
             vals = np.asarray(col.values)
             m = col.valid_mask()
-            _, cnt, _ = _factorize_valid(vals, m)
+            _, cnt, _, _ = _factorize_valid(vals, m)
             if len(cnt) <= self.max_cardinality:
                 plans.append({"kind": "pivot", "vocab": _top_levels(
                     cnt, self.min_support, self.top_k)})
