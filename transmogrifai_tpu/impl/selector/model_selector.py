@@ -537,10 +537,11 @@ class ModelSelector(AllowLabelAsInput, Estimator):
                         else:
                             X, lab, mask = self._rows_on_device(
                                 Xd_all, y_dense, idx)
-                    with _obs_span("evaluate.predict", **said), \
+                    family = MODEL_REGISTRY[fitted.family]
+                    with _obs_span("evaluate.predict", **said) as step, \
                             engine_mesh(self.mesh):
-                        parts = MODEL_REGISTRY[fitted.family].predict_parts(
-                            fitted, X)
+                        parts = family.predict_parts(fitted, X)
+                        step.set_attr(**family.predict_span_attrs(fitted))
                     with _obs_span("evaluate.metrics", **said):
                         results.append(ev.evaluate_parts(lab, parts, mask))
                     host_bytes += 4 * _count_numbers(results[-1])
@@ -729,9 +730,10 @@ class SelectedModel(AllowLabelAsInput, Transformer):
         family = MODEL_REGISTRY[self.fitted.family]
         # predict_one hands back host arrays: the span holds the launch of
         # the predict and the host's wait for its parts
-        with _obs_span("predict.parts", family=self.fitted.family), \
+        with _obs_span("predict.parts", family=self.fitted.family) as step, \
                 engine_mesh(mesh):
             parts = family.predict_one(self.fitted, X)
+            step.set_attr(rows=n, **family.predict_span_attrs(self.fitted))
         with _obs_span("predict.unmap"):
             if n_pad != n:
                 parts = {k: v[:n] for k, v in parts.items()}

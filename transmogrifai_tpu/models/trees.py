@@ -57,11 +57,12 @@ import numpy as np
 logger = logging.getLogger(__name__)
 
 from ..ops.forest import (
-    forest_leaf_sums, forest_leaf_sums_chain, forest_predict,
+    _T_CHAIN, forest_leaf_sums, forest_leaf_sums_chain, forest_predict,
     forest_predict_chain,
 )
 from ..histeng import build_hist, build_node_hist, pinned_row_sum
 from ..histeng.kernels import _combine_form, _hist_shards
+from ..observability.trace import span as _obs_span
 from .api import FittedParams, ModelFamily, register_family
 
 N_BINS = 32  # Spark maxBins default (reference DefaultSelectorParams.MaxBin)
@@ -1416,6 +1417,21 @@ class _TreeFamilyBase(ModelFamily):
                 "sampleRows": min(rows, _SWEEP_HIST_SAMPLE if sweep
                                   else _HIST_SAMPLE)}
 
+    def predict_span_attrs(self, fitted):
+        """``trees`` descended a row (a boosted fit's rounds x class
+        planes), their ``depth``, the ``features`` a row has, and
+        ``treeChunks``: the descent kernel's calls a predict
+        (`forest_predict_chain` takes `_T_CHAIN` trees a call,
+        `_forest_values` `_PREDICT_TREE_CHUNK`)."""
+        p = fitted.params
+        chain = "base_lv" in p
+        shape = np.shape(p["feat_lv"] if chain else p["feat"])
+        trees = int(np.prod(shape[:-2] if chain else shape[:-1]))
+        return {"trees": trees, "features": int(np.shape(p["edges"])[-2]),
+                "depth": int(shape[-2]) if chain else _depth_of(shape[-1] + 1),
+                "treeChunks": -(-trees // (_T_CHAIN if chain
+                                           else _PREDICT_TREE_CHUNK))}
+
     def select_params(self, batched, idx):
         """Per-config slice, except the bin-edge table, which is shared by
         every configuration of a fit and stored once."""
@@ -1879,11 +1895,22 @@ class GBTFamilyBase(_TreeFamilyBase):
 
         md = np.asarray(grid["maxDepth"], dtype=np.float64).reshape(-1)
         d_max = int(md.max())
+
+        def fit(slots):
+            if sweep:
+                return one_call(grid, weights, d_max, slots)
+            # a refit: the regrow on the split-search sample is all of it
+            # (boosting has no exact leaf pass), one program under its span
+            with _obs_span("refit.grow", family=self.name, trees=n_rounds,
+                           depth=d_max, slots=slots,
+                           sampleRows=min(int(X.shape[0]), _HIST_SAMPLE)):
+                return one_call(grid, weights, d_max, slots)
+
         if d_max <= _MAX_HEAP_DEPTH:
             # no depth grouping: boosting rounds are a sequential scan, and
             # a second scan chain for shallow configs costs more than the
             # wasted deep levels (their active-mask already stops splitting)
-            return one_call(grid, weights, d_max)
+            return fit(0)
         # deep grid: ONE slot-chain scan for ALL configs at the deepest
         # depth. Boosting is step-count-bound (each of rounds x levels
         # sequential steps carries ~ms of small-op overhead at GBT's narrow
@@ -1897,7 +1924,7 @@ class GBTFamilyBase(_TreeFamilyBase):
         shallow = md[md <= _MAX_HEAP_DEPTH]
         if shallow.size:  # budget must hold a shallow config's full tree
             n_slots = max(n_slots, 2 ** int(shallow.max()))
-        return one_call(grid, weights, d_max, n_slots)
+        return fit(n_slots)
 
     def predict_batch(self, params, X, num_classes):
         edges = self._edges_of(params)
