@@ -171,7 +171,8 @@ def test_the_steps_say_what_they_worked_on(train):
     assert fit_stats.attrs == {"path": "host"}
     # x1, x2 and their null flags as float32
     assert fill.attrs == {"path": "host", "bytes": ROWS * 4 * 4}
-    assert stack.attrs == {"columns": 4, "bytes": ROWS * 4 * 4}
+    assert stack.attrs == {"path": "host", "columns": 4,
+                           "bytes": ROWS * 4 * 4}
     (pad,), (column,) = by["predict.pad"], by["predict.column"]
     assert pad.attrs["rows"] == ROWS <= pad.attrs["paddedRows"]
     assert column.attrs["bytes"] > 0

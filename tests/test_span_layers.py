@@ -216,9 +216,34 @@ def test_the_device_path_says_so(monkeypatch):
     (concat,) = _named(mine, "onehot.concat")
     assert concat.attrs["bytes"] == ROWS * (6 + 4) * 4
     attrs = _combiner_span(mine).attrs
-    # x1, x2 and their null flags: four float32 columns from the host
+    # x1, x2 and their null flags were filled on the chip too (PR 40): both
+    # blocks stay where they are and nothing goes up
     assert (attrs["deviceInputs"], attrs["hostInputs"],
-            attrs["h2dBytes"]) == (1, 1, ROWS * 4 * 4)
+            attrs["h2dBytes"]) == (2, 0, 0)
+    _real_spans_of_one_transform(mine, "device")
+
+
+def _real_spans_of_one_transform(spans, path):
+    """``realvec.fill`` and ``realvec.stack``, once a transform of the Real
+    model, each with its ``path``: the benchmark's ``span_sum`` readers of
+    ``fe_real_fill_s`` / ``fe_real_stack_s`` find a value on either path."""
+    (stage,) = [s for s in _named(spans, "stage.transform")
+                if s.attrs["stage"] == "RealVectorizerModel"]
+    kids = sorted((s for s in spans if s.parent_id == stage.span_id),
+                  key=lambda s: s.ts_ns)
+    assert [(s.name, s.attrs["path"]) for s in kids] == [
+        ("realvec.fill", path), ("realvec.stack", path)]
+    fill, stack = kids
+    # what went up: x1 and x2 as float32 and a mask each; the host path
+    # counts what it filled, the four columns of the block
+    assert fill.attrs["bytes"] == ROWS * ((2 * 4 + 2) if path == "device"
+                                          else 4 * 4)
+    assert (stack.attrs["columns"], stack.attrs["bytes"]) == (4, ROWS * 4 * 4)
+
+
+def test_the_real_blocks_spans_are_there_on_the_host_path(spans):
+    _, mine = _root(spans, "workflow.train")
+    _real_spans_of_one_transform(mine, "host")
 
 
 @pytest.mark.parametrize("values,path,objects,own", [

@@ -439,11 +439,13 @@ def test_first_rows_are_where_each_code_first_appears(codes):
 
 def _pivot_on(monkeypatch, path, stage, fit_table, table):
     """``stage`` fitted on ``fit_table`` and applied to ``table`` with the
-    row constant set so that ``path`` is taken; (column, its spans)."""
+    row constant set so that ``path`` is taken (None: as it is); (column,
+    its spans)."""
     from transmogrifai_tpu.impl.feature import vectorizers
     from transmogrifai_tpu.observability import trace as ot
-    monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS",
-                        1 if path == "device" else 10 ** 12)
+    if path is not None:
+        monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS",
+                            1 if path == "device" else 10 ** 12)
     model = stage.fit(fit_table)
     ot.reset()
     ot.enable_tracing(True)
@@ -554,14 +556,15 @@ def test_a_multipicklist_input_keeps_the_host_path(monkeypatch):
     assert np.array_equal(got["host"].values, got["device"].values)
 
 
-def _categorical_workflow(df):
+def _categorical_workflow(df, reals=("x1",), picks=("c1", "c2")):
     from transmogrifai_tpu.impl.selector.factories import (
         BinaryClassificationModelSelector,
     )
     label = FeatureBuilder.RealNN("y").extract_field().as_response()
-    feats = [FeatureBuilder.Real("x1").extract_field().as_predictor(),
-             FeatureBuilder.PickList("c1").extract_field().as_predictor(),
-             FeatureBuilder.PickList("c2").extract_field().as_predictor()]
+    feats = [FeatureBuilder.Real(k).extract_field().as_predictor()
+             for k in reals]
+    feats += [FeatureBuilder.PickList(k).extract_field().as_predictor()
+              for k in picks]
     checked = transmogrify(feats).sanity_check(label)
     pred = (BinaryClassificationModelSelector.with_cross_validation(
         models=[("OpLogisticRegression", [{"regParam": 0.0135,
@@ -680,3 +683,217 @@ def test_the_pivot_sends_positions_and_nothing_else(monkeypatch):
         om.reset()
     assert moved == {"direction=h2d": 200 * 2 * 4.0}
     assert col.values.shape == (200, 4 + 6)
+
+
+# -- the chip fills the Real block from column uploads (PR 40) ----------------
+
+def _real_case(stage, track_nulls, nulls, n=96, seed=11):
+    """A stage of three inputs and their table: float32, float64 and int64
+    storage (an Integral stage: int64, int32, int64), every column masked
+    where ``nulls``, else one column without a mask object at all."""
+    from transmogrifai_tpu.table import Column
+    rng = np.random.RandomState(seed)
+    integral = stage == "integral"
+    ftype = Integral if integral else Real
+    dtypes = ((np.int64, np.int32, np.int64) if integral
+              else (np.float32, np.float64, np.int64))
+    cols, feats = {}, []
+    for j, dt in enumerate(dtypes):
+        vals = (rng.randint(-5, 6, n) if np.issubdtype(dt, np.integer)
+                else rng.randn(n) * 1e3 + 7).astype(dt)
+        if nulls:
+            mask = rng.rand(n) > 0.3
+            if j == 2:
+                mask[:] = False               # an all-null column
+        else:
+            mask = None if j == 0 else np.ones(n, bool)
+        cols[f"x{j}"] = Column(ftype, vals, mask)
+        feats.append(getattr(FeatureBuilder, ftype.__name__)(f"x{j}")
+                     .extract_field().as_predictor())
+    st = {"real_mean": lambda: RealVectorizer(track_nulls=track_nulls),
+          "real_constant": lambda: RealVectorizer(
+              fill_with_mean=False, fill_value=-2.5, track_nulls=track_nulls),
+          "integral": lambda: IntegralVectorizer(track_nulls=track_nulls),
+          }[stage]()
+    st.set_input(*feats)
+    return st, FeatureTable(cols, n)
+
+
+def _fill_paths(spans):
+    return [(s.name, s.attrs["path"]) for s in spans
+            if s.name.startswith("realvec.")]
+
+
+@pytest.mark.parametrize("nulls", [True, False], ids=["nulls", "no_nulls"])
+@pytest.mark.parametrize("track_nulls", [True, False],
+                         ids=["tracked", "untracked"])
+@pytest.mark.parametrize("stage", ["real_mean", "real_constant", "integral"])
+def test_device_real_block_equals_the_host_block_bit_for_bit(
+        monkeypatch, stage, track_nulls, nulls):
+    """Values AND ``vector_meta`` of the block the chip filled from column
+    uploads are the host block's: nulls and none, tracked and not, mean,
+    constant and mode fills, float64 and integer storage."""
+    import jax
+    got = {}
+    for path in ("host", "device"):
+        st, table = _real_case(stage, track_nulls, nulls)
+        got[path], spans = _pivot_on(monkeypatch, path, st, table, table)
+        assert _fill_paths(spans) == [("realvec.fill", path),
+                                      ("realvec.stack", path)]
+    assert isinstance(got["host"].values, np.ndarray)
+    assert isinstance(got["device"].values, jax.Array)
+    dev = np.asarray(got["device"].values)
+    assert dev.dtype == np.float32
+    assert dev.shape == got["host"].values.shape == (
+        96, 3 * (2 if track_nulls else 1))
+    assert np.array_equal(dev.view(np.uint32),
+                          got["host"].values.view(np.uint32))
+    assert _meta_tuples(got["device"]) == _meta_tuples(got["host"])
+
+
+@pytest.mark.parametrize("rows_off", [-1, 0], ids=["just_under", "at"])
+def test_the_row_constant_decides_the_real_blocks_path(rows_off):
+    """At the constant itself (not patched) the chip fills the block; one
+    row fewer and the host does. Both spans say which."""
+    import jax
+    from transmogrifai_tpu.impl.feature import vectorizers
+    n = vectorizers._DEVICE_BLOCK_MIN_ROWS + rows_off
+    st, table = _real_case("real_mean", True, True, n=n)
+    col, spans = _pivot_on(None, None, st, table, table)
+    path = "device" if rows_off == 0 else "host"
+    assert _fill_paths(spans) == [("realvec.fill", path),
+                                  ("realvec.stack", path)]
+    assert isinstance(col.values, jax.Array if rows_off == 0 else np.ndarray)
+    vals = np.asarray(col.values)
+    assert vals.shape == (n, 6) and vals.dtype == np.float32
+    x0 = table["x0"]
+    assert np.array_equal(vals[:, 0], np.where(
+        x0.mask, x0.values, np.float32(x0.values[x0.mask].mean(
+            dtype=np.float64))))
+    assert np.array_equal(vals[:, 1] == 1.0, ~x0.mask)
+
+
+def test_the_real_block_sends_columns_and_masks_and_nothing_else(monkeypatch):
+    """``tg_transfer_bytes_total`` of a device-path fill: four bytes a row
+    and column and one a row and mask, against the block's eight a row and
+    column; a second fit with other fills finds the program."""
+    from benchmark.harness import COMPILE_EVENT, Monitor
+    from transmogrifai_tpu.impl.feature import vectorizers
+    from transmogrifai_tpu.observability import metrics as om
+    monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS", 64)
+    n = 200
+    st, table = _real_case("real_mean", True, False, n=n)    # two masks
+    model = st.fit(table)
+    om.reset()
+    om.enable_metrics(True)
+    try:
+        col = model.transform_column(table)
+        moved = om.registry().snapshot()["tg_transfer_bytes_total"]
+    finally:
+        om.reset()
+    assert moved == {"direction=h2d": n * 3 * 4.0 + n * 2 * 1.0}
+    assert col.values.shape == (n, 6)
+    # the fills are an argument of the program, not constants inside it
+    other, _ = _real_case("real_constant", True, False, n=n)
+    again = other.fit(table)
+    assert again.fills != model.fills
+    monitor = Monitor().install()
+    monitor.phase = "second"
+    second = again.transform_column(table)
+    monitor.phase = "after"
+    assert monitor.count("second", COMPILE_EVENT) == 0
+    assert np.array_equal(np.asarray(second.values),
+                          np.asarray(col.values))   # no null: no fill shows
+
+
+@pytest.mark.parametrize("n", [4000, 4001], ids=["divides", "odd"])
+def test_the_real_block_is_born_sharded_under_a_mesh(monkeypatch, n):
+    """Under ``data=4`` the columns go up as shards and the block's rows are
+    sharded; a row count the data axis does not divide stays on one
+    device. The bits are the host path's either way."""
+    import jax
+    from transmogrifai_tpu.impl.feature import vectorizers
+    from transmogrifai_tpu.parallel.mesh import MeshSpec, make_mesh
+    from transmogrifai_tpu.parallel.sharded import row_sharding
+    st, table = _real_case("real_constant", True, True, n=n)
+    want, _ = _pivot_on(monkeypatch, "host", st, table, table)
+    monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS", 1000)
+    mesh = make_mesh(MeshSpec(data=4, model=1), devices=jax.devices()[:4])
+    st, table = _real_case("real_constant", True, True, n=n)
+    model = st.set_mesh(mesh).fit(table)
+    assert model.mesh is mesh
+    went_up = []
+    real = vectorizers._upload
+
+    def upload(host, mesh, site):
+        went_up.append(real(host, mesh, site))
+        return went_up[-1]
+    monkeypatch.setattr(vectorizers, "_upload", upload)
+    col = model.transform_column(table)
+    assert sorted(a.shape for a in went_up) == [(n,)] * 6
+    if n % 4 == 0:
+        assert col.values.sharding.is_equivalent_to(row_sharding(mesh, 2), 2)
+        for a in went_up:
+            assert a.sharding.is_equivalent_to(row_sharding(mesh, 1), 1)
+    else:
+        assert len(col.values.sharding.device_set) == 1
+    assert np.array_equal(np.asarray(col.values), want.values)
+
+
+def test_a_real_block_reaches_the_selector_without_a_host_matrix(monkeypatch):
+    """Real vectorizer -> SanityChecker -> selector: with reals alone
+    ``transmogrify`` hands the one vector on without a combiner, so the
+    Real block goes to the checker as it is (the ``train-higgs`` shape).
+    Over the row constant the checker, its model and the selector are
+    handed device arrays, no matrix of rows x derived columns is read back
+    to the host, and the scores are the host path's bits."""
+    import jax
+    import pandas as pd
+    from jax._src.array import ArrayImpl
+    from transmogrifai_tpu.impl.feature import vectorizers
+    from transmogrifai_tpu.impl.preparators import sanity_checker as sc
+    from transmogrifai_tpu.impl.selector import model_selector as ms
+    rng = np.random.RandomState(4)
+    n = 700
+    df = pd.DataFrame({"x1": rng.randn(n), "x2": rng.randn(n),
+                       "x3": rng.randn(n)})
+    df.loc[rng.rand(n) < 0.2, "x2"] = np.nan
+    df["y"] = ((df.x1 - df.x3) > 0).astype(float)
+    seen = []
+
+    def spying(cls, method):
+        real = getattr(cls, method)
+
+        def spy(self, table):
+            seen.append((cls.__name__, method, type(
+                table[self.input_features[1].name].values)))
+            return real(self, table)
+        monkeypatch.setattr(cls, method, spy)
+    spying(sc.SanityChecker, "fit_queued")
+    spying(sc.SanityCheckerModel, "transform_column")
+    spying(ms.ModelSelector, "fit")
+    spying(ms.SelectedModel, "transform_column")
+    real_read = ArrayImpl.__array__
+
+    def read_back(self, *a, **kw):
+        assert not (self.ndim == 2 and self.shape[0] == n
+                    and self.shape[1] >= 3), "a matrix came back to the host"
+        return real_read(self, *a, **kw)
+    scores = {}
+    for path, rows in (("host", 10 ** 12), ("device", 256)):
+        monkeypatch.setattr(vectorizers, "_DEVICE_BLOCK_MIN_ROWS", rows)
+        del seen[:]
+        wf, pred = _categorical_workflow(df, ("x1", "x2", "x3"), ())
+        if path == "device":
+            monkeypatch.setattr(ArrayImpl, "__array__", read_back)
+        try:
+            model = wf.train()
+        finally:
+            monkeypatch.setattr(ArrayImpl, "__array__", real_read)
+        kinds = {np.ndarray if path == "host" else jax.Array}
+        assert len(seen) == 4 and all(
+            issubclass(t, tuple(kinds)) for _, _, t in seen), seen
+        assert [type(s).__name__ for s in model.stages[:2]] == [
+            "RealVectorizerModel", "SanityCheckerModel"]
+        scores[path] = np.asarray(model.score(df=df)[pred.name].values)
+    assert np.array_equal(scores["host"], scores["device"])

@@ -11,10 +11,12 @@ hashing) run host-side in vectorized numpy — they are string work the TPU
 cannot express — and emit dense float32 blocks; everything downstream (models,
 stats, scoring) consumes the resulting device arrays. From
 ``_DEVICE_BLOCK_MIN_ROWS`` rows on, the pivot hands the device each row's
-position in its column's block and the device writes the dense block, and
-the combiner joins its inputs there: no host array of (rows x derived
-columns) is made. Null semantics match the reference: mean/mode fill + a
-tracked null-indicator column per feature.
+position in its column's block and the device writes the dense block, the
+Real / Integral fills send each input column up by itself (values, and the
+validity mask where there is one) and the device fills them into their
+block, and the combiner joins its inputs there: no host array of (rows x
+derived columns) is made. Null semantics match the reference: mean/mode
+fill + a tracked null-indicator column per feature.
 """
 from __future__ import annotations
 
@@ -73,10 +75,13 @@ class _VectorModelBase(Transformer):
 
     output_type = OPVector
 
-    def _emit(self, mat: np.ndarray, meta_cols: List[VectorColumnMetadata]) -> Column:
+    def _emit(self, mat, meta_cols: List[VectorColumnMetadata]) -> Column:
+        """``mat`` as the stage's column: a host matrix as contiguous
+        float32, a block the device wrote as it is."""
         vm = VectorMetadata.of(self.get_output().name, meta_cols)
-        return Column(OPVector, np.ascontiguousarray(mat, dtype=np.float32),
-                      None, {"vector_meta": vm})
+        if not isinstance(mat, jax.Array):
+            mat = np.ascontiguousarray(mat, dtype=np.float32)
+        return Column(OPVector, mat, None, {"vector_meta": vm})
 
     def transform_row(self, row: Dict[str, Any]) -> Any:
         one = FeatureTable(
@@ -160,6 +165,7 @@ class RealVectorizer(Estimator):
                     else:
                         fills.append(self.fill_value)
         model = RealVectorizerModel(fills=fills, track_nulls=self.track_nulls)
+        model.mesh = mesh                            # run-time, never saved
         return self._finalize_model(model)
 
     # -- streaming fit (OpWorkflow.train(stream=...), docs/streaming.md) -----
@@ -206,20 +212,41 @@ class RealVectorizer(Estimator):
         return finish(run.fold(pass_id, fold, extract))
 
 
-def _device_fill_blocks(input_features, fills, track_nulls, env):
-    """Shared pure-jax fill+null-track dual used by the fused serve program
-    (local/scoring.compiled_score_function): env maps input feature name →
-    (values, mask-or-None) jnp arrays; ``fills`` yields one fill per input."""
-    import jax.numpy as jnp
+def _fill_blocks(values, masks, fills, track_nulls):
+    """The fill and null-track arithmetic, stated once: each column's
+    ``where(mask, value, fill)`` and, with ``track_nulls``, ``~mask`` as
+    float32 beside it, as one ``(rows, columns)`` float32 block. A mask of
+    None is a column without nulls; a fill may be a number or a traced
+    scalar."""
     blocks = []
-    for f, fill in zip(input_features, fills):
-        vals, mask = env[f.name]
+    for vals, mask, fill in zip(values, masks, fills):
         vals = vals.reshape(-1).astype(jnp.float32)
         m = jnp.ones(vals.shape, bool) if mask is None else mask
         blocks.append(jnp.where(m, vals, jnp.float32(fill)))
         if track_nulls:
             blocks.append((~m).astype(jnp.float32))
-    return jnp.stack(blocks, axis=1), None
+    return jnp.stack(blocks, axis=1)
+
+
+def _device_fill_blocks(input_features, fills, track_nulls, env):
+    """Shared pure-jax fill+null-track dual used by the fused serve program
+    (local/scoring.compiled_score_function): env maps input feature name →
+    (values, mask-or-None) jnp arrays; ``fills`` yields one fill per input."""
+    values, masks = zip(*(env[f.name] for f in input_features))
+    return _fill_blocks(values, masks, fills, track_nulls), None
+
+
+@partial(jax.jit, static_argnames=("track_nulls", "mesh"))
+def _real_block(values, masks, fills, track_nulls: bool, mesh):
+    """Each column's (n,) float32 values and (n,) bool mask (None where the
+    column has none) → the (n, columns) float32 block of ``_fill_blocks``.
+    ``fills`` is a (k,) float32 ARGUMENT: a new fit on the same table finds
+    the program. Rows are independent: under a mesh every chip writes its
+    own."""
+    out = _fill_blocks(values, masks, fills, track_nulls)
+    if mesh is not None:
+        out = jax.lax.with_sharding_constraint(out, row_sharding(mesh, 2))
+    return out
 
 
 class RealVectorizerModel(_VectorModelBase):
@@ -227,28 +254,59 @@ class RealVectorizerModel(_VectorModelBase):
         super().__init__("vecReal", uid)
         self.fills = fills
         self.track_nulls = track_nulls
+        self.mesh = None
 
     def device_columnar(self, env):
         return _device_fill_blocks(self.input_features, self.fills,
                                    self.track_nulls, env)
 
     def transform_column(self, table: FeatureTable) -> Column:
-        blocks, meta = [], []
+        """From ``_DEVICE_BLOCK_MIN_ROWS`` rows on each input column goes up
+        by itself and ``_real_block`` fills the block on the device (under
+        the mesh as shards of rows); a smaller table is filled and stacked
+        on the host."""
+        n = table.num_rows
+        cols = [table[f.name] for f in self.input_features]
+        meta = []
+        for f in self.input_features:
+            meta.extend(_meta_cols(
+                f, [(f.name, None), (f.name, NULL_INDICATOR)]
+                if self.track_nulls else [(f.name, None)]))
+        if n >= _DEVICE_BLOCK_MIN_ROWS:
+            mesh = _rows_mesh(getattr(self, "mesh", None), n)
+            # a cast where the column is not float32, then the launches of
+            # the columns' uploads: values, and masks where there are any
+            with _obs_span("realvec.fill", path="device",
+                           columns=len(cols)) as step:
+                values = tuple(_upload(
+                    np.asarray(c.values, dtype=np.float32).reshape(-1),
+                    mesh, "realvec.upload") for c in cols)
+                masks = tuple(None if c.mask is None else _upload(
+                    c.valid_mask().reshape(-1), mesh, "realvec.upload")
+                    for c in cols)
+                step.set_attr(bytes=sum(
+                    int(a.nbytes) for a in values + masks if a is not None))
+            # the launch of the one program that writes the block
+            with _obs_span("realvec.stack", path="device",
+                           columns=len(meta)) as step:
+                out = self._emit(_real_block(
+                    values, masks, np.asarray(self.fills, dtype=np.float32),
+                    track_nulls=bool(self.track_nulls), mesh=mesh), meta)
+                step.set_attr(bytes=int(out.values.nbytes))
+            return out
+        blocks = []
         # a cast, a fill and a null indicator a column, on the host
         with _obs_span("realvec.fill", path="host") as step:
-            for f, fill in zip(self.input_features, self.fills):
-                col = table[f.name]
+            for col, fill in zip(cols, self.fills):
                 vals = np.asarray(col.values, dtype=np.float32).reshape(-1)
                 m = col.valid_mask()
-                filled = np.where(m, vals, np.float32(fill))
-                blocks.append(filled)
-                meta.extend(_meta_cols(f, [(f.name, None)]))
+                blocks.append(np.where(m, vals, np.float32(fill)))
                 if self.track_nulls:
                     blocks.append((~m).astype(np.float32))
-                    meta.extend(_meta_cols(f, [(f.name, NULL_INDICATOR)]))
             step.set_attr(bytes=sum(int(b.nbytes) for b in blocks))
         # the (rows, columns) block the combiner reads
-        with _obs_span("realvec.stack", columns=len(blocks)) as step:
+        with _obs_span("realvec.stack", path="host",
+                       columns=len(blocks)) as step:
             out = self._emit(np.stack(blocks, axis=1), meta)
             step.set_attr(bytes=int(out.values.nbytes))
         return out
@@ -267,6 +325,13 @@ class IntegralVectorizer(Estimator):
         self.fill_with_mode = fill_with_mode
         self.fill_value = fill_value
         self.track_nulls = track_nulls
+        self.mesh = None
+
+    def set_mesh(self, mesh) -> "IntegralVectorizer":
+        """The fitted model writes its block with the rows sharded over the
+        mesh's 'data' axis (SURVEY §2.10 P1)."""
+        self.mesh = mesh
+        return self
 
     def fit(self, table: FeatureTable) -> Transformer:
         fills = []
@@ -282,6 +347,7 @@ class IntegralVectorizer(Estimator):
                 fills.append(float(self.fill_value))
         model = RealVectorizerModel(fills=fills, track_nulls=self.track_nulls)
         model.operation_name = "vecIntegral"
+        model.mesh = getattr(self, "mesh", None)     # run-time, never saved
         return self._finalize_model(model)
 
 
@@ -687,13 +753,9 @@ class OneHotVectorizerModel(_VectorModelBase):
         # the blocks joined: on the device path the launch of the one
         # program that writes them
         with _obs_span("onehot.concat") as concat_span:
-            if on_device:
-                out = Column(OPVector, _pivot_block(
-                    tuple(blocks), widths=tuple(widths), mesh=mesh), None,
-                    {"vector_meta": VectorMetadata.of(
-                        self.get_output().name, meta)})
-            else:
-                out = self._emit(np.concatenate(blocks, axis=1), meta)
+            out = self._emit(_pivot_block(
+                tuple(blocks), widths=tuple(widths), mesh=mesh)
+                if on_device else np.concatenate(blocks, axis=1), meta)
             concat_span.set_attr(bytes=int(out.values.nbytes))
         return out
 
