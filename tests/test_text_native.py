@@ -49,7 +49,8 @@ def test_tokenize_hash_texts_parity():
                     reason="no native toolchain")
 def test_non_ascii_rows_flagged():
     res = text_native.tokenize_hash_native(["plain ascii", "Café"], 16)
-    counts, needs_py = res
+    counts, needs_py, tokens = res
+    assert tokens == 2
     assert not needs_py[0] and needs_py[1]
     # flagged row left zero for the caller
     assert counts[1].sum() == 0

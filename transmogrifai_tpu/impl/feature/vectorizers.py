@@ -1118,19 +1118,30 @@ def tokenize_hash_texts(docs: Sequence[Optional[str]], num_hashes: int,
     native C kernel handles ASCII docs (native/text_ops.cpp), the
     Unicode-aware Python tokenizer fills in the flagged rows — results are
     identical to tokenize_text + hash_token_lists by construction."""
+    return _tokenize_hash_counted(docs, num_hashes, min_token_length,
+                                  binary)[0]
+
+
+def _tokenize_hash_counted(docs: Sequence[Optional[str]], num_hashes: int,
+                           min_token_length: int = 1, binary: bool = False
+                           ) -> Tuple[np.ndarray, str, int, int]:
+    """``tokenize_hash_texts``'s block with what its span says of it: which
+    ``path`` the documents took (``native`` where the C kernel ran,
+    ``python`` where there is none), the rows the Python tokenizer took and
+    the tokens counted, each counted where it is met."""
     from ...utils.text_native import tokenize_hash_native
     res = tokenize_hash_native(docs, num_hashes, min_token_length, binary)
     if res is None:
-        return hash_token_lists(
-            [tokenize_text(d, min_token_length) for d in docs],
-            num_hashes, binary)
-    counts, needs_py = res
-    if needs_py.any():
-        idx = np.nonzero(needs_py)[0]
-        counts[idx] = hash_token_lists(
-            [tokenize_text(docs[i], min_token_length) for i in idx],
-            num_hashes, binary)
-    return counts
+        lists = [tokenize_text(d, min_token_length) for d in docs]
+        return (hash_token_lists(lists, num_hashes, binary), "python",
+                len(docs), sum(map(len, lists)))
+    counts, needs_py, tokens = res
+    idx = np.nonzero(needs_py)[0]
+    if len(idx):
+        lists = [tokenize_text(docs[i], min_token_length) for i in idx]
+        counts[idx] = hash_token_lists(lists, num_hashes, binary)
+        tokens += sum(map(len, lists))
+    return counts, "native", len(idx), tokens
 
 
 def hash_token_lists(token_lists: Sequence[Sequence[str]], num_hashes: int,
@@ -1216,15 +1227,19 @@ class SmartTextVectorizer(Estimator):
     def fit(self, table: FeatureTable) -> Transformer:
         plans: List[Dict[str, Any]] = []
         for f in self.input_features:
-            col = table[f.name]
-            vals = np.asarray(col.values)
-            m = col.valid_mask()
-            _, cnt, _, _ = _factorize_valid(vals, m)
-            if len(cnt) <= self.max_cardinality:
-                plans.append({"kind": "pivot", "vocab": _top_levels(
-                    cnt, self.min_support, self.top_k)})
-            else:
-                plans.append({"kind": "hash"})
+            with _obs_span("text.cardinality", cat="train", column=f.name,
+                           rows=table.num_rows) as card_span:
+                col = table[f.name]
+                vals = np.asarray(col.values)
+                m = col.valid_mask()
+                _, cnt, _, _ = _factorize_valid(vals, m)
+                if len(cnt) <= self.max_cardinality:
+                    plans.append({"kind": "pivot", "vocab": _top_levels(
+                        cnt, self.min_support, self.top_k)})
+                else:
+                    plans.append({"kind": "hash"})
+                card_span.set_attr(distinct=len(cnt),
+                                   plan=plans[-1]["kind"])
         model = SmartTextVectorizerModel(
             plans=plans, num_hashes=self.num_hashes, track_nulls=self.track_nulls)
         return self._finalize_model(model)
@@ -1248,23 +1263,34 @@ class SmartTextVectorizerModel(_VectorModelBase):
             if plan["kind"] == "pivot":
                 vocab = plan["vocab"]
                 k = len(vocab)
-                block = np.zeros((n, k + 1), dtype=np.float32)
-                index = {v: i for i, v in enumerate(vocab)}
-                block[m, _encode_valid(vals, m, index, False)[0][m]] = 1.0
+                with _obs_span("text.pivot", column=f.name, rows=n,
+                               levels=k):
+                    block = np.zeros((n, k + 1), dtype=np.float32)
+                    index = {v: i for i, v in enumerate(vocab)}
+                    block[m, _encode_valid(vals, m, index, False)[0][m]] = 1.0
                 blocks.append(block)
                 meta.extend(_meta_cols(
                     f, [(f.name, v) for v in vocab] + [(f.name, OTHER_INDICATOR)]))
             else:
-                blocks.append(tokenize_hash_texts(
-                    [v if ok else None for v, ok in zip(vals, m)],
-                    self.num_hashes))
+                with _obs_span("text.hash", column=f.name, rows=n,
+                               bins=self.num_hashes) as hash_span:
+                    block, path, py_rows, tokens = _tokenize_hash_counted(
+                        [v if ok else None for v, ok in zip(vals, m)],
+                        self.num_hashes)
+                    hash_span.set_attr(path=path, pyRows=py_rows,
+                                       tokens=tokens,
+                                       bytes=int(block.nbytes))
+                blocks.append(block)
                 meta.extend([VectorColumnMetadata(
                     f.name, f.type_name, f.name, None,
                     descriptor_value=f"hash_{j}") for j in range(self.num_hashes)])
             if self.track_nulls:
                 blocks.append((~m).astype(np.float32)[:, None])
                 meta.extend(_meta_cols(f, [(f.name, NULL_INDICATOR)]))
-        return self._emit(np.concatenate(blocks, axis=1), meta)
+        with _obs_span("text.concat", columns=len(blocks)) as concat_span:
+            out = self._emit(np.concatenate(blocks, axis=1), meta)
+            concat_span.set_attr(bytes=int(out.values.nbytes))
+        return out
 
 
 # ---------------------------------------------------------------------------

@@ -58,12 +58,14 @@ static inline bool word_byte(unsigned char c) {
 // Fused tokenize(lowercase, split on non-word) + crc32 hash + count for
 // packed documents. Non-ASCII documents are skipped with needs_py[d]=1 so
 // the caller can run the Unicode-aware Python tokenizer on just those rows.
-// buf: concatenated doc bytes; offs: (n_docs+1) byte offsets.
-void tg_tokenize_hash_count(const char* buf, const int64_t* offs,
+// buf: concatenated doc bytes; offs: (n_docs+1) byte offsets. Returns the
+// tokens it counted (those of the documents it kept).
+int64_t tg_tokenize_hash_count(const char* buf, const int64_t* offs,
                             int64_t n_docs, int32_t num_hashes,
                             int32_t min_token_len, int32_t binary,
                             float* out, uint8_t* needs_py) {
     unsigned char tok[4096];
+    int64_t n_tokens = 0;
     for (int64_t d = 0; d < n_docs; ++d) {
         const unsigned char* p =
             reinterpret_cast<const unsigned char*>(buf + offs[d]);
@@ -76,6 +78,7 @@ void tg_tokenize_hash_count(const char* buf, const int64_t* offs,
         needs_py[d] = 0;
         float* row = out + d * num_hashes;
         int64_t i = 0;
+        int64_t doc_tokens = 0;
         while (i < len) {
             while (i < len && !word_byte(p[i])) ++i;
             int64_t tl = 0;
@@ -97,14 +100,17 @@ void tg_tokenize_hash_count(const char* buf, const int64_t* offs,
                 const uint32_t h = static_cast<uint32_t>(
                     crc32(0L, tok, static_cast<uInt>(tl)));
                 row[h % static_cast<uint32_t>(num_hashes)] += 1.0f;
+                ++doc_tokens;
             }
         }
         if (needs_py[d]) continue;
+        n_tokens += doc_tokens;
         if (binary) {
             for (int32_t j = 0; j < num_hashes; ++j)
                 if (row[j] > 1.0f) row[j] = 1.0f;
         }
     }
+    return n_tokens;
 }
 
 }  // extern "C"

@@ -14,12 +14,15 @@ library); without a toolchain every entry point degrades to pure Python.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 from typing import List, Optional, Sequence
 
 import numpy as np
+
+logger = logging.getLogger(__name__)
 
 _HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_HERE, "native", "text_ops.cpp")
@@ -55,9 +58,16 @@ def _build_lib() -> Optional[ctypes.CDLL]:
             lib.tg_tokenize_hash_count.argtypes = [
                 ctypes.c_char_p, _I64P, ctypes.c_int64, ctypes.c_int32,
                 ctypes.c_int32, ctypes.c_int32, _F32P, _U8P]
+            lib.tg_tokenize_hash_count.restype = ctypes.c_int64
             _lib = lib
-        except Exception:
+        except Exception as e:
             _lib_failed = True
+            # said once: the flag above keeps every later call off this path
+            said = (getattr(e, "stderr", None) or b"")[-400:]
+            logger.warning(
+                "native text kernels unavailable, every document goes "
+                "through the Python tokenizer: %s: %s %s", type(e).__name__,
+                e, said.decode("utf-8", "replace"))
         return _lib
 
 
@@ -97,10 +107,11 @@ def tokenize_hash_native(
         min_token_length: int = 1, binary: bool = False):
     """Fused tokenize+hash for a document batch.
 
-    Returns (counts (n, num_hashes) float32, needs_py bool (n,)) — rows
-    flagged in needs_py are untouched zeros (non-ASCII or degenerate docs)
-    and must be filled by the Python tokenizer path. Returns None when the
-    native library is unavailable.
+    Returns (counts (n, num_hashes) float32, needs_py bool (n,), tokens) —
+    rows flagged in needs_py are untouched zeros (non-ASCII or degenerate
+    docs) and must be filled by the Python tokenizer path; ``tokens`` is how
+    many tokens the kernel counted in the rows it kept. Returns None when
+    the native library is unavailable.
     """
     lib = _build_lib()
     if lib is None:
@@ -112,8 +123,8 @@ def tokenize_hash_native(
     buf = b"".join(enc)
     out = np.zeros((n, num_hashes), dtype=np.float32)
     needs_py = np.zeros(n, dtype=np.uint8)
-    lib.tg_tokenize_hash_count(
+    tokens = lib.tg_tokenize_hash_count(
         buf, offs.ctypes.data_as(_I64P), n, np.int32(num_hashes),
         np.int32(min_token_length), np.int32(1 if binary else 0),
         out.ctypes.data_as(_F32P), needs_py.ctypes.data_as(_U8P))
-    return out, needs_py.astype(bool)
+    return out, needs_py.astype(bool), int(tokens)
