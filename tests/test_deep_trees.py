@@ -66,50 +66,179 @@ def test_heap_embedding_routes_identically():
     np.testing.assert_allclose(pred, expect, rtol=1e-6)
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_chain_kernels_match_xla(use_pallas, monkeypatch):
-    """Pallas chain descent (interpret mode on CPU) == the XLA fallback, for
-    predict and leaf sums."""
-    monkeypatch.setenv("TG_TREE_PALLAS", "1" if use_pallas else "0")
-    jax.clear_caches()
-    rng = np.random.RandomState(7)
-    n_bins, d, Tn, depth, W = 16, 5, 3, 10, 32
-    codes = jnp.asarray(rng.randint(0, n_bins, size=(257, d), dtype=np.int32))
-    # random but CONSISTENT chain: base pointers within next level's width
-    feat = jnp.asarray(rng.randint(0, d, size=(Tn, depth, W), dtype=np.int32))
+def _random_chain(rng, n_bins, d, Tn, depth, W):
+    """Random but CONSISTENT chain: base pointers within next level's width,
+    some slots leaves (sentinel bin)."""
+    feat = rng.randint(0, d, size=(Tn, depth, W)).astype(np.int32)
     bins_ = rng.randint(0, n_bins - 1, size=(Tn, depth, W)).astype(np.int32)
     base = np.zeros((Tn, depth, W), np.int32)
     for lv in range(depth):
         Wl = min(2 ** lv, W)
         Wn = min(2 ** (lv + 1), W)
         base[:, lv, :Wl] = rng.randint(0, max(Wn - 1, 1), size=(Tn, Wl))
-        # make some slots leaves (sentinel bin)
         stop = rng.rand(Tn, Wl) < 0.3
         bins_[:, lv, :Wl] = np.where(stop, n_bins, bins_[:, lv, :Wl])
-    bins_ = jnp.asarray(bins_)
-    base = jnp.asarray(base)
+    return jnp.asarray(feat), jnp.asarray(bins_), jnp.asarray(base)
+
+
+def _python_slots(codes, feat, bins_, base):
+    """Ground truth by per-row python descent."""
+    cn = np.asarray(codes)
+    fn_, bn, an = np.asarray(feat), np.asarray(bins_), np.asarray(base)
+    n, (Tn, depth, _) = cn.shape[0], fn_.shape
+    slots = np.zeros((n, Tn), np.int64)
+    for lv in range(depth):
+        for t in range(Tn):
+            s = slots[:, t]
+            go = cn[np.arange(n), fn_[t, lv, s]] > bn[t, lv, s]
+            slots[:, t] = an[t, lv, s] + go
+    return slots
+
+
+# rows on both sides of the kernels' block boundaries: under one 64-row
+# block (the predict's below 128 rows, the leaf sums' always), and the
+# predict's 128-row block with a ragged last one after 3, 8 and 17 steps
+@pytest.mark.parametrize("W", [32, 256])
+@pytest.mark.parametrize("n", [63, 257, 1000, 2049])
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_chain_kernels_match_xla(use_pallas, n, W, monkeypatch):
+    """Pallas chain descent (interpret mode on CPU) == the XLA fallback, for
+    predict and leaf sums."""
+    monkeypatch.setenv("TG_TREE_PALLAS", "1" if use_pallas else "0")
+    jax.clear_caches()
+    rng = np.random.RandomState(7)
+    n_bins, d, Tn, depth = 16, 5, 3, 10
+    codes = jnp.asarray(rng.randint(0, n_bins, size=(n, d), dtype=np.int32))
+    feat, bins_, base = _random_chain(rng, n_bins, d, Tn, depth, W)
     W_out = min(2 ** depth, W)
     leaf = jnp.asarray(rng.randn(Tn, W_out, 3).astype(np.float32))
-    aug = jnp.asarray(rng.randn(257, 3).astype(np.float32))
+    aug = jnp.asarray(rng.randn(n, 3).astype(np.float32))
     pred = np.asarray(forest_predict_chain(codes, feat, bins_, base, leaf,
                                            n_bins=n_bins))
     sums = np.asarray(forest_leaf_sums_chain(codes, feat, bins_, base, aug,
                                              n_bins=n_bins))
-    # ground truth by per-row python descent
-    cn = np.asarray(codes)
-    fn_, bn, an = np.asarray(feat), np.asarray(bins_), np.asarray(base)
-    slots = np.zeros((257, Tn), np.int64)
-    for lv in range(depth):
-        for t in range(Tn):
-            s = slots[:, t]
-            go = cn[np.arange(257), fn_[t, lv, s]] > bn[t, lv, s]
-            slots[:, t] = an[t, lv, s] + go
+    slots = _python_slots(codes, feat, bins_, base)
     expect_pred = np.asarray(leaf)[np.arange(Tn)[None, :], slots].sum(1)
     np.testing.assert_allclose(pred, expect_pred, rtol=1e-5, atol=1e-5)
     expect_sums = np.zeros((Tn, W_out, 3), np.float32)
     for t in range(Tn):
         np.add.at(expect_sums[t], slots[:, t], np.asarray(aug))
     np.testing.assert_allclose(sums, expect_sums, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("n,W,Tn,depth,d", [
+    (63, 32, 35, 12, 7), (300, 256, 3, 9, 7),
+    (1100, 32, 35, 12, 7), (1100, 32, 3, 12, 126), (200, 64, 3, 8, 130)])
+def test_chain_kernel_slots_are_the_xla_routes(n, W, Tn, depth, d,
+                                               monkeypatch):
+    """The kernel's slots are integers, and `route_codes_chain_xla`'s
+    exactly, whatever the block (64 rows or the wide one), the lane chunk
+    (a 256-slot level is four), the tree chunk (35 trees: two calls) and
+    the width of the select columns a block builds (126 codes and their two
+    ones fill one 128-row tile, 130 need a second). They are read through a
+    leaf table that holds each slot's own number in its tree's column."""
+    monkeypatch.setenv("TG_TREE_PALLAS", "1")
+    jax.clear_caches()
+    rng = np.random.RandomState(11)
+    n_bins = 32
+    codes = jnp.asarray(rng.randint(0, n_bins, size=(n, d), dtype=np.int32))
+    feat, bins_, base = _random_chain(rng, n_bins, d, Tn, depth, W)
+    W_out = min(2 ** depth, W)
+    reads_slot = (jnp.arange(W_out, dtype=jnp.float32)[None, :, None]
+                  * jnp.eye(Tn, dtype=jnp.float32)[:, None, :])
+    got = np.asarray(forest_predict_chain(codes, feat, bins_, base,
+                                          reads_slot, n_bins=n_bins))
+    want = np.asarray(route_codes_chain_xla(codes, feat, bins_, base, n_bins))
+    np.testing.assert_array_equal(got, want.astype(np.float32))
+    np.testing.assert_array_equal(want,
+                                  _python_slots(codes, feat, bins_, base))
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("W", [48, 100, 512])
+def test_chain_kernels_refuse_a_slot_count_they_cannot_walk(W):
+    """A level's lanes are walked in whole chunks and folded by halves:
+    the slot count is a power of two, at most 256."""
+    z = jnp.zeros((2, 4, W), jnp.int32)
+    codes = jnp.zeros((8, 3), jnp.int32)
+    with pytest.raises(ValueError, match="power of two"):
+        forest_predict_chain(codes, z, z, z, jnp.zeros((2, 16, 1)), n_bins=8)
+    with pytest.raises(ValueError, match="power of two"):
+        forest_leaf_sums_chain(codes, z, z, z, jnp.zeros((8, 1)), n_bins=8)
+
+
+def test_chain_leaf_sums_keep_their_digits(monkeypatch):
+    """`forest_leaf_sums_chain` accumulates a 64-row block at a time in the
+    order it always has: its float32 sums on a fixed seed are, bit for bit,
+    the ones the kernel gave before its descent was rewritten (PR 42; the
+    digest is of the parent commit's output, interpret mode on the CPU)."""
+    import hashlib
+    monkeypatch.setenv("TG_TREE_PALLAS", "1")
+    jax.clear_caches()
+    rng = np.random.RandomState(42)
+    n_bins, d, Tn, depth, W, n = 16, 5, 35, 10, 32, 300
+    feat, bins_, base = _random_chain(rng, n_bins, d, Tn, depth, W)
+    codes = jnp.asarray(rng.randint(0, n_bins, size=(n, d), dtype=np.int32))
+    aug = jnp.asarray(rng.randn(n, 3).astype(np.float32))
+    sums = np.asarray(forest_leaf_sums_chain(codes, feat, bins_, base, aug,
+                                             n_bins=n_bins))
+    assert sums.shape == (35, 32, 3) and sums.dtype == np.float32
+    assert [float(x).hex() for x in sums[0, 0]] == [
+        "-0x1.bd42560000000p+2", "0x1.876d780000000p+1",
+        "-0x1.8ddabe0000000p+2"]
+    assert hashlib.sha256(np.ascontiguousarray(sums).tobytes()).hexdigest() \
+        == "89808688b3ce75d74297721500bb1c8319cad8113be5f88fa73f721ed84d206e"
+    jax.clear_caches()
+
+
+def _kernel_blocks(fn, *args):
+    """(rows a block, widest select product's lanes) of every chain kernel
+    in the traced program ``fn(*args)``: the code block's shape, and the
+    widest product whose left operand is that block."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                block = tuple(
+                    int(getattr(b, "block_size", b)) for b in
+                    eqn.params["grid_mapping"].block_mappings[0].block_shape)
+                chunk = max(e.outvars[0].aval.shape[1]
+                            for e in eqn.params["jaxpr"].eqns
+                            if e.primitive.name == "dot_general"
+                            and e.invars[0].aval.shape == block)
+                found.append((block[0], int(chunk)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("rows,block", [(100, 64), (127, 64), (128, 128),
+                                        (5000, 128)])
+def test_predict_span_attrs_say_the_block_the_program_runs(rows, block,
+                                                           monkeypatch):
+    """``blockRows`` / ``laneChunk`` on the predict spans are what the
+    traced predict program's kernels take a grid step: 64 rows under one
+    wide block (the mechanism did not engage), the wide block from it on."""
+    from transmogrifai_tpu.models.api import FittedParams
+    from transmogrifai_tpu.ops import forest as F
+    monkeypatch.setenv("TG_TREE_PALLAS", "1")
+    jax.clear_caches()
+    X, y = _binary_data(n=200)
+    grid = [{"maxDepth": 12, "minInstancesPerNode": 5, "minInfoGain": 0.001,
+             "maxIter": 3, "stepSize": 0.3}]
+    fam, params = _fit("OpGBTClassifier", grid, X, y)
+    fitted = FittedParams(family=fam.name, params=fam.select_params(params, 0),
+                          hyper=grid[0], num_classes=2)
+    said = fam.predict_span_attrs(fitted, rows=rows)
+    assert said["blockRows"] == block
+    assert said["laneChunk"] == min(F._LANE_CHUNK, 32 * T._REFIT_SLOTS)
+    assert said["treeChunks"] == 1 and said["depth"] == 12
+    Xr = jnp.zeros((rows, X.shape[1]), jnp.float32)
+    kernels = _kernel_blocks(lambda x: fam.predict_batch(params, x, 2), Xr)
+    assert kernels == [(said["blockRows"], said["laneChunk"])]
+    jax.clear_caches()
 
 
 # ---------------------------------------------------------------------------

@@ -68,6 +68,52 @@ def test_the_descent_kernel_keeps_the_name_score_descent_s_reads(
         kernels_named
 
 
+def test_the_boosted_chain_predict_compiles_at_the_cells_size(
+        one_chip, chip_branches):
+    """``train-higgs``'s winner over the whole table: 20 chain trees of
+    depth 12 with 256 slots over 8 388 608 rows x 28 reals in ONE kernel
+    call, named after ``_predict_gbt_chain_batch`` (``hg_eval_descent_s``
+    and ``hg_closing_descent_s`` find it so). The chip's compiler takes the
+    block body, which builds its select columns a chunk, and the program's
+    temporaries are the kernel's bfloat16 code block (n x 128 x 2) and its
+    float32 output (n x 128 x 4): 6.4 GB where int32 codes made 8.6."""
+    from transmogrifai_tpu.models import trees
+    B, T, C, depth, W, nb, n, d = 1, 20, 1, 12, 256, 32, 8388608, 28
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    tables = [shape((B, T, C, depth, W), jnp.int32)] * 3
+    args = (*tables, shape((B, T, C, W), jnp.float32),
+            shape((B, C), jnp.float32), shape((B,), jnp.float32),
+            shape((B, T), jnp.float32), shape((d, nb - 1), jnp.float32),
+            shape((n, d), jnp.float32))
+    compiled = trees._predict_gbt_chain_batch.lower(
+        *args, n_bins=nb).compile()
+    kernels_named = re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text())
+    assert len(kernels_named) == 1, kernels_named
+    assert "_predict_gbt_chain_batch" in kernels_named[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < 6.6e9
+
+
+def test_the_chain_predict_compiles_for_a_wide_table(one_chip, chip_branches):
+    """A tree winner on ``train-tweets``'s 546 derived columns: the block
+    builds select columns of five 128-row tiles, 548 of their rows compared
+    and the rest zeros, and the chip's compiler takes that too."""
+    from transmogrifai_tpu.ops import forest
+    T, depth, W, k, nb, n, d = 20, 12, 256, 1, 32, 8192, 546
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    tables = [shape((T, depth, W), jnp.int32)] * 3
+    compiled = jax.jit(forest.forest_predict_chain,
+                       static_argnames="n_bins").lower(
+        shape((n, d), jnp.int32), *tables, shape((T, W, k), jnp.float32),
+        n_bins=nb).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_the_softmax_refit_compiles_for_the_chip_at_the_cells_size(one_chip):
     """``train-kddcup99``'s refit: one lane of 23 classes over the selector's
     900 000 rows (bucket 1 048 576) x 76 columns, float32 temporaries and

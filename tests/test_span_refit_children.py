@@ -26,7 +26,8 @@ MODELS = {
     "linear": [("OpLogisticRegression", [
         {"regParam": 0.0139, "elasticNetParam": 0.0}])],
 }
-DESCENT = {"trees", "depth", "features", "treeChunks"}
+DESCENT = {"trees", "depth", "features", "treeChunks", "blockRows",
+           "laneChunk"}
 
 
 def _df(seed=13):
@@ -108,6 +109,9 @@ def test_the_predicts_say_what_they_descend(train):
         assert s.attrs["trees"] == hyper.get("numTrees",
                                              hyper.get("maxIter"))
         assert s.attrs["treeChunks"] == 1 and s.attrs["features"] == 3
+        # a complete heap's kernel: 128 rows a block, a level's lanes whole
+        # (the chain kernels' blocks: tests/test_deep_trees.py)
+        assert (s.attrs["blockRows"], s.attrs["laneChunk"]) == (128, 0)
     # the rows a predict is asked for: a split's, and the whole table's
     assert sorted(s.attrs["rows"] for s in launches) == [
         ROWS // 10, ROWS - ROWS // 10, ROWS]

@@ -541,7 +541,8 @@ class ModelSelector(AllowLabelAsInput, Estimator):
                     with _obs_span("evaluate.predict", **said) as step, \
                             engine_mesh(self.mesh):
                         parts = family.predict_parts(fitted, X)
-                        step.set_attr(**family.predict_span_attrs(fitted))
+                        step.set_attr(**family.predict_span_attrs(
+                            fitted, rows=X.shape[0]))
                     with _obs_span("evaluate.metrics", **said):
                         results.append(ev.evaluate_parts(lab, parts, mask))
                     host_bytes += 4 * _count_numbers(results[-1])
@@ -733,7 +734,8 @@ class SelectedModel(AllowLabelAsInput, Transformer):
         with _obs_span("predict.parts", family=self.fitted.family) as step, \
                 engine_mesh(mesh):
             parts = family.predict_one(self.fitted, X)
-            step.set_attr(rows=n, **family.predict_span_attrs(self.fitted))
+            step.set_attr(rows=n, **family.predict_span_attrs(
+                self.fitted, rows=X.shape[0]))
         with _obs_span("predict.unmap"):
             if n_pad != n:
                 parts = {k: v[:n] for k, v in parts.items()}

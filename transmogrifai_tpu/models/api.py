@@ -102,10 +102,12 @@ class ModelFamily(abc.ABC):
         uses; nothing is fetched. Default: none."""
         return {}
 
-    def predict_span_attrs(self, fitted: "FittedParams") -> Dict[str, Any]:
-        """What one predict of ``fitted`` is made of, as attributes for the
-        spans that launch it (``evaluate.predict``, ``predict.parts``): from
-        shapes, nothing is fetched. Default: none."""
+    def predict_span_attrs(self, fitted: "FittedParams",
+                           rows: int) -> Dict[str, Any]:
+        """What one predict of ``fitted`` over a matrix of ``rows`` rows is
+        made of, as attributes for the spans that launch it
+        (``evaluate.predict``, ``predict.parts``): from shapes, nothing is
+        fetched. Default: none."""
         return {}
 
     @abc.abstractmethod

@@ -57,8 +57,8 @@ import numpy as np
 logger = logging.getLogger(__name__)
 
 from ..ops.forest import (
-    _T_CHAIN, forest_leaf_sums, forest_leaf_sums_chain, forest_predict,
-    forest_predict_chain,
+    _BLK_R, _T_CHAIN, chain_block_shape, forest_leaf_sums,
+    forest_leaf_sums_chain, forest_predict, forest_predict_chain,
 )
 from ..histeng import build_hist, build_node_hist, pinned_row_sum
 from ..histeng.kernels import _combine_form, _hist_shards
@@ -1417,20 +1417,29 @@ class _TreeFamilyBase(ModelFamily):
                 "sampleRows": min(rows, _SWEEP_HIST_SAMPLE if sweep
                                   else _HIST_SAMPLE)}
 
-    def predict_span_attrs(self, fitted):
+    def predict_span_attrs(self, fitted, rows):
         """``trees`` descended a row (a boosted fit's rounds x class
         planes), their ``depth``, the ``features`` a row has, and
         ``treeChunks``: the descent kernel's calls a predict
         (`forest_predict_chain` takes `_T_CHAIN` trees a call,
-        `_forest_values` `_PREDICT_TREE_CHUNK`)."""
+        `_forest_values` `_PREDICT_TREE_CHUNK`); and the block the kernel walks
+        the ``rows`` of the matrix handed to the predict in:
+        ``blockRows`` a grid step and ``laneChunk``, the lanes of its widest
+        select product (`ops.forest.chain_block_shape`: 64 rows where the
+        call is under one wide block; a heap's kernel takes `_BLK_R` rows
+        and a level's lanes whole, 0)."""
         p = fitted.params
         chain = "base_lv" in p
         shape = np.shape(p["feat_lv"] if chain else p["feat"])
         trees = int(np.prod(shape[:-2] if chain else shape[:-1]))
+        depth = int(shape[-2]) if chain else _depth_of(shape[-1] + 1)
+        block, chunk = (chain_block_shape(int(rows), depth, int(shape[-1]))
+                        if chain else (_BLK_R, 0))
         return {"trees": trees, "features": int(np.shape(p["edges"])[-2]),
-                "depth": int(shape[-2]) if chain else _depth_of(shape[-1] + 1),
+                "depth": depth,
                 "treeChunks": -(-trees // (_T_CHAIN if chain
-                                           else _PREDICT_TREE_CHUNK))}
+                                           else _PREDICT_TREE_CHUNK)),
+                "blockRows": block, "laneChunk": chunk}
 
     def select_params(self, batched, idx):
         """Per-config slice, except the bin-edge table, which is shared by

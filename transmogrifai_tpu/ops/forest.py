@@ -358,9 +358,47 @@ def forest_leaf_sums(codes: jnp.ndarray, feat_heap: jnp.ndarray,
 # mixed-depth grids share one predict program.
 # ---------------------------------------------------------------------------
 
-_BLK_R_CHAIN = 64     # rows per VMEM block (deep levels are lane-wide)
+# The chain kernels' block body keeps only the work that selects a row's
+# slot (PR 42). Per level there is ONE matmul a chunk of its lanes and, for
+# the product, a compare and a select:
+# - a lane's column of the select table holds a one at its split feature and,
+#   at the two rows that meet the code block's two columns of ones, the
+#   lane's threshold and base (`_chain_tables`), so the product reads
+#   ``code + 256 - bin + 512·base``: the go bit and the next slot's base in
+#   one exact integer. A block builds each chunk's columns from the lanes'
+#   three numbers (`_select_columns`: only the rows up to the row's last
+#   code can hold anything), whatever the table's width;
+# - a lane keeps its number where the row's slot is the lane's. One lane a
+#   tree matches, so the selects and the sum of the 128-lane slabs after them
+#   are the sum over a tree's slots: no group-sum matmul; two lane rolls add
+#   a slab's four tree groups and leave the slot tiled four times along the
+#   lanes, as the next level's compare wants it;
+# - the lanes of a level are walked in chunks of `_LANE_CHUNK`, so the
+#   (rows, chunk) product and its select are the only wide temporaries, and a
+#   grid step takes `_chain_block_rows(n)` rows: 128 (on the chip 64, 128, 256
+#   and 512 rows read 0.328, 0.306, 0.306 and 0.302 s for 4 M rows x 32
+#   trees, PERF.md section 5; a kernel of 512 compiles for three times as
+#   long, and a sweep program holds dozens);
+# - the predict's leaf product is ONE bfloat16 pass: the one-hot against the
+#   leaf table split into its three bfloat16 terms (hi + mid + lo is the
+#   float32 value exactly), summed after, in place of six passes of
+#   `Precision.HIGHEST` over a one-hot whose lower terms are zeros;
+# - the body is written in chunk-wide operations, some 400 equations: every
+#   `pallas_call` of every program traces and lowers its body again in every
+#   process, compile cache or not, and a body unrolled slab by slab (7 000)
+#   added 10 s to a warm `setup_s` of 43.
+
+_BLK_R_CHAIN = 64     # rows a block where a call has fewer than a wide block
+_BLK_R_CHAIN_WIDE = 128   # rows a block of the predict from 128 rows on
+_LANE_CHUNK = 2048    # lanes a select product: (128, 2048) f32 is 1 MiB
 _T_CHAIN = 32         # trees per chain kernel call (lane budget)
-_MAX_SLOTS = 256      # bin codes AND slot ids ride bf16 lanes: exact ≤ 256
+_MAX_SLOTS = 256      # slots a level: T_pad x 256 lanes, four chunks
+
+
+def _chain_block_rows(n: int) -> int:
+    """Rows a grid step of the chain predict takes for a call of ``n``
+    rows: the wide block once the call fills one, else 64."""
+    return _BLK_R_CHAIN_WIDE if n >= _BLK_R_CHAIN_WIDE else _BLK_R_CHAIN
 
 
 def _chain_widths(depth: int, W: int):
@@ -375,100 +413,177 @@ def _chain_w_eff(Wl: int) -> int:
     return max(4, Wl)
 
 
+def _chain_lane_chunk(depth: int, W: int, T_pad: int = _T_CHAIN) -> int:
+    """Lanes of the widest select product a block makes."""
+    return min(_LANE_CHUNK,
+               T_pad * _chain_w_eff(max(_chain_widths(depth, W))))
+
+
+def chain_block_shape(n: int, depth: int, W: int):
+    """(rows a block, lanes a chunk) the chain predict of ``n`` rows runs
+    with: what `predict_span_attrs` reports."""
+    return _chain_block_rows(n), _chain_lane_chunk(depth, W)
+
+
 def _check_slots(W: int) -> None:
-    if W > _MAX_SLOTS:
+    if W > _MAX_SLOTS or W & (W - 1):
         raise ValueError(
-            f"n_slots={W} > {_MAX_SLOTS}: slot ids are accumulated in "
-            f"bfloat16 lanes, exact only up to 256")
+            f"n_slots={W}: the chain kernels take a power of two up to "
+            f"{_MAX_SLOTS} (T_pad x {_MAX_SLOTS} lanes a level, walked in "
+            f"whole chunks and folded by halves)")
+
+
+def _chain_d_pad(d: int) -> int:
+    """Columns of the kernels' code block: the row's ``d`` codes and the two
+    columns of ones that carry a lane's threshold and base into the select
+    product."""
+    return _pad_to(d + 2, 128)
 
 
 def _chain_tables(feat_lv, bin_lv, base_lv, depth, W, n_bins, T_pad):
     """j-major ragged per-level tables, concatenated flat: level l occupies
     T_pad·_chain_w_eff(W_l) lanes (lane = slot·T_pad + t). Sentinel bins fill
-    padded slots/trees; padded bases are 0 (no rows ever sit there)."""
+    padded slots/trees; padded bases are 0 (no rows ever sit there).
+
+    A lane's three numbers ride ONE select product: against a row's
+    ``[codes, 1, 1, 0...]`` the lane's column holds a one at its split
+    feature, ``256 - bin`` at row ``d`` and ``512·base`` at row ``d + 1``
+    (each exact in bfloat16: a bin is at most 256, a base under 256), so the
+    product reads ``code + 256 - bin + 512·base``. Returns the (3, Σw)
+    int32 rows (feature, 256 - bin, 512·base) a block builds each chunk's
+    columns from (`_select_columns`)."""
     T = feat_lv.shape[0]
-    f_rows, b_rows, a_rows = [], [], []
+    rows = [[], [], []]
     for level, Wl in enumerate(_chain_widths(depth, W)):
         We = _chain_w_eff(Wl)
-        f = jnp.pad(feat_lv[:, level, :Wl],
-                    ((0, T_pad - T), (0, We - Wl)))
-        b = jnp.pad(bin_lv[:, level, :Wl],
-                    ((0, T_pad - T), (0, We - Wl)), constant_values=n_bins)
-        a = jnp.pad(base_lv[:, level, :Wl],
-                    ((0, T_pad - T), (0, We - Wl)))
-        f_rows.append(f.T.reshape(-1))
-        b_rows.append(b.T.reshape(-1))
-        a_rows.append(a.T.reshape(-1))
-    return (jnp.concatenate(f_rows)[None, :].astype(jnp.int32),
-            jnp.concatenate(b_rows)[None, :].astype(jnp.int32),
-            jnp.concatenate(a_rows)[None, :].astype(jnp.int32))
+        pad = ((0, T_pad - T), (0, We - Wl))
+        f = jnp.pad(feat_lv[:, level, :Wl], pad)
+        b = jnp.pad(bin_lv[:, level, :Wl], pad, constant_values=n_bins)
+        a = jnp.pad(base_lv[:, level, :Wl], pad)
+        for out, x in zip(rows, (f, 256 - b, 512 * a)):
+            out.append(x.T.reshape(-1).astype(jnp.int32))
+    return jnp.stack([jnp.concatenate(r) for r in rows])      # (3, Σw)
 
 
-def _descend_chain(codes_f, f_ref, b_ref, a_ref, *, depth, W, T_pad, d_pad):
-    """In-kernel: (R, d_pad) f32 codes → (R, T_pad) int32 leaf slots.
+def _select_columns(t_ref, at, d: int, d_pad: int):
+    """In-kernel: lanes ``at`` of the (3, Σw) table → their (d_pad, c)
+    bfloat16 select columns. Only the first ``d + 2`` rows can hold
+    anything, so only they (to a whole bfloat16 tile) are compared."""
+    c = at.stop - at.start
+    d_up = _pad_to(d + 2, 16)
+    row = jax.lax.broadcasted_iota(jnp.int32, (d_up, c), 0)
+    cols = jnp.where(row == d, t_ref[1:2, at],
+                     jnp.where(row == d + 1, t_ref[2:3, at],
+                               (row == t_ref[0:1, at]).astype(jnp.int32)))
+    cols = cols.astype(jnp.float32).astype(jnp.bfloat16)
+    if d_up == d_pad:
+        return cols
+    return jnp.concatenate(
+        [cols, jnp.zeros((d_pad - d_up, c), jnp.bfloat16)], axis=0)
 
-    Same matmul skeleton as `_descend`, plus the base-pointer gather: the
-    next slot is Σ_j oh[j]·(base[j] + go[j]) — one fused group-sum matmul
-    (base values < 256 are exact in the bf16 operand, accumulated f32)."""
-    R = codes_f.shape[0]
-    codes_bf = codes_f.astype(jnp.bfloat16)
-    slot = jnp.zeros((R, T_pad), jnp.int32)
+
+def _chain_codes(codes, n_pad: int):
+    """(n, d) int32 bin codes → the kernels' (n_pad, d_pad) bfloat16 block
+    rows ``[codes, 1, 1, 0...]`` (codes up to 256 are exact)."""
+    n, d = codes.shape
+    ones = jnp.ones((n, 2), jnp.bfloat16)
+    return jnp.pad(
+        jnp.concatenate([codes.astype(jnp.bfloat16), ones], axis=1),
+        ((0, n_pad - n), (0, _chain_d_pad(d) - d - 2)))
+
+
+def _lane_slots(c: int, T_pad: int):
+    """(1, c) float32: the slot each lane of a level's first ``c`` lanes
+    stands for (lane = slot·T_pad + t)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
+    return (lane // T_pad).astype(jnp.float32)
+
+
+def _fold_lanes(x):
+    """(R, c) → (R, 128): the sum of ``x``'s 128-lane slabs, by halves."""
+    while x.shape[1] > 128:
+        half = x.shape[1] // 2
+        x = x[:, :half] + x[:, half:]
+    return x
+
+
+def _descend_chain(codes_bf, t_ref, *, d, depth, W, T_pad, chunk):
+    """In-kernel: (R, d_pad) bf16 code rows → (R, 128) float32 leaf slots,
+    the slot of tree t on every lane ≡ t mod T_pad.
+
+    Per level, chunk by chunk of its lanes: ONE matmul
+    (R, d_pad) x (d_pad, chunk) reads ``code + 256 - bin + 512·base`` on
+    every lane (`_chain_tables`), and a compare and a select keep a lane's
+    number where the row's slot is the lane's. One lane a tree matches, so
+    the chain of selects over the chunks and the sum of the 128-lane slabs
+    after it ARE the group-sum over a tree's slots; rolls by 64 and 32
+    lanes then add the slab's four tree groups, which leaves the result
+    tiled along the lanes as the next level's compare wants it.
+    ``floor(q / 512)`` is the base, the rest is over 256 where the code is
+    over the bin. Every value is an integer under 2^18, exact in float32, so
+    the slots are `route_codes_chain_xla`'s whatever the chunk or the
+    block. ``W`` is a power of two (`_check_slots`): a level's lanes are
+    walked in whole chunks and folded by halves. The body is a few hundred
+    equations: every `pallas_call` of every program traces and lowers it
+    again in every process."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    R, d_pad = codes_bf.shape
+    slot = jnp.zeros((R, 128), jnp.float32)
     off = 0
-    for level, Wl in enumerate(_chain_widths(depth, W)):
-        We = _chain_w_eff(Wl)
-        w = T_pad * We
-        f_row = f_ref[0:1, off:off + w]                       # (1, w)
-        b_row = b_ref[0:1, off:off + w]
-        a_row = a_ref[0:1, off:off + w]
+    for Wl in _chain_widths(depth, W):
+        w = T_pad * _chain_w_eff(Wl)
+        c = min(chunk, w)
+        mine = _tile_lanes(slot, c // 128)                    # (R, c)
+        lanes = _lane_slots(c, T_pad)
+        q = jnp.zeros((R, c), jnp.float32)
+        for lo in range(0, w, c):
+            at = slice(off + lo, off + lo + c)
+            q_all = jnp.dot(codes_bf, _select_columns(t_ref, at, d, d_pad),
+                            preferred_element_type=jnp.float32)  # (R, c)
+            q = jnp.where(mine == lanes + float(lo // T_pad), q_all, q)
         off += w
-        d_iota = jax.lax.broadcasted_iota(jnp.int32, (d_pad, w), 0)
-        sel = (d_iota == f_row).astype(jnp.bfloat16)          # (d_pad, w)
-        code_sel = jnp.dot(codes_bf, sel,
-                           preferred_element_type=jnp.float32)  # (R, w)
-        go_lane = (code_sel > b_row.astype(jnp.float32)
-                   ).astype(jnp.bfloat16)
-        slot_rep = _tile_lanes(slot, We)                      # (R, w)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (R, w), 1)
-        oh = (slot_rep == lane // T_pad).astype(jnp.bfloat16)
-        val = (go_lane + a_row.astype(jnp.bfloat16)) * oh     # (R, w)
-        gl = jax.lax.broadcasted_iota(jnp.int32, (w, T_pad), 0) % T_pad
-        gt = jax.lax.broadcasted_iota(jnp.int32, (w, T_pad), 1)
-        G = (gl == gt).astype(jnp.bfloat16)                   # (w, T_pad)
-        nxt = jnp.dot(val, G, preferred_element_type=jnp.float32)
-        slot = nxt.astype(jnp.int32)
+        q = _fold_lanes(q)
+        shift = 64
+        while shift >= T_pad:
+            q = q + pltpu.roll(q, shift, axis=1)
+            shift //= 2
+        base = jnp.floor(q * (1.0 / 512.0))
+        slot = base + jnp.where(q - 512.0 * base > 256.0, 1.0, 0.0)
     return slot
 
 
-def _leaf_onehot_chain(slot, *, W_out, T_pad):
-    R = slot.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (R, T_pad * W_out), 1)
-    slot_rep = _tile_lanes(slot, W_out)
-    return (slot_rep == lane // T_pad).astype(jnp.bfloat16)
+def _leaf_onehot_chain(slot, lo, c, *, T_pad, dtype):
+    """Lanes lo..lo+c of the (R, T_pad·W_out) leaf one-hot, lane =
+    slot·T_pad + t, from the (R, 128) tiled slots."""
+    return (_tile_lanes(slot, c // 128)
+            == _lane_slots(c, T_pad) + float(lo // T_pad)).astype(dtype)
 
 
-def _leaf_sums_chain_pallas(codes, f_lvls, b_lvls, a_lvls, aug, *, depth, W,
-                            W_out, n_bins, T_pad):
+def _leaf_sums_chain_pallas(codes_p, tables, aug, *, d, depth, W, W_out,
+                            T_pad):
+    """The exact leaf statistics keep their 64-row accumulation order
+    (`out_ref += part` a 64-row block): the float32 sums are the ones the
+    kernel has always given, bit for bit. Only the descent is the new one."""
     from jax.experimental import pallas as pl
 
-    n, d = codes.shape
-    k = aug.shape[1]
-    d_pad = _pad_to(d, 128)
+    n, k = aug.shape
     k_pad = _pad_to(k, 8)
+    lanes_out = T_pad * _chain_w_eff(W_out)
     blk_r = _BLK_R_CHAIN
-    n_pad = _pad_to(n, blk_r)
-    codes_p = jnp.pad(codes.astype(jnp.int32),
-                      ((0, n_pad - n), (0, d_pad - d)))
+    chunk = _chain_lane_chunk(depth, W, T_pad)
+    n_pad = codes_p.shape[0]
     aug_p = jnp.pad(aug.astype(jnp.float32),
                     ((0, n_pad - n), (0, k_pad - k)))
 
-    def kernel(codes_ref, f_ref, b_ref, a_ref, aug_ref, out_ref):
+    def kernel(codes_ref, t_ref, aug_ref, out_ref):
         r = pl.program_id(0)
-        slot = _descend_chain(codes_ref[:].astype(jnp.float32), f_ref, b_ref,
-                              a_ref, depth=depth, W=W, T_pad=T_pad,
-                              d_pad=d_pad)
-        l_oh = _leaf_onehot_chain(slot, W_out=W_out, T_pad=T_pad)
+        slot = _descend_chain(codes_ref[:], t_ref, d=d, depth=depth, W=W,
+                              T_pad=T_pad, chunk=chunk)
+        l_oh = _leaf_onehot_chain(slot, 0, lanes_out, T_pad=T_pad,
+                                  dtype=jnp.float32)
         part = jax.lax.dot_general(
-            aug_ref[:], l_oh.astype(jnp.float32),
+            aug_ref[:], l_oh,
             (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST)
@@ -483,61 +598,76 @@ def _leaf_sums_chain_pallas(codes, f_lvls, b_lvls, a_lvls, aug, *, depth, W,
 
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((k_pad, T_pad * W_out), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((k_pad, lanes_out), jnp.float32),
         grid=(n_pad // blk_r,),
         in_specs=[
-            pl.BlockSpec((blk_r, d_pad), lambda r: (r, 0)),
-            pl.BlockSpec(f_lvls.shape, lambda r: (0, 0)),
-            pl.BlockSpec(b_lvls.shape, lambda r: (0, 0)),
-            pl.BlockSpec(a_lvls.shape, lambda r: (0, 0)),
+            pl.BlockSpec((blk_r, codes_p.shape[1]), lambda r: (r, 0)),
+            pl.BlockSpec(tables.shape, lambda r: (0, 0)),
             pl.BlockSpec((blk_r, k_pad), lambda r: (r, 0)),
         ],
-        out_specs=pl.BlockSpec((k_pad, T_pad * W_out), lambda r: (0, 0)),
+        out_specs=pl.BlockSpec((k_pad, lanes_out), lambda r: (0, 0)),
         interpret=_interpret(),
-    )(codes_p, f_lvls, b_lvls, a_lvls, aug_p)
+    )(codes_p, tables, aug_p)
     # (k, slot·T_pad + t) -> (T_pad, W_out, k)
-    return out.reshape(k_pad, W_out, T_pad).transpose(2, 1, 0)[:, :, :k]
+    return out.reshape(k_pad, -1, T_pad).transpose(2, 1, 0)[:, :W_out, :k]
 
 
-def _predict_chain_pallas(codes, f_lvls, b_lvls, a_lvls, leaf_flat, *,
-                          depth, W, W_out, n_bins, T_pad):
+def _bf16_terms(x):
+    """float32 ``x`` as three bfloat16 terms hi + mid + lo == x exactly:
+    each keeps the eight leading bits of what the ones before left (by
+    mask, so no rounding that the compiler might drop)."""
+    terms = []
+    for _ in range(3):
+        t = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(x, jnp.uint32)
+            & jnp.uint32(0xFFFF0000), jnp.float32)
+        terms.append(t.astype(jnp.bfloat16))
+        x = x - t
+    return terms
+
+
+def _predict_chain_pallas(codes_p, tables, leaf_flat, *, blk_r, d, depth, W,
+                          W_out, T_pad):
+    """(n_pad, 3k padded) float32: a row's sum over the call's trees of each
+    leaf value's three bfloat16 terms, columns [hi | mid | lo]."""
     from jax.experimental import pallas as pl
 
-    n, d = codes.shape
+    n_pad = codes_p.shape[0]
     k = leaf_flat.shape[1]
-    d_pad = _pad_to(d, 128)
-    k_pad = _pad_to(k, 128)
-    blk_r = _BLK_R_CHAIN
-    n_pad = _pad_to(n, blk_r)
-    codes_p = jnp.pad(codes.astype(jnp.int32),
-                      ((0, n_pad - n), (0, d_pad - d)))
-    leaf_p = jnp.pad(leaf_flat.astype(jnp.float32),
-                     ((0, 0), (0, k_pad - k)))
+    k_pad = _pad_to(3 * k, 128)
+    lanes_out = T_pad * _chain_w_eff(W_out)
+    chunk = _chain_lane_chunk(depth, W, T_pad)
+    # the three bfloat16 terms of every leaf value side by side: one pass
+    # of the array over the one-hot, three columns a value, summed after
+    leaf_p = jnp.pad(
+        jnp.concatenate(_bf16_terms(leaf_flat.astype(jnp.float32)), axis=1),
+        ((0, lanes_out - leaf_flat.shape[0]), (0, k_pad - 3 * k)))
 
-    def kernel(codes_ref, f_ref, b_ref, a_ref, leaf_ref, out_ref):
-        slot = _descend_chain(codes_ref[:].astype(jnp.float32), f_ref, b_ref,
-                              a_ref, depth=depth, W=W, T_pad=T_pad,
-                              d_pad=d_pad)
-        l_oh = _leaf_onehot_chain(slot, W_out=W_out, T_pad=T_pad)
-        out_ref[:] = jnp.dot(l_oh.astype(jnp.float32), leaf_ref[:],
-                             preferred_element_type=jnp.float32,
-                             precision=jax.lax.Precision.HIGHEST)
+    def kernel(codes_ref, t_ref, leaf_ref, out_ref):
+        slot = _descend_chain(codes_ref[:], t_ref, d=d, depth=depth, W=W,
+                              T_pad=T_pad, chunk=chunk)
+        out = None
+        for lo in range(0, lanes_out, chunk):
+            c = min(chunk, lanes_out - lo)
+            l_oh = _leaf_onehot_chain(slot, lo, c, T_pad=T_pad,
+                                      dtype=jnp.bfloat16)
+            part = jnp.dot(l_oh, leaf_ref[lo:lo + c, :],
+                           preferred_element_type=jnp.float32)
+            out = part if out is None else out + part
+        out_ref[:] = out
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((n_pad, k_pad), jnp.float32),
         grid=(n_pad // blk_r,),
         in_specs=[
-            pl.BlockSpec((blk_r, d_pad), lambda r: (r, 0)),
-            pl.BlockSpec(f_lvls.shape, lambda r: (0, 0)),
-            pl.BlockSpec(b_lvls.shape, lambda r: (0, 0)),
-            pl.BlockSpec(a_lvls.shape, lambda r: (0, 0)),
-            pl.BlockSpec(leaf_flat.shape[:1] + (k_pad,), lambda r: (0, 0)),
+            pl.BlockSpec((blk_r, codes_p.shape[1]), lambda r: (r, 0)),
+            pl.BlockSpec(tables.shape, lambda r: (0, 0)),
+            pl.BlockSpec(leaf_p.shape, lambda r: (0, 0)),
         ],
         out_specs=pl.BlockSpec((blk_r, k_pad), lambda r: (r, 0)),
         interpret=_interpret(),
-    )(codes_p, f_lvls, b_lvls, a_lvls, leaf_p)
-    return out[:n, :k]
+    )(codes_p, tables, leaf_p)
 
 
 def route_codes_chain_xla(codes: jnp.ndarray, feat_lv: jnp.ndarray,
@@ -649,15 +779,17 @@ def forest_leaf_sums_chain(codes: jnp.ndarray, feat_lv: jnp.ndarray,
         return _leaf_sums_chain_xla(codes, feat_lv, bin_lv, base_lv, aug,
                                     n_bins=n_bins)
     parts = []
+    n, d = codes.shape
+    codes_p = _chain_codes(codes, _pad_to(n, _BLK_R_CHAIN))
     for lo in range(0, T, _T_CHAIN):
         hi = min(lo + _T_CHAIN, T)
         T_pad = _T_CHAIN
-        f_lvls, b_lvls, a_lvls = _chain_tables(
+        tables = _chain_tables(
             feat_lv[lo:hi], bin_lv[lo:hi], base_lv[lo:hi], depth, W, n_bins,
             T_pad)
         out = _leaf_sums_chain_pallas(
-            codes, f_lvls, b_lvls, a_lvls, aug, depth=depth, W=W,
-            W_out=W_out, n_bins=n_bins, T_pad=T_pad)
+            codes_p, tables, aug, d=d, depth=depth, W=W, W_out=W_out,
+            T_pad=T_pad)
         parts.append(out[:hi - lo])
     return jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
 
@@ -677,18 +809,23 @@ def forest_predict_chain(codes: jnp.ndarray, feat_lv: jnp.ndarray,
         return _predict_chain_xla(codes, feat_lv, bin_lv, base_lv, leaf,
                                   n_bins=n_bins)
     out = None
+    n, d = codes.shape
+    blk_r = _chain_block_rows(n)
+    codes_p = _chain_codes(codes, _pad_to(n, blk_r))
     for lo in range(0, T, _T_CHAIN):
         hi = min(lo + _T_CHAIN, T)
         T_pad = _T_CHAIN
-        f_lvls, b_lvls, a_lvls = _chain_tables(
+        tables = _chain_tables(
             feat_lv[lo:hi], bin_lv[lo:hi], base_lv[lo:hi], depth, W, n_bins,
             T_pad)
         leaf_flat = (jnp.pad(leaf[lo:hi].astype(jnp.float32),
                              ((0, T_pad - (hi - lo)), (0, 0), (0, 0)))
                      .transpose(1, 0, 2).reshape(T_pad * W_out, k))
-        part = _predict_chain_pallas(
-            codes, f_lvls, b_lvls, a_lvls, leaf_flat, depth=depth, W=W,
-            W_out=W_out, n_bins=n_bins, T_pad=T_pad)
+        terms = _predict_chain_pallas(
+            codes_p, tables, leaf_flat, blk_r=blk_r, d=d, depth=depth, W=W,
+            W_out=W_out, T_pad=T_pad)
+        part = (terms[:n, :k] + terms[:n, k:2 * k]
+                + terms[:n, 2 * k:3 * k])                   # hi + mid + lo
         out = part if out is None else out + part
     return out
 
