@@ -114,6 +114,28 @@ def test_the_chain_predict_compiles_for_a_wide_table(one_chip, chip_branches):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("trees", [1, 2, 5])
+def test_a_few_trees_leaf_sums_compile_for_the_chip(one_chip, chip_branches,
+                                                    trees):
+    """A boosted winner's refit sums the leaves of ONE tree a round
+    (`train-higgs`: 65 536 sampled rows, 256 slots, G and H). The block of
+    trees follows the tree count (PR 43) but stops at `_DIAG_MIN_BLOCK`: the
+    chip's compiler takes the histogram kernel with a code block of four
+    columns and refuses one of one or two at 64 leaves and more (the lane
+    repeat asks 29-58 MB of VMEM for 16), which interpret mode does not
+    show."""
+    from transmogrifai_tpu.models import trees as tr
+    S, J, L = 65536, 2, 256
+    node = jax.ShapeDtypeStruct((S, trees), jnp.int32, sharding=one_chip)
+    cols = jax.ShapeDtypeStruct((S, J, trees), jnp.float32,
+                                sharding=one_chip)
+    text = jax.jit(lambda n, a: tr._diag_leaf_hist(n, a, L)).lower(
+        node, cols).compile().as_text()
+    (kernel,) = re.findall(r"= (\S+) custom-call\([^\n]*tpu_custom_call", text)
+    block = max(tr._DIAG_MIN_BLOCK, 1 << (trees - 1).bit_length())
+    assert kernel.startswith(f"f32[{J * block},{block * L}]")
+
+
 def test_the_softmax_refit_compiles_for_the_chip_at_the_cells_size(one_chip):
     """``train-kddcup99``'s refit: one lane of 23 classes over the selector's
     900 000 rows (bucket 1 048 576) x 76 columns, float32 temporaries and

@@ -155,7 +155,9 @@ def test_tree_families_put_the_combine_on_their_spans(family, monkeypatch):
     """``histShards`` (K) and ``combine`` (the spelling `_tree_combine`
     traces, by its own test of the engine mesh) are what every tree family
     adds to ``sweep.family`` / ``selector.refit``; the forest keeps its
-    chunk count and column width on top; other families add neither."""
+    chunk count and column width on top, a boosted family the trees a round
+    grows side by side and the lanes they are laid on (PR 43: a grid of one
+    point is one tree on one lane); other families add neither."""
     fam = MODEL_REGISTRY[family]
     grid = [{"maxDepth": 3}]
     own = fam.fit_span_attrs(4000, 12, grid, 2, True)
@@ -165,7 +167,10 @@ def test_tree_families_put_the_combine_on_their_spans(family, monkeypatch):
     assert fam.fit_span_attrs(10 ** 7, 12, grid, 2, True)["sampleRows"] == 8192
     assert (set(own) - {"histShards", "combine", "sampleRows"}
             == ({"configChunks", "featSubset"} if "Forest" in family
+                else {"treeLanes", "treeLanesPadded"} if "GB" in family
                 else set()))
+    if "treeLanes" in own:
+        assert (own["treeLanes"], own["treeLanesPadded"]) == (1, 1)
     with histeng.engine_mesh(make_mesh(MeshSpec(data=4, model=2))):
         assert fam.fit_span_attrs(4000, 12, grid, 2, True)[
             "combine"] == "halving"

@@ -91,6 +91,26 @@ def test_the_children_say_what_they_worked_on(train):
     assert grow.attrs["slots"] == 0          # a complete heap at this depth
     # the rows kept, padded to the refit's bucket: all of them grown on
     assert refit.attrs["rows"] <= grow.attrs["sampleRows"] <= 65536
+    # one tree a round, laid on one tree lane (PR 43: it was 32), and the
+    # refit's own span says the same of its family's program
+    for s in (grow, refit):
+        assert (s.attrs["treeLanes"], s.attrs["treeLanesPadded"]) == (1, 1)
+
+
+def test_a_boosted_sweep_says_how_full_its_tree_lanes_are(train):
+    """``treeLanes``: the trees a boosting round grows side by side, every
+    configuration of every fold; ``treeLanesPadded``: the lanes the node
+    histogram lays out for them, by the histogram engine's own rule. No
+    other family's sweep carries either."""
+    from transmogrifai_tpu.histeng.kernels import tree_lane_shape
+    winner, spans = train
+    (sweep,) = [s for s in spans if s.name == "sweep.family"]
+    if winner != "boosted":
+        assert not {"treeLanes", "treeLanesPadded"} & set(sweep.attrs)
+        return
+    lanes = sweep.attrs["configs"] * sweep.attrs["folds"]
+    assert sweep.attrs["treeLanes"] == lanes == sweep.attrs["lanes"] > 1
+    assert sweep.attrs["treeLanesPadded"] == tree_lane_shape(lanes)[0]
 
 
 def test_the_predicts_say_what_they_descend(train):

@@ -247,3 +247,86 @@ def test_grow_forest_compact_columns_grow_the_same_trees(mode, k):
     np.testing.assert_allclose(compact[4], full[4], rtol=1e-6, atol=1e-4)
     assert (compact[2] < n_bins).any() and compact[4][3].sum() > 0
     assert not (compact[2][3] < n_bins).any()
+
+
+@pytest.mark.parametrize("Tb", [1, 2, 5, 33, 70])
+def test_diag_leaf_hist_is_the_segment_sum(Tb):
+    """The leaf sums' tree block follows the tree count up to 32 (PR 43: a
+    lone tree is a block of `_DIAG_MIN_BLOCK`, 33 and 70 take blocks of 64):
+    whatever the block, out[j, t, l] is tree t's own sum of stat j over the
+    rows in its leaf l, float32-exact against numpy in float64."""
+    from transmogrifai_tpu.models import trees
+    rng = np.random.RandomState(Tb)
+    S, J, L = 777, 2, 16
+    node = rng.randint(0, L, size=(S, Tb)).astype(np.int32)
+    A = rng.randn(S, J, Tb).astype(np.float32)
+    got = np.asarray(trees._diag_leaf_hist(jnp.asarray(node),
+                                           jnp.asarray(A), L))
+    want = np.zeros((J, Tb, L))
+    for t in range(Tb):
+        for j in range(J):
+            want[j, t] = np.bincount(node[:, t], weights=A[:, j, t].astype(
+                np.float64), minlength=L)
+    assert got.shape == (J, Tb, L)
+    # sums of some 50 numbers of either sign: the float32 rounding of a
+    # sum that cancels is absolute
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-5)
+    one = np.asarray(trees._diag_leaf_hist(
+        jnp.asarray(node), jnp.asarray(A[:, 0]), L))       # (S, Tb) form
+    np.testing.assert_array_equal(one, got[0])
+
+
+def test_a_lone_boosted_tree_grows_as_in_company():
+    """A boosted refit grows ONE tree a round (B = C = 1) on one tree lane
+    and sums its leaves in a block of four; the same configuration fitted as
+    33 copies side by side takes the 64-lane forms of both. The tables of
+    copy 0 are the lone tree's: the masked operand's values are the same
+    bfloat16 numbers and every lane sums the same rows in the same eight
+    blocks."""
+    from transmogrifai_tpu.models import trees
+    X, y = _binary_data(n=1500, d=6, seed=43)
+    n = X.shape[0]
+    w = np.ones((1, n), np.float32)
+    w[0, ::9] = 0.0                                       # a fold held out
+
+    def fit(B):
+        col = lambda v: jnp.full((B,), v, jnp.float32)
+        return trees._fit_gbt_batch(
+            X, y, jnp.asarray(np.repeat(w, B, axis=0)), col(5.0), col(5.0),
+            col(0.0), col(4.0), col(0.3), col(0.0), col(0.0), depth=5,
+            n_bins=32, num_classes=2, task="binary", n_rounds=4,
+            sweep=False, n_slots=16)
+
+    lone, company = fit(1), fit(33)
+    for key in ("feat_lv", "bins_lv", "base_lv", "thresh_lv"):
+        got, want = np.asarray(lone[key]), np.asarray(company[key])
+        assert got.shape == (1,) + want.shape[1:] == (1, 4, 1, 5, 16)
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(want[32], want[0])
+    assert (np.asarray(lone["bins_lv"]) < 32).sum() > 30       # trees grew
+    np.testing.assert_allclose(np.asarray(lone["leaf"])[0],
+                               np.asarray(company["leaf"])[0],
+                               rtol=0, atol=1e-6)
+
+
+def test_a_lone_trees_program_holds_no_contraction_laid_out_for_dozens():
+    """`_fit_gbt_batch` as `train-higgs`'s refit calls it (one binary
+    configuration, depth 12, 256 slots, 20 rounds), lowered at test size: no
+    level contraction is wider than 3 x 256 x 1 stat columns (they were
+    3 x 256 x 32), and the leaf sums are 2 x 4 columns against 4 x 256
+    (they were 2 x 64 against 64 x 256)."""
+    import re
+    from transmogrifai_tpu.models import trees
+    X, y = _binary_data(n=1500, d=6, seed=43)
+    col = lambda v: jnp.full((1,), v, jnp.float32)
+    text = trees._fit_gbt_batch.lower(
+        X, y, jnp.ones((1, 1500), jnp.float32), col(12.0), col(5.0),
+        col(0.0), col(20.0), col(0.1), col(0.0), col(0.0), depth=12,
+        n_bins=32, num_classes=2, task="binary", n_rounds=20, sweep=False,
+        n_slots=256).as_text()
+    blocked = re.findall(r"dot_general[^\n]*: \(tensor<8x\d+x(\d+)x(\w+)>, "
+                         r"tensor<8x\d+x(\d+)x\w+>\)", text)
+    levels = sorted({int(a) for a, dt, _ in blocked if dt == "bf16"})
+    assert levels == [3 * 128, 3 * 256]
+    assert [(int(a), int(b)) for a, dt, b in blocked if dt == "f32"] == [
+        (2 * 4, 4 * 256)]

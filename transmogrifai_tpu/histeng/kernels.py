@@ -387,14 +387,23 @@ def hist_matmul(codes: jnp.ndarray, A: jnp.ndarray,
 
 
 
-def _t_pad128(T: int) -> int:
-    """Tree-lane padding the node-hist kernel accepts: 32, 64, or a multiple
-    of 128 (so a 128-lane output block covers whole trees × whole slots)."""
+def tree_lane_shape(T: int, Wl: int = 1):
+    """``(T_pad, Wl_eff)``: the tree lanes and slots the shared-codes node
+    histogram lays out for ``T`` trees of ``Wl`` slots. Up to 32 trees the
+    tree lanes follow the count (the next power of two: 1, 2, 4 ... 32), then
+    64, then multiples of 128; the slots are rounded up so that
+    ``Wl_eff * T_pad`` is a multiple of 128 and every minor dimension of the
+    masked-stat operand stays 128-aligned. `node_hist_matmul` lays its operand
+    out by this rule, `models/trees.py` takes its leaf-sum block and the
+    spans' ``treeLanesPadded`` from it."""
     if T <= 32:
-        return 32
-    if T <= 64:
-        return 64
-    return _pad_to(T, 128)
+        T_pad = 1 << max(T - 1, 0).bit_length()
+    elif T <= 64:
+        T_pad = 64
+    else:
+        T_pad = _pad_to(T, 128)
+    rep = max(1, 128 // T_pad)
+    return T_pad, _pad_to(max(Wl, rep), rep)
 
 
 def _node_hist_xla(codes, node, sws, Wl_eff, n_bins, stride, k, exact=False):
@@ -402,11 +411,18 @@ def _node_hist_xla(codes, node, sws, Wl_eff, n_bins, stride, k, exact=False):
     blocked hist contraction. node: (S, T_pad) int32 (pad -1); sws:
     (k, S, T_pad) stat-stacked. Returns (k·Wl_eff·T_pad, d·nb)."""
     S, T_pad = node.shape
-    j = stride * jnp.arange(Wl_eff, dtype=jnp.int32)[None, :, None]
-    n_oh = (node[:, None, :] == j).astype(sws.dtype)      # (S, Wl_eff, T_pad)
-    A = jnp.concatenate(
-        [n_oh * sws[ki][:, None, :] for ki in range(k)],
-        axis=1).reshape(S, k * Wl_eff * T_pad)
+    j = stride * jnp.arange(Wl_eff, dtype=jnp.int32)
+    if T_pad == 1:
+        # one tree: the lane is the slot. No (S, Wl_eff, 1) array is made,
+        # whose minor axis of one tree the chip would lay on 128 lanes
+        n_oh = (node == j[None, :]).astype(sws.dtype)         # (S, Wl_eff)
+        A = jnp.concatenate([n_oh * sws[ki] for ki in range(k)], axis=1)
+    else:
+        n_oh = (node[:, None, :] == j[None, :, None]
+                ).astype(sws.dtype)                       # (S, Wl_eff, T_pad)
+        A = jnp.concatenate(
+            [n_oh * sws[ki][:, None, :] for ki in range(k)],
+            axis=1).reshape(S, k * Wl_eff * T_pad)
     return _hist_xla_pinned(codes, A, n_bins, exact)
 
 
@@ -473,17 +489,16 @@ def node_hist_matmul(codes: jnp.ndarray, node: jnp.ndarray,
     S, d = codes.shape
     T = node.shape[1]
     k = len(sw_list)
-    # lane padding to 32/64/128-multiple tree lanes is KEPT on purpose: it
-    # predates the retired pallas kernel's constraints but MEASURES faster
-    # on v5e — removing it dropped the default-grid sweep from ~108 to
-    # ~88 fits/sec (the A_cat expansion + contraction tile better on
-    # 128-aligned minor dims than on T=54-ragged ones, logical-FLOP
-    # savings notwithstanding)
-    T_pad = _t_pad128(T)
-    rep = max(1, 128 // T_pad)
-    Wl_eff = max(Wl, rep)
-    if Wl_eff * T_pad % 128:
-        Wl_eff = -(-Wl_eff // rep) * rep
+    # the tree lanes are padded on purpose, and the slots with them, so that
+    # every minor dimension is 128-aligned. MEASURED on v5e at T = 54 (the
+    # default grid's boosted sweep): laid on 64 lanes ~108 fits/sec, ragged
+    # on 54 ~88 (the A_cat expansion and the contraction tile better,
+    # logical-FLOP savings notwithstanding). At T = 1 (a boosted winner's
+    # refit, one tree a round at 65 536 rows x 28 columns, 256 slots) a
+    # block of 32 lanes was 31 lanes of zeros: 18.8 ms a level, 0.8 ms on
+    # one lane (PERF.md, PR 43). So the padding follows T up to 32 and is
+    # what it was above: `tree_lane_shape`
+    T_pad, Wl_eff = tree_lane_shape(T, Wl)
     node_p = (jnp.pad(node, ((0, 0), (0, T_pad - T)), constant_values=-1)
               if T_pad != T else node)
     sws = jnp.stack(

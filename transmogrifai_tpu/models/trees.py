@@ -61,7 +61,7 @@ from ..ops.forest import (
     forest_leaf_sums_chain, forest_predict, forest_predict_chain,
 )
 from ..histeng import build_hist, build_node_hist, pinned_row_sum
-from ..histeng.kernels import _combine_form, _hist_shards
+from ..histeng.kernels import _combine_form, _hist_shards, tree_lane_shape
 from ..observability.trace import span as _obs_span
 from .api import FittedParams, ModelFamily, register_family
 
@@ -661,6 +661,12 @@ def _grow_forest_capped(codes_s, edges, sw_list, fmasks, cfg, *, depth: int,
 
 
 _DIAG_BLOCK = 64
+#: the narrowest block of trees the leaf sums are cut into: the histogram
+#: kernel's code block is one column a tree, and the chip's compiler takes
+#: none under 4 columns at 64 leaves and more (the lane repeat of a 1- or
+#: 2-column block asks 29-58 MB of VMEM for 16: compiled for a described
+#: v5e, PR 43)
+_DIAG_MIN_BLOCK = 4
 
 
 def _diag_leaf_hist(node_s: jnp.ndarray, A_cols: jnp.ndarray,
@@ -669,14 +675,17 @@ def _diag_leaf_hist(node_s: jnp.ndarray, A_cols: jnp.ndarray,
     segment-sums through the histogram kernel (trees as 'features', leaves
     as 'bins'), diagonal extracted. ``A_cols``: (S, Tb) for one stat — or
     (S, J, Tb) to reduce J stats against the same trees in ONE kernel call
-    (GBT's G and H sums). Blocked in groups of _DIAG_BLOCK trees so the
-    cross-tree waste stays a constant factor (full-width would be quadratic
-    in the tree count)."""
+    (GBT's G and H sums). Blocked in groups of trees so the cross-tree waste
+    stays a constant factor (full-width would be quadratic in the tree
+    count): `_DIAG_BLOCK` trees a group, and where there are 32 or fewer the
+    power of two at or above their count (`tree_lane_shape`'s), no narrower
+    than `_DIAG_MIN_BLOCK`. A boosted refit's ONE tree a round is then a
+    (4 J, S) x (S, 4 L) product, not 64 trees against 64 x L leaves."""
     squeeze = A_cols.ndim == 2
     if squeeze:
         A_cols = A_cols[:, None, :]
     S, J, Tb = A_cols.shape
-    g = _DIAG_BLOCK
+    g = min(_DIAG_BLOCK, max(_DIAG_MIN_BLOCK, tree_lane_shape(Tb)[0]))
     Tp = -(-Tb // g) * g
     if Tp != Tb:  # sentinel code L matches no leaf; zero stat columns
         node_s = jnp.pad(node_s, ((0, 0), (0, Tp - Tb)), constant_values=L)
@@ -1850,9 +1859,13 @@ class GBTFamilyBase(_TreeFamilyBase):
             if capped is not None:
                 n_rounds = int(capped.max())
                 grid = dict(grid, maxIter=jnp.asarray(capped, jnp.float32))
-        n_slots = _SWEEP_SLOTS if sweep else _REFIT_SLOTS
+        md = np.asarray(grid["maxDepth"], dtype=np.float64).reshape(-1)
+        depth, slots = self._scan_shape(md, sweep)
+        B = weights.shape[0]
+        cb, C = self._round_lanes(B, depth, slots, int(X.shape[0]),
+                                  int(X.shape[1]), num_classes, sweep)
 
-        def one_raw(g, w, depth, slots=0):
+        def one_raw(g, w):
             return _fit_gbt_batch(
                 X, y, w, g["maxDepth"],
                 _g(g, "minInstancesPerNode", 0.0), _g(g, "minInfoGain", 0.0),
@@ -1862,64 +1875,45 @@ class GBTFamilyBase(_TreeFamilyBase):
                 depth=depth, n_bins=N_BINS, num_classes=max(num_classes, 2),
                 task=task, n_rounds=n_rounds, sweep=sweep, n_slots=slots)
 
-        def one_call(g, w, depth, slots=0):
-            # config chunking under the SAME per-level histogram budget as
-            # RF (_LEVEL_HIST_ELEMS): the (Tb·nodes, d, n_bins, k) split
-            # pipeline scales with the feature count, and GBT's boosting
-            # scan otherwise runs every config at once — a 600-column
-            # text-hashed vector at depth 12 would ask XLA for tens of GB
-            B_g = w.shape[0]
-            C_g = max(num_classes, 2) if task == "multiclass" else 1
-            nodes_w = (min(2 ** depth, slots) if slots
-                       else 2 ** max(depth - 1, 0))
-            per_cfg = C_g * nodes_w * X.shape[1] * N_BINS * 3
-            cb = int(max(1, min(B_g, _LEVEL_HIST_ELEMS // max(per_cfg, 1))))
-            # ...AND bound the (S, k·Wl·T_pad) masked-stat operand of the
-            # level histogram itself: at the refit sample (65536 rows) a
-            # 200+-config exact grid otherwise asks XLA for a >10 GB
-            # concatenate per level, and the scheduler keeps ~3 pipeline
-            # stages of it alive (observed 24.5 GB on the fidelity
-            # experiment's exact arm)
-            S_est = min(X.shape[0],
-                        _SWEEP_HIST_SAMPLE if sweep else _HIST_SAMPLE)
-            lanes_max = max((1 << 29) // max(S_est, 1), 192)
-            cb = int(max(1, min(cb, lanes_max // (3 * nodes_w * C_g))))
-            if cb >= B_g:
-                return one_raw(g, w, depth, slots)
-            n_ch = -(-B_g // cb)
+        def one_call():
+            if cb >= B:
+                return one_raw(grid, weights)
             parts = []
-            for c in range(n_ch):
+            for c in range(-(-B // cb)):
                 # wrap the tail chunk so every chunk shares one compile
                 # plain-numpy index: grid values may be host constants
                 # (the fused sweep program passes them that way), and
                 # numpy cannot be indexed by a traced jnp constant
-                idx = np.arange(c * cb, (c + 1) * cb) % B_g
-                sub = {k2: v[idx] for k2, v in g.items()}
-                p = one_raw(sub, w[idx], depth, slots)
-                count = min((c + 1) * cb, B_g) - c * cb
+                idx = np.arange(c * cb, (c + 1) * cb) % B
+                sub = {k2: v[idx] for k2, v in grid.items()}
+                p = one_raw(sub, weights[idx])
+                count = min((c + 1) * cb, B) - c * cb
                 parts.append((idx[:count],
                               {k2: (v if k2 == "edges" else v[:count])
                                for k2, v in p.items()}))
-            return _stitch_parts(B_g, parts)
+            return _stitch_parts(B, parts)
 
-        md = np.asarray(grid["maxDepth"], dtype=np.float64).reshape(-1)
-        d_max = int(md.max())
+        if sweep:
+            return one_call()
+        # a refit: the regrow on the split-search sample is all of it
+        # (boosting has no exact leaf pass), one program under its span
+        with _obs_span("refit.grow", family=self.name, trees=n_rounds,
+                       depth=depth, slots=slots,
+                       sampleRows=min(int(X.shape[0]), _HIST_SAMPLE),
+                       **self._lane_attrs(cb * C)):
+            return one_call()
 
-        def fit(slots):
-            if sweep:
-                return one_call(grid, weights, d_max, slots)
-            # a refit: the regrow on the split-search sample is all of it
-            # (boosting has no exact leaf pass), one program under its span
-            with _obs_span("refit.grow", family=self.name, trees=n_rounds,
-                           depth=d_max, slots=slots,
-                           sampleRows=min(int(X.shape[0]), _HIST_SAMPLE)):
-                return one_call(grid, weights, d_max, slots)
-
+    @staticmethod
+    def _scan_shape(depths, sweep):
+        """``(depth, slots)`` of the ONE boosting scan a grid's
+        configurations share: the deepest ``maxDepth``, and 0 slots (complete
+        heaps) up to `_MAX_HEAP_DEPTH`, else the slot-chain leaf budget."""
+        d_max = int(depths.max())
         if d_max <= _MAX_HEAP_DEPTH:
             # no depth grouping: boosting rounds are a sequential scan, and
             # a second scan chain for shallow configs costs more than the
             # wasted deep levels (their active-mask already stops splitting)
-            return fit(0)
+            return d_max, 0
         # deep grid: ONE slot-chain scan for ALL configs at the deepest
         # depth. Boosting is step-count-bound (each of rounds x levels
         # sequential steps carries ~ms of small-op overhead at GBT's narrow
@@ -1930,10 +1924,57 @@ class GBTFamilyBase(_TreeFamilyBase):
         # configs' trees still fit within the budget exactly when
         # 2^depth <= n_slots (chain == heap, test_capped_grower_matches_
         # heap_when_uncapped).
-        shallow = md[md <= _MAX_HEAP_DEPTH]
+        n_slots = _SWEEP_SLOTS if sweep else _REFIT_SLOTS
+        shallow = depths[depths <= _MAX_HEAP_DEPTH]
         if shallow.size:  # budget must hold a shallow config's full tree
             n_slots = max(n_slots, 2 ** int(shallow.max()))
-        return fit(n_slots)
+        return d_max, n_slots
+
+    def _round_lanes(self, B, depth, slots, rows, features, num_classes,
+                     sweep):
+        """``(cb, C)``: the configurations one boosting scan takes at once
+        and the class planes of each; a round grows ``cb * C`` trees side by
+        side. Config chunking under the SAME per-level histogram budget as
+        RF (_LEVEL_HIST_ELEMS): the (Tb·nodes, d, n_bins, k) split pipeline
+        scales with the feature count, and GBT's boosting scan otherwise
+        runs every config at once — a 600-column text-hashed vector at depth
+        12 would ask XLA for tens of GB."""
+        C = (max(num_classes, 2)
+             if self._gbt_task(num_classes) == "multiclass" else 1)
+        nodes_w = (min(2 ** depth, slots) if slots
+                   else 2 ** max(depth - 1, 0))
+        per_cfg = C * nodes_w * features * N_BINS * 3
+        cb = int(max(1, min(B, _LEVEL_HIST_ELEMS // max(per_cfg, 1))))
+        # ...AND bound the (S, k·Wl·T_pad) masked-stat operand of the level
+        # histogram itself: at the refit sample (65536 rows) a 200+-config
+        # exact grid otherwise asks XLA for a >10 GB concatenate per level,
+        # and the scheduler keeps ~3 pipeline stages of it alive (observed
+        # 24.5 GB on the fidelity experiment's exact arm)
+        S_est = min(rows, _SWEEP_HIST_SAMPLE if sweep else _HIST_SAMPLE)
+        lanes_max = max((1 << 29) // max(S_est, 1), 192)
+        return int(max(1, min(cb, lanes_max // (3 * nodes_w * C)))), C
+
+    def fit_span_attrs(self, rows, features, grid, num_classes, sweep):
+        """``treeLanes``: the trees a boosting round grows side by side
+        (`_round_lanes`: configurations x folds x class planes, 1 for a
+        binary or regression winner's refit), and ``treeLanesPadded``: the
+        tree lanes the node histogram lays out for them
+        (`histeng.kernels.tree_lane_shape`); their quotient is the share of
+        the level contraction's lanes that carry a tree."""
+        attrs = super().fit_span_attrs(rows, features, grid, num_classes,
+                                       sweep)
+        if any("maxDepth" not in g for g in grid):
+            return attrs
+        depth, slots = self._scan_shape(
+            np.asarray([g["maxDepth"] for g in grid], np.float64), sweep)
+        cb, C = self._round_lanes(len(grid), depth, slots, rows, features,
+                                  num_classes, sweep)
+        return dict(attrs, **self._lane_attrs(cb * C))
+
+    @staticmethod
+    def _lane_attrs(lanes):
+        return {"treeLanes": lanes,
+                "treeLanesPadded": tree_lane_shape(lanes)[0]}
 
     def predict_batch(self, params, X, num_classes):
         edges = self._edges_of(params)
