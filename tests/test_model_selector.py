@@ -204,7 +204,11 @@ def test_fold_sliced_scoring_matches_masked_path():
 def test_fold_sliced_pins_binned_metric_choice():
     """Fold-slicing shrinks the metric's row axis; the binned-vs-exact
     AuROC choice must follow the PRE-slice row count so both scoring paths
-    agree even when n is above the binned threshold but n/F is below it."""
+    agree even when n is above the binned threshold but n/F is below it.
+    Both counts are row BUCKETS (900 rows pad to 1024, a fold's 300 to 512),
+    and the validator must read the threshold when it is called, as the
+    metrics do: pinned, the two paths agree to the bit; unpinned, or pinned
+    to a threshold captured at import, the boosted scores differ by 4e-5."""
     import numpy as np
     import jax.numpy as jnp
     from transmogrifai_tpu.impl.tuning.validators import OpCrossValidation
@@ -213,7 +217,7 @@ def test_fold_sliced_pins_binned_metric_choice():
     import transmogrifai_tpu.models.trees  # noqa: F401
 
     old = M._BINNED_MIN_N
-    M._BINNED_MIN_N = 512          # n=900 above, n/3=300 below
+    M._BINNED_MIN_N = 600          # the table's 1024 above, a fold's 512 below
     # _BINNED_MIN_N is read at trace time inside the module-level-jitted
     # metrics; stale per-shape traces from earlier tests would silently
     # bypass the patched threshold (and the un-patch below)
@@ -226,17 +230,19 @@ def test_fold_sliced_pins_binned_metric_choice():
         y = jnp.asarray((np.asarray(X) @ rng.randn(d).astype(np.float32)
                          + 0.5 * rng.randn(n) > 0).astype(np.float32))
         # a tree family: linear families opt out of fold-sliced predicts
-        # (fold_sliced_predict=False), so only trees exercise the pin
-        models = [(MODEL_REGISTRY["OpDecisionTreeClassifier"],
-                   [{"maxDepth": 3}])]
+        # (fold_sliced_predict=False), so only trees exercise the pin; a
+        # boosted one: one shallow tree's few distinct scores are the same
+        # tie groups binned or not
+        models = [(MODEL_REGISTRY["OpGBTClassifier"],
+                   [{"maxDepth": 3, "maxIter": 8}])]
         cv = OpCrossValidation(num_folds=3, seed=3)
         sliced = cv.validate(models, X, y, "binary", "AuROC", True, 2)
         masked = cv.validate(models, X, y, "binary", "AuROC", True, 2,
                              fold_sliced=False)
         got = np.asarray(sliced.results[0].fold_metrics)
         want = np.asarray(masked.results[0].fold_metrics)
-        # same algorithm (binned) on both paths -> near-identical values
-        assert np.allclose(got, want, rtol=1e-3, atol=2e-3), (got, want)
+        # same algorithm (binned) on both paths -> the same values
+        assert np.allclose(got, want, rtol=0, atol=1e-6), (got, want)
     finally:
         M._BINNED_MIN_N = old
         M.auroc_masked.clear_cache()

@@ -29,6 +29,7 @@ from transmogrifai_tpu.impl.tuning.sweep_checkpoint import (
 )
 from transmogrifai_tpu.robustness import faults
 from transmogrifai_tpu.robustness.faults import SimulatedPreemption
+from transmogrifai_tpu.robustness.policy import FaultLog
 from transmogrifai_tpu.workflow import OpWorkflow
 
 LR_GRID = [{"regParam": 0.01, "elasticNetParam": 0.0},
@@ -309,6 +310,32 @@ def test_sweep_checkpoint_put_get_and_corruption(tmp_path):
         fh.write(b"garbage")
     ck3 = SweepCheckpoint(d, "sel_1")
     assert ck3.get("k1") is None
+
+
+@pytest.mark.parametrize("reason", [None, "fit raised ValueError: boom"])
+def test_what_persist_writes_is_what_restore_returns(tmp_path, reason):
+    d = str(tmp_path / "ck")
+    os.makedirs(d)
+    fm = np.array([[0.5, np.nan], [np.inf, -np.inf], [0.25, 1e-30]],
+                  dtype=np.float32)
+    SweepCheckpoint(d, "sel_1").persist("k1", "fam", LR_GRID, "AuPR", fm,
+                                        reason)
+    ck = SweepCheckpoint(d, "sel_1")        # a new process
+    with FaultLog().activate() as log:
+        back, why = ck.restore("k1", 3, 2)
+    assert back.dtype == np.float32 and why == reason
+    np.testing.assert_array_equal(back, fm)
+    (rep,) = log.reports
+    assert (rep.site, rep.kind) == ("sweep.candidate", "restored")
+    assert rep.detail == {
+        "family": "fam", "configs": 2, "candidateKey": "k1",
+        "quarantined": reason is not None}
+    rec = ck.get("k1")
+    assert rec["grid"] == LR_GRID and rec["metricName"] == "AuPR"
+    assert rec["paramsHashes"] == [params_hash(g) for g in LR_GRID]
+    # no record, or one of another sweep's folds or grid: nothing replays
+    assert ck.restore("k2", 3, 2) is None
+    assert ck.restore("k1", 2, 2) is None and ck.restore("k1", 3, 1) is None
 
 
 # ---------------------------------------------------------------------------

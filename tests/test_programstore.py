@@ -456,6 +456,51 @@ def test_sweep_programs_cached_across_processes(tmp_path, monkeypatch):
             == [list(r.values()) for r in r2])
 
 
+def test_a_second_row_bucket_gets_its_own_sweep_program(tmp_path,
+                                                        monkeypatch):
+    """The fused cache's key holds the table's shape and the fold gather's,
+    as the store's fingerprint and the ledger's record do. Keyed without
+    them, a store hit for the first table's bucket sat in the cache under a
+    key the second table's sweep matched too: it was handed an executable
+    deserialized for other rows, which threw, and the family was
+    quarantined."""
+    from transmogrifai_tpu.impl.tuning.validators import OpCrossValidation
+    from transmogrifai_tpu.models.api import MODEL_REGISTRY
+    models = [(MODEL_REGISTRY["OpLogisticRegression"],
+               [{"regParam": 0.01, "elasticNetParam": 0.0},
+                {"regParam": 0.1, "elasticNetParam": 0.0}])]
+
+    def sweep(n):
+        rng = np.random.RandomState(n)
+        X = rng.randn(n, 3).astype(np.float32)
+        y = (X.sum(axis=1) > 0).astype(np.float32)
+        return OpCrossValidation(num_folds=3, seed=1).validate(
+            models, X, y, "binary", "AuPR", True, 2)
+
+    _validators._FUSED_CACHE.clear()
+    alone = {n: sweep(n) for n in (300, 1000)}      # no store: the reference
+    monkeypatch.setenv("TG_AOT_STORE", str(tmp_path / "sweepstore"))
+    _validators._FUSED_CACHE.clear()
+    first = sweep(768)      # builds for 1024 rows, 256 a fold, and exports
+    _validators._FUSED_CACHE.clear()
+    lg.ledger().clear()
+    mark = lg.ledger().mark()
+    again = sweep(768)      # the store's executable, into the fused cache
+    assert ps.stats()["hits"].get("sweep", 0) == 1
+    np.testing.assert_array_equal(again.results[0].fold_metrics,
+                                  first.results[0].fold_metrics)
+    # 512 rows; then 1024 rows again, but 512 a fold: not that executable
+    for n in (300, 1000):
+        got = sweep(n)
+        assert got.quarantined == []
+        np.testing.assert_array_equal(got.results[0].fold_metrics,
+                                      alone[n].results[0].fold_metrics)
+    built = [r for r in lg.ledger().since(mark) if r.subsystem == "sweep"]
+    assert [(r.bucket, r.fingerprint["foldRows"]) for r in built] == [
+        (512, 256), (1024, 512)]
+    assert len(ProgramStore(str(tmp_path / "sweepstore")).entries()) == 3
+
+
 # ---------------------------------------------------------------------------
 # cli programs + warm report + ledger unit
 # ---------------------------------------------------------------------------

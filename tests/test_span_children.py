@@ -88,6 +88,25 @@ def train(request):
     return problem, spans, other
 
 
+@pytest.fixture(scope="module")
+def warm(train):
+    """Two more trains of ``train``'s first table: its programs are
+    compiled, so a region lasts its work (milliseconds, where the first
+    train's compiles make it hundreds of them)."""
+    problem = train[0]
+    return [_traced_train(_workflow(_df(ROWS, problem), problem))[1]
+            for _ in range(2)]
+
+
+def _steps(spans, parent):
+    """(the region, its children by start, the steps' names)."""
+    region, steps = _parents(spans)[parent]
+    kids = sorted((s for s in spans if s.parent_id == region.span_id
+                   and not s.name.startswith("mesh.")),
+                  key=lambda s: s.ts_ns)
+    return region, kids, steps
+
+
 def _stage(spans, name, stage):
     (s,) = [s for s in spans
             if s.name == name and s.attrs.get("stage") == stage]
@@ -121,24 +140,40 @@ PARENTS = ["selector.prepare", "selector.evaluate", "checker", "real fit",
            "real transform", "closing transform"]
 
 
+def _uncovered(spans, parent):
+    """(the region's time, the parts of it that no step covers: before the
+    first step, between each two, after the last)."""
+    region, kids, _ = _steps(spans, parent)
+    edges = [region.ts_ns, *(t for s in kids
+                             for t in (s.ts_ns, s.ts_ns + s.dur_ns)),
+             region.ts_ns + region.dur_ns]
+    return region.dur_ns, [b - a for a, b in zip(edges[::2], edges[1::2])]
+
+
 @pytest.mark.parametrize("parent", PARENTS)
 def test_every_step_is_one_child_in_order_and_the_steps_cover_the_region(
-        train, parent):
-    _, spans, _ = train
-    region, steps = _parents(spans)[parent]
-    kids = sorted((s for s in spans if s.parent_id == region.span_id
-                   and not s.name.startswith("mesh.")),
-                  key=lambda s: s.ts_ns)
-    assert [s.name for s in kids] == steps
-    for a, b in zip(kids, kids[1:]):
-        assert a.ts_ns + a.dur_ns <= b.ts_ns
-    assert region.ts_ns <= kids[0].ts_ns
-    assert kids[-1].ts_ns + kids[-1].dur_ns <= region.ts_ns + region.dur_ns
-    # 90 % of the region; a region of microseconds is held to the fixed
-    # cost of leaving it (a column into the table, the spans themselves)
-    outside = region.dur_ns - sum(s.dur_ns for s in kids)
-    assert outside <= max(0.1 * region.dur_ns, 300_000), (
-        outside, region.dur_ns)
+        train, warm, parent):
+    for spans in (train[1], *warm):
+        region, kids, steps = _steps(spans, parent)
+        assert [s.name for s in kids] == steps
+        for a, b in zip(kids, kids[1:]):
+            assert a.ts_ns + a.dur_ns <= b.ts_ns
+        assert region.ts_ns <= kids[0].ts_ns
+        assert (kids[-1].ts_ns + kids[-1].dur_ns
+                <= region.ts_ns + region.dur_ns)
+    # what the CODE leaves outside the steps (a pass over the rows, a wait
+    # for the device) it leaves in the same gap of every train; what the
+    # machine puts there (a thread off its core for 4 ms, a collection) it
+    # puts where it likes. So each gap counts for the shorter of its two
+    # warm trains', and a loaded machine has to hit one gap twice. The
+    # steps' own entries and exits are 5-9 % of the regions of two
+    # milliseconds and more, and up to 14 % beside seven busy loops on
+    # eight cores (PR 46): a fifth of the region, or 600 us for a region of
+    # microseconds (a column into the table, the spans themselves)
+    (dur, gaps), (dur2, gaps2) = (_uncovered(w, parent) for w in warm)
+    outside = sum(map(min, gaps, gaps2))
+    assert 0 <= outside <= max(0.2 * min(dur, dur2), 600_000), (
+        gaps, dur, gaps2, dur2)
 
 
 def test_the_steps_say_what_they_worked_on(train):

@@ -125,7 +125,7 @@ class ModelSelector(AllowLabelAsInput, Estimator):
         — instead of re-running them (docs/robustness.md "Resumable
         sweeps"). Train-time wiring only; never serialized with the fitted
         model."""
-        self.validator._sweep_ckpt = ckpt
+        self.validator.sweep_checkpoint = ckpt
         return self
 
     def _resolve_models(self, models):

@@ -26,15 +26,40 @@ from __future__ import annotations
 
 import hashlib
 import json
+import logging
 import os
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ...manifest import CheckpointManifest
 from ...robustness.policy import FaultLog, FaultReport
 
+logger = logging.getLogger(__name__)
+
 SWEEP_STATE_VERSION = 1
+
+
+def sweep_fingerprint(X, y, val_masks: np.ndarray, *, problem: str,
+                      metric_name: str, num_classes: int,
+                      larger_better: bool, exact: bool,
+                      max_eval_rows: Optional[int]) -> Dict[str, Any]:
+    """What one sweep's records may be replayed onto: the fold and metric
+    configuration, the sweep's fidelity, the table's shape and a sha256 of
+    the labels and of the (F, n) validation masks. Taken of the rows as the
+    caller handed them, BEFORE the validator pads them to their bucket."""
+    F, n = val_masks.shape
+    return {
+        "n": int(n), "F": int(F), "problem": problem,
+        "d": int(X.shape[-1]) if X.ndim > 1 else 1,
+        "metric": metric_name, "numClasses": int(num_classes),
+        "largerBetter": bool(larger_better), "exact": bool(exact),
+        "maxEvalRows": max_eval_rows,
+        "yhash": hashlib.sha256(
+            np.ascontiguousarray(np.asarray(y)[:n]).tobytes()).hexdigest(),
+        "foldHash": hashlib.sha256(
+            np.ascontiguousarray(val_masks).tobytes()).hexdigest(),
+    }
 
 
 def candidate_key(family: str, grid: List[Dict[str, Any]],
@@ -59,7 +84,8 @@ def params_hash(hyper: Dict[str, Any]) -> str:
 class SweepCheckpoint:
     """Durable per-candidate sweep state for one selector stage.
 
-    ``get``/``put`` operate on whole-family records::
+    ``restore``/``persist`` are what a sweep calls: they own every field
+    of a whole-family record, over ``get``/``put``::
 
         {"family": "OpGBTClassifier",
          "grid": [...hyper dicts...],
@@ -127,6 +153,48 @@ class SweepCheckpoint:
         self.manifest.record_file(self.fname, sha, len(data))
         self.manifest.complete_sweep(self.owner_uid, self.fname)
         self.manifest.save()
+
+    # -- one family's record, whole ------------------------------------------
+    def persist(self, cand_key: str, family: str,
+                grid: List[Dict[str, Any]], metric_name: str,
+                fold_metrics: np.ndarray,
+                reason: Optional[str] = None) -> None:
+        """Commit one family's evaluated branch: its (F, G) fold metrics
+        (NaN throughout where the fit threw) and, for a quarantined family,
+        the ``reason``."""
+        self.put(cand_key, {
+            "family": family,
+            "grid": [dict(g) for g in grid],
+            "paramsHashes": [params_hash(g) for g in grid],
+            "metricName": metric_name,
+            **self.encode_metrics(fold_metrics),
+            "quarantined": reason is not None,
+            "reason": reason,
+        })
+
+    def restore(self, cand_key: str, F: int, G: int
+                ) -> Optional[Tuple[np.ndarray, Optional[str]]]:
+        """``(fold_metrics, quarantine_reason_or_None)`` of a persisted
+        branch, or None where there is no record or its metrics are not
+        this sweep's (F, G). A replay is filed in the fault log."""
+        rec = self.get(cand_key)
+        if rec is None:
+            return None
+        fold_metrics = self.decode_metrics(rec)
+        if fold_metrics.shape != (F, G):
+            return None
+        quarantined = bool(rec.get("quarantined"))
+        FaultLog.record(FaultReport(
+            site="sweep.candidate", kind="restored",
+            detail={"family": rec.get("family"), "configs": G,
+                    "candidateKey": cand_key[:16],
+                    "quarantined": quarantined}))
+        logger.info("sweep resume: restored %d %s candidate(s) from "
+                    "checkpoint", G, rec.get("family"))
+        reason = None
+        if quarantined:
+            reason = rec.get("reason") or "restored quarantined candidate"
+        return fold_metrics, reason
 
     # -- metric (de)hydration ------------------------------------------------
     @staticmethod
