@@ -5,7 +5,8 @@ path produced, and builds the result line.
 Data only: a configuration is ``configs/<name>.json``, a traffic mix
 ``traffic/<name>.json``, a per-layer metric ``layer_metrics/<name>.json``;
 a traffic ``kind`` is ``kinds/<kind>.py``. Adding any of them is adding a
-file and one entry in ``BENCHMARK.json``.
+file and one entry in ``BENCHMARK.json``. A per-layer entry's ``workloads``
+lists every cell whose traced run reads it: a reader is written once.
 """
 from __future__ import annotations
 

@@ -7,7 +7,8 @@ events and ``memory_stats``. A reader that finds nothing to read returns
 None and the harness leaves the metric out of the line. A kind that is not
 listed here is loaded from ``reader_kinds/<kind>.py`` beside the cell's
 ``layer_metrics/`` directory, a file with a ``read(spec, readings)``
-function, so a later PR adds a kind as a new file.
+function, so a later PR adds a kind as a new file. A reader is written
+once: its manifest entry lists every cell that reads it, under one name.
 """
 from __future__ import annotations
 

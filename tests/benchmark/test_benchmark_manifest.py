@@ -1,13 +1,19 @@
 """Every file the manifest names loads, and the manifest keeps to the
-contract's forms: names, units, keys, bounds, chips."""
+contract's forms: names, units, keys, bounds, chips. A per-layer entry is one
+reader with the cells that read it listed in it: every (entry, cell) pairing
+loads, no reader stands twice, ``layer_metrics/`` holds one file an entry, and
+the contract's caps are held here, once."""
+import ast
+import functools
 import glob
+import importlib.util
 import json
 import os
 import re
 
 import pytest
 
-from benchmark import harness
+from benchmark import harness, readers
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -116,3 +122,118 @@ def test_configurations_state_their_cuts(manifest):
         assert cfg["reduced"] == c["reduced"]
         if "rows" in c["reduced"]:
             assert cfg["rows"] < cfg["source_rows"]
+
+
+# -- one entry a reader, with its cells listed in it --------------------------
+
+def _pairings():
+    m = harness.load_manifest(ROOT)
+    cells = [w["name"] for w in m["workloads"]]
+    return [(e["name"], c) for e in m["per_layer"]
+            for c in e.get("workloads", cells)]
+
+
+@pytest.fixture(scope="module")
+def cells(manifest):
+    return {w["name"]: harness.load_cell(ROOT, manifest, w["name"])
+            for w in manifest["workloads"]}
+
+
+def _benchmark_imports(module, seen):
+    """The benchmark's own modules in the import closure of ``module``,
+    read from the source: what a process that runs one traffic kind has
+    imported, whatever this test process has."""
+    path = os.path.join(ROOT, *module.split(".")) + ".py"
+    if module in seen or not os.path.exists(path):
+        return seen
+    seen.add(module)
+    package = module.rpartition(".")[0]
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            found = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            base = importlib.util.resolve_name(
+                "." * node.level + (node.module or ""), package)
+            found = [base] + [f"{base}.{a.name}" for a in node.names]
+        else:
+            continue
+        for name in found:
+            if name.startswith("benchmark."):
+                _benchmark_imports(name, seen)
+    return seen
+
+
+@functools.lru_cache(maxsize=None)
+def _kinds_a_cell_can_read(traffic_kind):
+    """Reader kinds a run of this traffic kind finds: the readers' own, the
+    files under ``reader_kinds/``, and those a module registers in
+    ``readers.KINDS`` when the traffic kind imports it."""
+    harness.loop_for(traffic_kind)
+    imported = _benchmark_imports(f"benchmark.kinds.{traffic_kind}", set())
+    files = {os.path.basename(p)[:-3] for p in glob.glob(
+        os.path.join(ROOT, "benchmark", "reader_kinds", "*.py"))}
+    return files | {k for k, f in readers.KINDS.items()
+                    if f.__module__ in imported | {"benchmark.readers"}}
+
+
+@pytest.mark.parametrize("name,cell_name", _pairings())
+def test_a_cell_loads_each_entry_that_lists_it(manifest, cells, name,
+                                               cell_name):
+    (entry,) = [e for e in manifest["per_layer"] if e["name"] == name]
+    cell = cells[cell_name]
+    (spec,) = [s for s in cell.per_layer if s["name"] == name]
+    # the file agrees with its entry key for key, the cells listed too
+    assert {k: spec[k] for k in entry} == entry
+    assert set(spec) == set(entry) | {"what", "read", "bench_dir"}
+    assert len(spec["what"]) > 20
+    # the cell reports the end-to-end metric that the entry moves
+    assert entry["moves"] in {m["name"] for m in cell.end_to_end}
+    assert entry["moves"] in set(cell.traffic["reports"]) | {"setup_s"}
+    # the reader's kind is there in a process that runs this cell's traffic
+    # kind, and says nothing of an empty run
+    assert spec["read"]["kind"] in _kinds_a_cell_can_read(
+        cell.traffic["kind"])
+    assert readers.read_metric(spec, readers.Readings()) is None
+
+
+def test_no_two_entries_share_a_reader(manifest):
+    """A reader under a second name is a copy: list the cell in the entry
+    that is there."""
+    seen = {}
+    for e in manifest["per_layer"]:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                               e["name"] + ".json")) as f:
+            read = json.dumps(json.load(f)["read"], sort_keys=True)
+        key = (read,) + tuple(e[k] for k in ("unit", "better", "source",
+                                             "layer", "moves"))
+        assert key not in seen, f"{e['name']} is {seen[key]}'s reader"
+        seen[key] = e["name"]
+
+
+def test_layer_metrics_holds_exactly_one_file_an_entry(manifest):
+    held = sorted(os.listdir(os.path.join(ROOT, "benchmark",
+                                          "layer_metrics")))
+    assert held == sorted(e["name"] + ".json" for e in manifest["per_layer"])
+
+
+def test_every_cell_named_in_a_workloads_list_is_a_cell(manifest):
+    cells = [w["name"] for w in manifest["workloads"]]
+    for e in manifest["end_to_end"] + manifest["per_layer"]:
+        listed = e.get("workloads", cells)
+        assert listed and set(listed) <= set(cells), e["name"]
+        # in the manifest's order of cells, each once
+        assert listed == [c for c in cells if c in listed], e["name"]
+
+
+def test_the_manifest_stays_inside_the_contracts_counts(manifest):
+    """The caps, here and nowhere else: a PR that adds a cell or a metric
+    edits no test."""
+    assert len(manifest["per_layer"]) <= 128
+    assert len(manifest["workloads"]) <= 24 and len(manifest["configs"]) <= 24
+    assert len(manifest["end_to_end"]) <= 16
+    four = sum(w["chips"] == 4 for w in manifest["workloads"])
+    assert four <= max(1, len(manifest["workloads"]) // 4)
+    for e in manifest["configs"] + manifest["workloads"]:
+        assert len(e["why"]) <= 200 and len(e.get("source", "")) <= 200

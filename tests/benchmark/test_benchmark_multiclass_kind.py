@@ -22,6 +22,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 EXT = os.path.join(HERE, "data", "extension_multiclass")
 CELL = "train-tiny-multiclass"
+REAL = "train-kddcup99"
+#: the cell's own metrics, and the shared readers that list it
+MINE = ("mc_forest_config_chunks", "mc_softmax_roofline")
+SHARED = ("sweep_linear_s", "sweep_forest_s", "refit_fit_s", "refit_eval_s",
+          "selector_prepare_s")
 
 
 @pytest.fixture()
@@ -44,11 +49,8 @@ def extended(tmp_path):
         {"name": CELL, "config": "tiny-multiclass",
          "traffic": "train_multiclass_tiny_closed_loop", "chips": 1,
          "why": "tests"})
-    for e in m["end_to_end"]:
-        if e["name"] == "train_s":
-            e["workloads"].append(CELL)
-    for e in m["per_layer"]:
-        if e["name"].startswith("mc_"):
+    for e in m["end_to_end"] + m["per_layer"]:
+        if REAL in e.get("workloads", []):
             e["workloads"].append(CELL)
     json.dump(m, open(os.path.join(root, "BENCHMARK.json"), "w"))
     return root, m
@@ -89,10 +91,9 @@ def test_a_traced_run_reads_the_span_metrics_this_pr_adds(extended):
     got = res["metrics"]
     # spans and counters read on the CPU; the device readers (the two sweep
     # programs' seconds, the roofline) find no device plane and say nothing
-    for name in ("mc_refit_fit_s", "mc_refit_eval_s",
-                 "mc_selector_prepare_s"):
+    for name in ("refit_fit_s", "refit_eval_s", "selector_prepare_s"):
         assert got[name]["value"] > 0
-    assert not {"mc_sweep_softmax_s", "mc_softmax_roofline"} & set(got)
+    assert not {"sweep_linear_s", "mc_softmax_roofline"} & set(got)
     assert "mc_forest_config_chunks" not in got      # no forest in this grid
 
 
@@ -198,26 +199,29 @@ def test_the_controls_fail_the_limits_a_sound_run_keeps(extended):
 
 def test_the_cell_and_its_metrics_are_in_the_manifest():
     m = harness.load_manifest(ROOT)
-    cell = harness.load_cell(ROOT, m, "train-kddcup99")
+    cell = harness.load_cell(ROOT, m, REAL)
     assert cell.chips == 1 and cell.config["problem"] == "multiclass"
     assert cell.traffic["kind"] == "train_multiclass_closed_loop"
     assert cell.traffic["min_ops"] == 6 and cell.traffic["traced_ops"] == 1
     airline = harness.load_cell(ROOT, m, "train-airline").traffic
     assert cell.traffic["process_env"] == airline["process_env"]
     assert cell.config["workflow"]["expected_fits"] == 72
-    mine = {s["name"]: s for s in cell.per_layer
-            if s["name"].startswith("mc_")}
-    assert set(mine) == {"mc_sweep_softmax_s", "mc_sweep_forest_s",
-                         "mc_refit_fit_s", "mc_refit_eval_s",
-                         "mc_selector_prepare_s", "mc_forest_config_chunks",
-                         "mc_softmax_roofline"}
-    for spec in mine.values():
-        assert spec["workloads"] == ["train-kddcup99"]
+    specs = {s["name"]: s for s in cell.per_layer}
+    # its own two are its own; the readers it shares list it among others
+    assert {n for n in specs if n.startswith("mc_")} == set(MINE)
+    assert set(specs) >= set(SHARED)
+    for name in MINE + SHARED:
+        spec = specs[name]
+        assert (spec["workloads"] == [REAL] if name in MINE
+                else REAL in spec["workloads"])
         assert spec["moves"] == "train_s" and len(spec["what"]) > 20
         assert readers.read_metric(spec, readers.Readings()) is None
-    assert mine["mc_softmax_roofline"]["unit"] == "%"
-    assert len(m["workloads"]) == 3
-    assert all(w["chips"] == 1 for w in m["workloads"])
+    assert specs["mc_softmax_roofline"]["unit"] == "%"
+    # the many-class selector lists no boosted family: nothing to read
+    assert "sweep_gbt_s" not in specs
+    # in this cell the linear families' program IS the softmax solver's
+    assert specs["mc_softmax_roofline"]["read"]["attrs"]["family"] in \
+        specs["sweep_linear_s"]["read"]["attrs"]["family"].split("|")
 
 
 def test_the_operation_count_is_the_spans_own():

@@ -23,9 +23,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 EXT = os.path.join(HERE, "data", "extension_regression")
 CELL = "train-tiny-regression"
-NEW = ("rg_sweep_linear_s", "rg_sweep_forest_s", "rg_sweep_gbt_s",
-       "rg_refit_fit_s", "rg_refit_eval_s", "rg_selector_prepare_s",
-       "rg_fe_date_s", "rg_gram_roofline")
+REAL = "train-nyctaxi"
+#: the cell's own metrics, and the shared readers PR 30 first read here
+MINE = ("rg_fe_date_s", "rg_gram_roofline")
+NEW = ("sweep_linear_s", "sweep_forest_s", "sweep_gbt_s", "refit_fit_s",
+       "refit_eval_s", "selector_prepare_s") + MINE
 
 
 @pytest.fixture()
@@ -48,11 +50,8 @@ def extended(tmp_path):
         {"name": CELL, "config": "tiny-regression",
          "traffic": "train_regression_tiny_closed_loop", "chips": 1,
          "why": "tests"})
-    for e in m["end_to_end"]:
-        if e["name"] == "train_s":
-            e["workloads"].append(CELL)
-    for e in m["per_layer"]:
-        if e["name"].startswith("rg_"):
+    for e in m["end_to_end"] + m["per_layer"]:
+        if REAL in e.get("workloads", []):
             e["workloads"].append(CELL)
     json.dump(m, open(os.path.join(root, "BENCHMARK.json"), "w"))
     return root, m
@@ -93,10 +92,10 @@ def test_a_traced_run_reads_the_span_metrics_this_pr_adds(extended):
     got = res["metrics"]
     # spans read on the CPU; the device readers (the family programs'
     # seconds, the roofline) find no device plane and say nothing
-    for name in ("rg_refit_fit_s", "rg_refit_eval_s", "rg_selector_prepare_s",
+    for name in ("refit_fit_s", "refit_eval_s", "selector_prepare_s",
                  "rg_fe_date_s"):
         assert got[name]["value"] > 0
-    assert not {"rg_sweep_linear_s", "rg_sweep_forest_s", "rg_sweep_gbt_s",
+    assert not {"sweep_linear_s", "sweep_forest_s", "sweep_gbt_s",
                 "rg_gram_roofline"} & set(got)
     assert {s["name"] for s in cell.per_layer} >= set(NEW)
 
@@ -253,7 +252,7 @@ def test_the_controls_fail_the_limits_a_sound_run_keeps(extended):
 
 def test_the_cell_and_its_metrics_are_in_the_manifest():
     m = harness.load_manifest(ROOT)
-    cell = harness.load_cell(ROOT, m, "train-nyctaxi")
+    cell = harness.load_cell(ROOT, m, REAL)
     assert cell.chips == 1 and cell.config["problem"] == "regression"
     assert cell.traffic["kind"] == "train_regression_closed_loop"
     assert cell.traffic["min_ops"] == 6 and cell.traffic["traced_ops"] == 1
@@ -262,22 +261,24 @@ def test_the_cell_and_its_metrics_are_in_the_manifest():
     airline = harness.load_cell(ROOT, m, "train-airline").traffic
     assert cell.traffic["process_env"] == airline["process_env"]
     assert {e["name"] for e in cell.end_to_end} == {"train_s", "setup_s"}
-    mine = {s["name"]: s for s in cell.per_layer
-            if s["name"].startswith("rg_")}
-    assert set(mine) == set(NEW)
-    for spec in mine.values():
-        assert spec["workloads"] == ["train-nyctaxi"]
+    specs = {s["name"]: s for s in cell.per_layer}
+    assert {n for n in specs if n.startswith("rg_")} == set(MINE)
+    assert set(specs) >= set(NEW)
+    for name in NEW:
+        spec = specs[name]
+        assert REAL in spec["workloads"]
         assert spec["moves"] == "train_s" and len(spec["what"]) > 20
         assert readers.read_metric(spec, readers.Readings()) is None
-    assert mine["rg_gram_roofline"]["unit"] == "%"
-    shared = {s["name"] for s in cell.per_layer} - set(mine)
-    assert shared >= {"refit_s", "fe_s", "sanity_s", "sweep_s",
-                      "train_device_busy_s", "train_device_idle_pct",
-                      "train_hbm_peak_gb", "setup_compile_s"}
-    assert m["workloads"][-1]["name"] == "train-nyctaxi"
-    assert all(w["chips"] == 1 for w in m["workloads"])
-    before = [w["name"] for w in m["workloads"][:-1]]
-    assert before == ["score-higgs", "train-airline", "train-kddcup99"]
+    assert specs["rg_gram_roofline"]["workloads"] == [REAL]
+    assert specs["rg_gram_roofline"]["unit"] == "%"
+    # the roofline's seconds are the linear families' programs', which in
+    # this cell are the linear and the generalised-linear one
+    gram = specs["rg_gram_roofline"]["read"]["seconds"]["attrs"]["family"]
+    assert set(gram.split("|")) <= set(
+        specs["sweep_linear_s"]["read"]["attrs"]["family"].split("|"))
+    assert set(specs) >= {"refit_s", "fe_s", "sanity_s", "sweep_s",
+                          "train_device_busy_s", "train_device_idle_pct",
+                          "train_hbm_peak_gb", "setup_compile_s"}
 
 
 def test_the_byte_count_is_the_spans_own():
@@ -318,7 +319,7 @@ def test_the_roofline_reader_divides_span_bytes_by_device_seconds():
     got = roofline_bytes.read(spec["read"], r, device_kind="TPU v5 lite")
     assert got == pytest.approx(100 * nbytes / 8e-3 / 819e9)
     linear_s = json.load(open(os.path.join(
-        ROOT, "benchmark", "layer_metrics", "rg_sweep_linear_s.json")))
+        ROOT, "benchmark", "layer_metrics", "sweep_linear_s.json")))
     assert readers.reader_for("device_op_by_span_order", os.path.join(
         ROOT, "benchmark"))(linear_s["read"], r) == pytest.approx(8e-3)
     # a span without the fit's own count (the parent): nothing to read

@@ -76,10 +76,9 @@ def _readings():
         epoch_ns=raw["epoch_ns"])
 
 
-def test_the_entries_are_appended_in_this_order(manifest):
+def test_the_entries_are_there_once_each(manifest):
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(EXPECTED):] == list(EXPECTED)
-    assert len(set(names)) == len(names) <= 128
+    assert all(names.count(name) == 1 for name in EXPECTED)
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))
@@ -87,10 +86,12 @@ def test_a_file_agrees_with_its_entry_and_uses_a_kind_that_was_there(
         manifest, name):
     layer, unit, kind, _ = EXPECTED[name]
     (entry,) = [m for m in manifest["per_layer"] if m["name"] == name]
-    assert entry == {"name": name, "unit": unit, "better": "lower",
-                     "source": "program_span", "layer": layer,
-                     "moves": "train_s",
-                     "workloads": WORKLOADS.get(name, TRAIN)}
+    assert dict(entry, workloads=None) == {
+        "name": name, "unit": unit, "better": "lower",
+        "source": "program_span", "layer": layer, "moves": "train_s",
+        "workloads": None}
+    # the cells PR 36 read it in, and whichever were listed since
+    assert set(entry["workloads"]) >= set(WORKLOADS.get(name, TRAIN))
     spec = _spec(name)
     assert {k: spec[k] for k in entry} == entry
     assert set(spec) == set(entry) | {"what", "read"}

